@@ -247,10 +247,18 @@ def cmd_agent(args) -> None:
     server.start()
     http = start_http_server(server, host=cfg.http.host, port=cfg.http.port)
     print(f"==> nomad-tpu agent started; HTTP on :{http.port}")
+    dev = server.device_supervisor.status()
+    backend_line = (
+        f"backend: platform={dev['platform']} "
+        f"device_kind={dev['device_kind']} "
+        f"devices={dev['device_count']} "
+        f"supervision={'on' if dev['enabled'] else 'idle'}"
+    )
+    print(f"==> {backend_line}")
     # lifecycle lines feed /v1/agent/monitor (the logging handler only
     # sees `logging` records, not stdout prints)
     server.log_monitor.write_line(
-        f"agent started; HTTP on :{http.port}"
+        f"agent started; HTTP on :{http.port}; {backend_line}"
     )
     bridge = None
     if cfg.bridge_port is not None:
@@ -822,8 +830,13 @@ def cmd_device_status(args) -> None:
     st = _request("GET", "/v1/device")
     if _emit(args, st):
         return
+    print(
+        f"Platform: {st.get('platform')}  "
+        f"Device kind: {st.get('device_kind')}  "
+        f"Devices: {st.get('device_count', 0)}"
+    )
     if not st.get("enabled"):
-        print("Device supervision idle (no accelerator expected)")
+        print("Device supervision idle (JAX resolved no accelerator)")
         return
     lat = st.get("probe_latency_ms", {})
     _table(

@@ -12,8 +12,11 @@ The device subsystem owns accelerator liveness for the whole server:
   (``NOMAD_TPU_FAULT=wedge_launch|slow_fetch|init_block|flaky``) so
   every transition is testable on CPU;
 * ``preflight``   — ``python -m nomad_tpu.device.preflight``, the
-  bounded canary probe absorbing the ad-hoc checks that used to live
-  in ``bench.py`` and the deleted ``tools/tpu_retry_loop.sh`` wrapper.
+  bounded canary probe as a standalone check that names the platform
+  that answered.
+
+Which backend the process is on is answered by ``nomad_tpu.backend``
+(``resolve_backend``), from what JAX initialised.
 """
 from .faults import FaultPlan, InjectedFault
 from .supervisor import (

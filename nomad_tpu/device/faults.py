@@ -13,8 +13,8 @@ points:
                     eventually completes (a device stalling under
                     contention) — trips the deadline monitor while the
                     sacrificial thread finishes harmlessly
-  init_block        the canary blocks forever (backend init hangs, the
-                    BENCH_r05 rc=2 shape).  Like the real thing there
+  init_block        the canary blocks forever (backend init hangs
+                    without raising).  Like the real thing there
                     is no in-process recovery: backend init is
                     process-wide and memoized, so a parked init call
                     blocks every later attempt too (the supervisor's

@@ -21,10 +21,10 @@ do inline:
    backend epoch bumps and every subscribed listener (the batch
    worker) flushes its backend-keyed caches, re-jits on CPU and
    disables the sharded mesh path.  The CPU kernels are bit-identical
-   to the device kernels (the CPU-parity sweep in
-   ``BENCH_CPU_PARITY_r05.json``), so failover preserves decision
-   parity.  In LOST the canary keeps probing the *device*; a success
-   moves to RECOVERING, and after ``recover_canaries`` consecutive
+   to the device kernels (``chip_smoke.py`` checks the chip's f32
+   placements against the sequential oracle), so failover preserves
+   decision parity.  In LOST the canary keeps probing the *device*; a
+   success moves to RECOVERING, and after ``recover_canaries`` consecutive
    passes the pipeline flips back; the registered re-warm hooks (the
    ``NOMAD_TPU_WARM_ON_START`` machinery) then recompile the launch
    shapes for the restored backend, the cold-compile shield covering
@@ -37,9 +37,11 @@ tripped watchdog.
 
 Env knobs (config-file equivalents in ``config.DeviceConfig``):
 
-  NOMAD_TPU_SUPERVISOR         1 forces supervision on (0 off) even on
-                               CPU-only backends — the fault-injection
-                               and soak tests run this way
+  NOMAD_TPU_SUPERVISOR         1 forces supervision on (0 off) whatever
+                               backend JAX resolved (default: on when
+                               it resolved an accelerator) — the
+                               fault-injection and soak tests run
+                               forced-on over the CPU backend
   NOMAD_TPU_PROBE_INTERVAL_S   canary cadence (default 30)
   NOMAD_TPU_PROBE_TIMEOUT_S    canary deadline (default 10)
   NOMAD_TPU_INIT_GRACE_S       deadline floor until the FIRST canary
@@ -68,6 +70,7 @@ from typing import Callable, Dict, List, Optional
 
 LOG = logging.getLogger("nomad_tpu.device")
 
+from ..backend import Backend
 from ..telemetry import percentile as _percentile
 from ..trace import TRACE
 from .faults import FAULT_ENV, FaultPlan
@@ -75,7 +78,7 @@ from .watchdog import BudgetTracker, DeviceTimeout, bounded_call
 
 # -- states -----------------------------------------------------------
 
-CPU_ONLY = "CPU_ONLY"  # no accelerator expected; supervision idle
+CPU_ONLY = "CPU_ONLY"  # JAX resolved no accelerator; supervision idle
 HEALTHY = "HEALTHY"
 DEGRADED = "DEGRADED"
 LOST = "LOST"
@@ -157,6 +160,7 @@ class DeviceSupervisor:
         config=None,
         canary: Optional[Callable[[], object]] = None,
         expected: Optional[bool] = None,
+        backend: Optional[Backend] = None,
         probe_interval_s: Optional[float] = None,
         probe_timeout_s: Optional[float] = None,
         watchdog_factor: Optional[float] = None,
@@ -216,10 +220,14 @@ class DeviceSupervisor:
             ),
         )
         self._canary = canary or self._default_canary
+        # the backend JAX resolved (nomad_tpu.backend.resolve_backend,
+        # passed in by the server at start); None = this process runs
+        # no kernels (a sequential-oracle server never touches JAX)
+        self.backend = backend
         self.expected = (
             expected
             if expected is not None
-            else self._accelerator_expected()
+            else self._accelerator_expected(backend)
         )
         self._state = HEALTHY if self.expected else CPU_ONLY
         self.backend_epoch = 0
@@ -264,15 +272,6 @@ class DeviceSupervisor:
         self._unhealthy_accum = 0.0
         self._unhealthy_since: Optional[float] = None
         self._since_wall = time.time()
-        # the platform the canary probes: the first non-cpu platform
-        # named in JAX_PLATFORMS (None = jax's default device, which on
-        # CPU-only test boxes is the cpu backend the faults simulate)
-        plats = [
-            p.strip()
-            for p in os.environ.get("JAX_PLATFORMS", "").split(",")
-            if p.strip() and p.strip() != "cpu"
-        ]
-        self._probe_backend = plats[0] if plats else None
         self._cpu_device = None
         self._register_metrics()
         # happens-before sanitizer (NOMAD_TPU_TSAN=1)
@@ -283,7 +282,7 @@ class DeviceSupervisor:
     # -- construction helpers ------------------------------------------
 
     @staticmethod
-    def _accelerator_expected() -> bool:
+    def _accelerator_expected(backend: Optional[Backend]) -> bool:
         forced = os.environ.get("NOMAD_TPU_SUPERVISOR")
         if forced == "1":
             return True
@@ -293,10 +292,9 @@ class DeviceSupervisor:
             # an armed fault plan simulates an accelerator: the
             # supervisor must be live for the faults to mean anything
             return True
-        from ..device_lock import _cpu_only
-
-        plats = os.environ.get("JAX_PLATFORMS", "")
-        return bool(plats) and not _cpu_only(plats)
+        # what JAX resolved decides, never how JAX_PLATFORMS is spelt:
+        # on a TPU host JAX picks the chip with the variable unset
+        return backend is not None and backend.accelerated
 
     def _register_metrics(self) -> None:
         metrics = self.metrics
@@ -484,12 +482,10 @@ class DeviceSupervisor:
         import jax
         import jax.numpy as jnp
 
-        device = (
-            jax.devices(self._probe_backend)[0]
-            if self._probe_backend
-            else jax.devices()[0]
-        )
-        x = jax.device_put(jnp.ones(8), device)
+        # jax's default device: the accelerator wherever one resolved
+        # (a failed-over pipeline places on the CPU explicitly, so the
+        # canary keeps probing the device it must recover)
+        x = jax.device_put(jnp.ones(8), jax.devices()[0])
         # nomadlint: disable=jit-purity -- deliberate per-probe retrace: the canary must exercise the FULL trace+compile+execute+fetch path each probe (a cached wrapper would skip the compile wedge mode)
         return float(jax.jit(lambda a: a + 1)(x).sum())
 
@@ -794,11 +790,22 @@ class DeviceSupervisor:
         # polls race the probe thread's transitions, and a torn
         # multi-field view (state from before a failover, epoch from
         # after) would mislead exactly the operator debugging it
+        backend = self.backend
         return {
             "enabled": self.expected,
             "state": self._state,
             "state_code": STATE_CODES[self._state],
-            "backend": "cpu" if self.failed_over() else "device",
+            # what JAX resolved at server start (None: no kernel path)
+            "platform": backend.platform if backend else None,
+            "device_kind": backend.device_kind if backend else None,
+            "device_count": backend.device_count if backend else 0,
+            # where launches go right now: the resolved platform, the
+            # cpu while failed over, "none" without a kernel path
+            "backend": (
+                "cpu"
+                if self.failed_over()
+                else backend.platform if backend else "none"
+            ),
             "backend_epoch": self.backend_epoch,
             # False until the device answered once; deadlines are
             # floored to init_grace_s while it is

@@ -1,14 +1,12 @@
 """Sacrificial-thread bounded calls + per-stage watchdog budgets.
 
 A wedged PJRT client does not raise — it *blocks*, indefinitely, inside
-a C extension call no Python-level timeout can interrupt (that is how
-four bench rounds were lost: ``BENCH_r05.json`` rc=2, "backend init
-blocked (no error raised)").  The only robust in-process containment is
-to run the possibly-wedging call on a disposable thread and, when the
-deadline passes, *abandon* the thread: the caller gets a
-``DeviceTimeout`` and keeps scheduling; the sacrificial thread stays
-parked inside the wedged call until process exit (it is a daemon and
-holds no locks the pipeline needs).
+a C extension call no Python-level timeout can interrupt.  The only
+robust in-process containment is to run the possibly-wedging call on a
+disposable thread and, when the deadline passes, *abandon* the thread:
+the caller gets a ``DeviceTimeout`` and keeps scheduling; the
+sacrificial thread stays parked inside the wedged call until process
+exit (it is a daemon and holds no locks the pipeline needs).
 
 Budgets come from an EWMA of the stage's own observed latency — a
 launch that exceeds its historical cost by ``factor`` is wedged, not

@@ -372,7 +372,8 @@ ENV_KNOBS: Dict[str, EnvKnob] = {
     "NOMAD_TPU_SUPERVISOR": EnvKnob(
         "auto", "nomad_tpu/device/supervisor.py",
         "1 forces device supervision on, 0 off (default: on when "
-        "JAX_PLATFORMS names a non-cpu backend or a fault is armed)",
+        "JAX resolved an accelerator at server start, whatever "
+        "JAX_PLATFORMS holds, or a fault is armed)",
     ),
     "NOMAD_TPU_PROBE_INTERVAL_S": EnvKnob(
         "30", "nomad_tpu/device/supervisor.py",
@@ -416,16 +417,6 @@ ENV_KNOBS: Dict[str, EnvKnob] = {
         "600", "nomad_tpu/device/preflight.py",
         "total preflight retry budget for "
         "`python -m nomad_tpu.device.preflight`",
-    ),
-    # -- device lock (nomad_tpu/device_lock.py) -----------------------
-    "NOMAD_TPU_DEVICE_LOCK": EnvKnob(
-        "/tmp/nomad_tpu_device.lock", "nomad_tpu/device_lock.py",
-        "cross-process accelerator lockfile path",
-    ),
-    "NOMAD_TPU_DEVICE_LOCK_WAIT": EnvKnob(
-        "block", "nomad_tpu/device_lock.py",
-        "seconds to wait for the device lock before giving up "
-        "(default: block forever)",
     ),
     # -- client -------------------------------------------------------
     "NOMAD_TPU_EXEC_ISOLATION": EnvKnob(

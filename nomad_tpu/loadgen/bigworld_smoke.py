@@ -240,9 +240,7 @@ def _child_env(
     procs: int,
     devices_per_proc: int,
 ) -> dict:
-    from ..device_lock import scrub_accelerator_env
-
-    env = scrub_accelerator_env()
+    env = dict(os.environ)
     # hermetic world: the parent shell's knobs must not reshape the
     # gate — children see ONLY the pinned set below
     for key in [k for k in env if k.startswith("NOMAD_TPU_")]:

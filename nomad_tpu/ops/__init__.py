@@ -8,5 +8,12 @@ deterministic limited-walk selection that reproduces the reference's
 GenericStack.Select bit-for-bit.  `batch.py` scans/vmaps the kernel over
 picks and evals for throughput.
 """
-from .score import score_and_select, ScoreInputs  # noqa: F401
-from .constraints import MaskCompiler  # noqa: F401
+from ..backend import ensure_compile_cache
+
+# every kernel module lives in this package, so every path that jits
+# passes through here first: the persistent compile cache is placed
+# before anything can compile
+ensure_compile_cache()
+
+from .score import score_and_select, ScoreInputs  # noqa: E402,F401
+from .constraints import MaskCompiler  # noqa: E402,F401

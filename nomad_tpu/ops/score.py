@@ -268,8 +268,7 @@ def score_and_select(inp: ScoreInputs, spread_fit: bool = False):
 def score_and_select_packed(inp: ScoreInputs, spread_fit: bool = False):
     """score_and_select with all outputs packed into ONE i32[2] array
     ([chosen_row, pulls]) so the host pays a single device->host sync
-    per select — each fetch is a full round trip on tunneled
-    accelerators."""
+    per select — each fetch is a full device round trip."""
     chosen_row, _best, _n, pulls = score_and_select(
         inp, spread_fit=spread_fit
     )

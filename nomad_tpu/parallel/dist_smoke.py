@@ -1,5 +1,7 @@
 """Two-process distributed smoke: the tier-1-hermetic proof that the
-multi-host mesh actually works, on nothing but the CPU backend.
+multi-host mesh actually works, on nothing but the CPU backend.  A
+CPU-only CI harness: every child is pinned to ``JAX_PLATFORMS=cpu``
+(a chip belongs to one process, so this is never run against one).
 
 ``python -m nomad_tpu.parallel.dist_smoke`` spawns N local worker
 processes (default 2), wires them into one jax.distributed world via
@@ -579,8 +581,6 @@ def launch(
     timeout — a collective deadlock must fail the gate, not hang it."""
     import tempfile
 
-    from ..device_lock import scrub_accelerator_env
-
     port = _free_port()
     repo_root = os.path.dirname(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -589,7 +589,7 @@ def launch(
     children: List[subprocess.Popen] = []
     outs = []
     for rank in range(procs):
-        env = scrub_accelerator_env()
+        env = dict(os.environ)
         # hermetic world: the parent shell's NOMAD_TPU_* knobs must
         # not reshape (or fail) the deterministic gate — children see
         # ONLY the pinned knob set below

@@ -30,27 +30,17 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:
-    from jax import shard_map as _shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
+from ..ops.batch import BatchInputs, plan_picks
+from ..ops.score import ScoreInputs, _limited_walk_argmax, _score_vectors
 
 
 def shard_map(f=None, **kwargs):
     """shard_map with replication checking off: the selection walk's
     outputs are replicated by construction (post-all-gather), which the
     static varying-axes inference cannot prove."""
-    for flag in ("check_vma", "check_rep"):
-        try:
-            return _shard_map(f, **kwargs, **{flag: False})
-        except TypeError:
-            continue
-    return _shard_map(f, **kwargs)
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from ..ops.batch import BatchInputs, plan_picks
-from ..ops.score import ScoreInputs, _limited_walk_argmax, _score_vectors
+    return jax.shard_map(f, check_vma=False, **kwargs)
 
 
 # -- multi-host distribution (NOMAD_TPU_DIST_*) ------------------------
@@ -149,10 +139,14 @@ def distributed_init() -> bool:
         return False
     if _dist_initialized:
         return True
-    from ..device_lock import _cpu_only
-
+    # the one decision that must read JAX_PLATFORMS itself: the
+    # collectives implementation is picked BEFORE the backend exists,
+    # so there is nothing resolved to ask yet
     plats = os.environ.get("JAX_PLATFORMS", "")
-    if not plats or _cpu_only(plats):
+    cpu_only = bool(plats) and (
+        set(p.strip() for p in plats.split(",")) <= {"cpu"}
+    )
+    if not plats or cpu_only:
         # CPU multiprocess computations are only implemented over
         # gloo; must be picked before the backend client exists.
         # Unset JAX_PLATFORMS counts too — a host whose backend
@@ -164,7 +158,7 @@ def distributed_init() -> bool:
                 "jax_cpu_collectives_implementation", "gloo"
             )
         except Exception:
-            if _cpu_only(plats):
+            if cpu_only:
                 # an explicitly-CPU world cannot collectivize
                 # without gloo — fail now, not mid-chain
                 raise
@@ -236,26 +230,17 @@ def make_mesh(
     eval_axis: Optional[int] = None,
     backend: Optional[str] = None,
 ) -> Mesh:
-    """Build an (evals, nodes) mesh over the available devices.  When the
-    default backend has fewer devices than requested, fall back to the
-    CPU backend (virtual host devices for sharding tests).
+    """Build an (evals, nodes) mesh over the backend's devices — the
+    default backend's unless ``backend`` names another.  A backend
+    with fewer devices than requested yields the smaller mesh (callers
+    check ``mesh.devices.size``); no other backend is substituted.
 
     With the NOMAD_TPU_DIST_* knobs set, `distributed_init` joins the
     multi-process world first and ``jax.devices()`` returns EVERY
     host's devices — the node axis then spans the whole pod and the
     same sharded programs run unchanged across processes."""
-    from ..device_lock import align_jax_platforms
-
-    align_jax_platforms()
     distributed_init()
     devices = jax.devices(backend) if backend else jax.devices()
-    if n_devices is not None and len(devices) < n_devices:
-        try:
-            cpu = jax.devices("cpu")
-            if len(cpu) >= n_devices:
-                devices = cpu
-        except RuntimeError:
-            pass
     if n_devices is not None:
         devices = devices[:n_devices]
     n = len(devices)

@@ -203,12 +203,29 @@ class Server:
         # accelerator supervisor: owns device liveness (health probes,
         # launch watchdogs, hot CPU failover) for every worker.  Built
         # BEFORE the workers so they can subscribe to backend
-        # transitions; idle (no thread) on CPU-only deployments unless
-        # forced via NOMAD_TPU_SUPERVISOR=1 or an armed NOMAD_TPU_FAULT
+        # transitions; idle (no thread) where JAX resolved the CPU
+        # unless forced via NOMAD_TPU_SUPERVISOR=1 or an armed
+        # NOMAD_TPU_FAULT.  The batch pipeline's backend is resolved
+        # HERE, once (PJRT init is paid now, not inside the first
+        # flush): what JAX initialised decides supervision, donation
+        # and the /v1/device payload — never how JAX_PLATFORMS is
+        # spelt.  A sequential-oracle server never touches JAX.
         from ..device import DeviceSupervisor
 
+        backend = None
+        if batch_pipeline:
+            from ..backend import resolve_backend
+
+            backend = resolve_backend()
+            LOG.info(
+                "batch pipeline backend: platform=%s device_kind=%s "
+                "devices=%d",
+                backend.platform, backend.device_kind,
+                backend.device_count,
+            )
         self.device_supervisor = DeviceSupervisor(
-            metrics=self.metrics, config=device_config
+            metrics=self.metrics, config=device_config,
+            backend=backend,
         )
         self.broker = EvalBroker(nack_timeout=nack_timeout)
         # lost-eval accounting: the broker is constructed without a
