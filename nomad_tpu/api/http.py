@@ -23,6 +23,7 @@ from typing import Any, Dict, Optional, Tuple
 from urllib.parse import parse_qs, urlencode, urlparse
 
 from ..structs import DrainStrategy, SchedulerConfiguration, PreemptionConfig
+from ..trace import TRACE
 from .codec import (
     alloc_to_dict,
     deployment_to_dict,
@@ -556,9 +557,13 @@ class APIHandler(BaseHTTPRequestHandler):
             if method in ("POST", "PUT"):
                 self._check_acl("submit-job", ns)
                 body = self._body()
-                raw_job = body.get("Job") or body.get("job") or body
-                job = job_from_dict(raw_job)
-                ev = srv.register_job(job)
+                # flight recorder: the eval's trace begins here, where
+                # the request is parsed (`ingress.register` ends where
+                # the broker takes the eval)
+                with TRACE.ingress():
+                    raw_job = body.get("Job") or body.get("job") or body
+                    job = job_from_dict(raw_job)
+                    ev = srv.register_job(job)
                 self._respond(
                     {"EvalID": ev.id if ev else "", "JobModifyIndex": job.modify_index}
                 )
@@ -579,10 +584,11 @@ class APIHandler(BaseHTTPRequestHandler):
             if method in ("POST", "PUT"):
                 self._check_acl("submit-job", ns)
                 body = self._body()
-                raw_job = body.get("Job") or body.get("job") or body
-                job = job_from_dict(raw_job)
-                job.id = job_id
-                ev = srv.register_job(job)
+                with TRACE.ingress():
+                    raw_job = body.get("Job") or body.get("job") or body
+                    job = job_from_dict(raw_job)
+                    job.id = job_id
+                    ev = srv.register_job(job)
                 self._respond({"EvalID": ev.id if ev else ""})
                 return True
             if method == "DELETE":
@@ -1964,8 +1970,6 @@ class APIHandler(BaseHTTPRequestHandler):
         # traces carry job ids and node ids across every namespace
         if path == "/v1/traces" and method == "GET":
             self._check_acl("agent:read")
-            from ..trace import TRACE
-
             slow_ms = None
             if "slow_ms" in q:
                 try:
@@ -1989,8 +1993,6 @@ class APIHandler(BaseHTTPRequestHandler):
         m = re.fullmatch(r"/v1/traces/([^/]+)", path)
         if m and method == "GET":
             self._check_acl("agent:read")
-            from ..trace import TRACE
-
             trace = TRACE.get(m.group(1))
             if trace is None:
                 raise HTTPError(404, "trace not found")

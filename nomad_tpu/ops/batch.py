@@ -377,7 +377,16 @@ def _run_picks(
     Internally the scan runs in per-pick/per-group space (see
     TGInputs): single-task-group callers (``tg is None``) normalize to
     T=1 with every pick routed to slot 0 — numerically identical to
-    the historical single-group kernel."""
+    the historical single-group kernel.
+
+    The phases of a pick-step carry ``jax.named_scope`` names —
+    ``score`` (fit, feasibility and the score terms), ``spread`` (the
+    spread boost), ``walk`` (the rotating limited walk and the
+    winner), ``usage_update`` (evictions before the pick, the winner's
+    usage after it) — so an operation of a device profile (a
+    ``%while`` or a fusion) can be put to a phase from its metadata.
+    Scopes are metadata only: the jit names and the compiled program
+    are what they were."""
     if wanted is None:
         wanted = jnp.asarray(n_picks, jnp.int32)
     dtype = cpu_total.dtype
@@ -427,14 +436,17 @@ def _run_picks(
         # per-step gathers): desired/penalty per node are static,
         # used-per-node recomputes from the (S, V+1) carries each step
         _S, V1 = spread.desired.shape
-        codes_sp = jnp.take(spread.codes, perm, axis=1)  # (S, C)
-        onehot_p = jax.nn.one_hot(codes_sp, V1, dtype=dtype)
-        desired_node = jnp.einsum(
-            "scv,sv->sc", onehot_p, spread.desired
-        )
-        penalty_node = codes_sp == (V1 - 1)
-        safe_desired = jnp.where(desired_node != 0, desired_node, 1.0)
-        spread_existing = spread.used0.astype(dtype)  # (S, V+1)
+        with jax.named_scope("spread"):
+            codes_sp = jnp.take(spread.codes, perm, axis=1)  # (S, C)
+            onehot_p = jax.nn.one_hot(codes_sp, V1, dtype=dtype)
+            desired_node = jnp.einsum(
+                "scv,sv->sc", onehot_p, spread.desired
+            )
+            penalty_node = codes_sp == (V1 - 1)
+            safe_desired = jnp.where(
+                desired_node != 0, desired_node, 1.0
+            )
+            spread_existing = spread.used0.astype(dtype)  # (S, V+1)
 
     def step(carry, pick_idx):
         cpu_used = carry["cpu"]
@@ -454,202 +466,208 @@ def _run_picks(
         active = (pick_idx < wanted) & ~dead[t]
         penalty_vec = penalty_p
         app = jnp.asarray(False)
-        if deltas is not None:
-            erow = deltas.evict_rows[pick_idx]
-            epos = jnp.argmax(perm == erow)
-            app = active & (erow >= 0)
-            zf = jnp.asarray(0.0, dtype)
-            cpu_used = cpu_used.at[epos].add(
-                jnp.where(app, deltas.evict_cpu[pick_idx], zf)
-            )
-            mem_used = mem_used.at[epos].add(
-                jnp.where(app, deltas.evict_mem[pick_idx], zf)
-            )
-            disk_used = disk_used.at[epos].add(
-                jnp.where(app, deltas.evict_disk[pick_idx], zf)
-            )
-            collisions = collisions.at[t, epos].add(
-                jnp.where(app, deltas.evict_coll[pick_idx], 0)
-            )
-            prow = deltas.penalty_rows[pick_idx]  # (K,)
-            penalty_vec = penalty_vec | jnp.any(
-                perm[:, None] == prow[None, :], axis=1
-            )
-            if spread is not None:
-                # the evicted alloc's value slot gains one cleared use
-                # (its stop is staged into plan.node_update just before
-                # this pick — propertyset counts it as cleared).  A
-                # destructive eviction replaces an alloc of the PICKING
-                # group, so group-scoped slots of other groups are
-                # untouched
-                evict_slot = spread.codes[:, jnp.maximum(erow, 0)]
-                app_slot = jnp.asarray(app)
-                if spread.group is not None:
-                    app_slot = (app & (spread.group == t))[:, None]
-                spread_clr = spread_clr + jnp.where(
-                    app_slot,
-                    jax.nn.one_hot(evict_slot, V1, dtype=dtype),
-                    0.0,
+        with jax.named_scope("usage_update"):
+            if deltas is not None:
+                erow = deltas.evict_rows[pick_idx]
+                epos = jnp.argmax(perm == erow)
+                app = active & (erow >= 0)
+                zf = jnp.asarray(0.0, dtype)
+                cpu_used = cpu_used.at[epos].add(
+                    jnp.where(app, deltas.evict_cpu[pick_idx], zf)
                 )
-        ask_cpu_k = tg.ask_cpu[pick_idx]
-        ask_mem_k = tg.ask_mem[pick_idx]
-        ask_disk_k = tg.ask_disk[pick_idx]
-        coll_t = collisions[t]  # this pick's group's collision row
-        cpu_after = cpu_used + ask_cpu_k
-        mem_after = mem_used + ask_mem_k
-        disk_after = disk_used + ask_disk_k
-        fit = (
-            (cpu_after <= cpu_total_p)
-            & (mem_after <= mem_total_p)
-            & (disk_after <= disk_total_p)
-        )
-        # distinct_hosts (feasible.go:470 DistinctHostsIterator,
-        # both scopes): the collision carries ARE the proposed-
-        # allocs-per-node counts — live allocs at the snapshot, +1
-        # per pick, -1 per staged destructive eviction.  JOB-level
-        # scope blocks on any proposed job alloc: the summed carries
-        # plus occ_extra (groups placing nothing this eval).
-        # GROUP-level scope blocks only on the picking group's own
-        # carry; multi-group jobs with ONLY group-level constraints
-        # ship dh_tg and leave inp.distinct_hosts False.
-        occupancy = collisions.sum(axis=0)
-        if occ_extra_p is not None:
-            occupancy = occupancy + occ_extra_p
-        feasible = feas_tp[t] & fit & ~(
-            inp.distinct_hosts & (occupancy > 0)
-        )
-        if dh_tg is not None:
-            feasible = feasible & ~(dh_tg[t] & (coll_t > 0))
-        if ports_on:
-            # static-port collision: skipped WITHOUT consuming a
-            # walk-limit slot (rank.go network path `continue`) —
-            # exactly how the walk treats infeasible nodes
-            ask_t_ports = port_ask[t]  # (Q,)
-            ports_c = carry["ports"]
-            collide = jnp.any(
-                ports_c & ask_t_ports[:, None], axis=0
+                mem_used = mem_used.at[epos].add(
+                    jnp.where(app, deltas.evict_mem[pick_idx], zf)
+                )
+                disk_used = disk_used.at[epos].add(
+                    jnp.where(app, deltas.evict_disk[pick_idx], zf)
+                )
+                collisions = collisions.at[t, epos].add(
+                    jnp.where(app, deltas.evict_coll[pick_idx], 0)
+                )
+                prow = deltas.penalty_rows[pick_idx]  # (K,)
+                penalty_vec = penalty_vec | jnp.any(
+                    perm[:, None] == prow[None, :], axis=1
+                )
+                if spread is not None:
+                    # the evicted alloc's value slot gains one cleared use
+                    # (its stop is staged into plan.node_update just before
+                    # this pick — propertyset counts it as cleared).  A
+                    # destructive eviction replaces an alloc of the PICKING
+                    # group, so group-scoped slots of other groups are
+                    # untouched
+                    evict_slot = spread.codes[:, jnp.maximum(erow, 0)]
+                    app_slot = jnp.asarray(app)
+                    if spread.group is not None:
+                        app_slot = (app & (spread.group == t))[:, None]
+                    spread_clr = spread_clr + jnp.where(
+                        app_slot,
+                        jax.nn.one_hot(evict_slot, V1, dtype=dtype),
+                        0.0,
+                    )
+        with jax.named_scope("score"):
+            ask_cpu_k = tg.ask_cpu[pick_idx]
+            ask_mem_k = tg.ask_mem[pick_idx]
+            ask_disk_k = tg.ask_disk[pick_idx]
+            coll_t = collisions[t]  # this pick's group's collision row
+            cpu_after = cpu_used + ask_cpu_k
+            mem_after = mem_used + ask_mem_k
+            disk_after = disk_used + ask_disk_k
+            fit = (
+                (cpu_after <= cpu_total_p)
+                & (mem_after <= mem_total_p)
+                & (disk_after <= disk_total_p)
             )
-            feasible = feasible & ~collide
-        if devs_on:
-            # device capacity: feasible only where every ASKED
-            # signature still has enough free instances (the
-            # DeviceChecker runs pre-binpack, so shortage is plain
-            # infeasibility in the walk arithmetic).  Unasked slots
-            # (ask 0) must not couple the pick to unrelated pools
-            ask_t_dev = dev_ask[t]  # (D,)
-            devs_c = carry["dev"]
-            feasible = feasible & jnp.all(
-                (ask_t_dev[:, None] == 0)
-                | (devs_c >= ask_t_dev[:, None]),
-                axis=0,
+            # distinct_hosts (feasible.go:470 DistinctHostsIterator,
+            # both scopes): the collision carries ARE the proposed-
+            # allocs-per-node counts — live allocs at the snapshot, +1
+            # per pick, -1 per staged destructive eviction.  JOB-level
+            # scope blocks on any proposed job alloc: the summed carries
+            # plus occ_extra (groups placing nothing this eval).
+            # GROUP-level scope blocks only on the picking group's own
+            # carry; multi-group jobs with ONLY group-level constraints
+            # ship dh_tg and leave inp.distinct_hosts False.
+            occupancy = collisions.sum(axis=0)
+            if occ_extra_p is not None:
+                occupancy = occupancy + occ_extra_p
+            feasible = feas_tp[t] & fit & ~(
+                inp.distinct_hosts & (occupancy > 0)
             )
+            if dh_tg is not None:
+                feasible = feasible & ~(dh_tg[t] & (coll_t > 0))
+            if ports_on:
+                # static-port collision: skipped WITHOUT consuming a
+                # walk-limit slot (rank.go network path `continue`) —
+                # exactly how the walk treats infeasible nodes
+                ask_t_ports = port_ask[t]  # (Q,)
+                ports_c = carry["ports"]
+                collide = jnp.any(
+                    ports_c & ask_t_ports[:, None], axis=0
+                )
+                feasible = feasible & ~collide
+            if devs_on:
+                # device capacity: feasible only where every ASKED
+                # signature still has enough free instances (the
+                # DeviceChecker runs pre-binpack, so shortage is plain
+                # infeasibility in the walk arithmetic).  Unasked slots
+                # (ask 0) must not couple the pick to unrelated pools
+                ask_t_dev = dev_ask[t]  # (D,)
+                devs_c = carry["dev"]
+                feasible = feasible & jnp.all(
+                    (ask_t_dev[:, None] == 0)
+                    | (devs_c >= ask_t_dev[:, None]),
+                    axis=0,
+                )
 
-        free_cpu = 1.0 - cpu_after / safe_cpu
-        free_mem = 1.0 - mem_after / safe_mem
-        # canonical f32-rounded exponential (structs/funcs.py _pow10)
-        base = _pow10_f32(free_cpu, dtype) + _pow10_f32(free_mem, dtype)
-        if spread_fit:
-            fitness = jnp.clip(base - 2.0, 0.0, 18.0)
-        else:
-            fitness = jnp.clip(20.0 - base, 0.0, 18.0)
-        score_sum = fitness / 18.0
-        count = jnp.ones_like(score_sum)
+            free_cpu = 1.0 - cpu_after / safe_cpu
+            free_mem = 1.0 - mem_after / safe_mem
+            # canonical f32-rounded exponential (structs/funcs.py _pow10)
+            base = _pow10_f32(free_cpu, dtype) + _pow10_f32(free_mem, dtype)
+            if spread_fit:
+                fitness = jnp.clip(base - 2.0, 0.0, 18.0)
+            else:
+                fitness = jnp.clip(20.0 - base, 0.0, 18.0)
+            score_sum = fitness / 18.0
+            count = jnp.ones_like(score_sum)
 
-        has_coll = coll_t > 0
-        anti = jnp.where(
-            has_coll,
-            -(coll_t.astype(dtype) + 1.0)
-            / tg.desired_count[pick_idx].astype(dtype),
-            0.0,
-        )
-        score_sum = score_sum + anti
-        count = count + has_coll.astype(dtype)
-        score_sum = score_sum - penalty_vec.astype(dtype)
-        count = count + penalty_vec.astype(dtype)
-        aff_k = aff_tp[t]
-        has_aff = aff_k != 0.0
-        score_sum = score_sum + jnp.where(has_aff, aff_k, 0.0)
-        count = count + has_aff.astype(dtype)
-        if dev_aff is not None:
-            # device-affinity match fraction (rank.go:460): appended
-            # for EVERY scored node when the ask carries affinities
-            # with non-zero total weight — even a 0.0 value enters
-            # the mean, unlike the node-affinity component
-            d_on = dev_aff_on[t]
-            score_sum = score_sum + jnp.where(
-                d_on, dev_aff_p[t], 0.0
+            has_coll = coll_t > 0
+            anti = jnp.where(
+                has_coll,
+                -(coll_t.astype(dtype) + 1.0)
+                / tg.desired_count[pick_idx].astype(dtype),
+                0.0,
             )
-            count = count + d_on.astype(dtype)
-        if spread is not None:
-            # boost per stanza: ((desired - (used+1)) / desired) * w,
-            # -1.0 on the penalty slot (spread.py next()); appended
-            # to the score list only when the total is non-zero —
-            # shared implementation with the sharded planner.  For
-            # multi-group evals only the picking group's slots score
-            # (group-scoped propertysets)
-            slot_active = spread.active
-            if spread.group is not None:
-                slot_active = slot_active & (spread.group == t)
-            spread_total = spread_contribution(
-                onehot_p, desired_node, penalty_node, safe_desired,
-                spread_existing, spread_prop, spread_clr,
-                spread.weight, slot_active, spread.even, dtype,
-            )
-            has_spread = spread_total != 0.0
-            score_sum = score_sum + spread_total
-            count = count + has_spread.astype(dtype)
-        final = score_sum / count
+            score_sum = score_sum + anti
+            count = count + has_coll.astype(dtype)
+            score_sum = score_sum - penalty_vec.astype(dtype)
+            count = count + penalty_vec.astype(dtype)
+            aff_k = aff_tp[t]
+            has_aff = aff_k != 0.0
+            score_sum = score_sum + jnp.where(has_aff, aff_k, 0.0)
+            count = count + has_aff.astype(dtype)
+            if dev_aff is not None:
+                # device-affinity match fraction (rank.go:460): appended
+                # for EVERY scored node when the ask carries affinities
+                # with non-zero total weight — even a 0.0 value enters
+                # the mean, unlike the node-affinity component
+                d_on = dev_aff_on[t]
+                score_sum = score_sum + jnp.where(
+                    d_on, dev_aff_p[t], 0.0
+                )
+                count = count + d_on.astype(dtype)
+        with jax.named_scope("spread"):
+            if spread is not None:
+                # boost per stanza: ((desired - (used+1)) / desired) * w,
+                # -1.0 on the penalty slot (spread.py next()); appended
+                # to the score list only when the total is non-zero —
+                # shared implementation with the sharded planner.  For
+                # multi-group evals only the picking group's slots score
+                # (group-scoped propertysets)
+                slot_active = spread.active
+                if spread.group is not None:
+                    slot_active = slot_active & (spread.group == t)
+                spread_total = spread_contribution(
+                    onehot_p, desired_node, penalty_node, safe_desired,
+                    spread_existing, spread_prop, spread_clr,
+                    spread.weight, slot_active, spread.even, dtype,
+                )
+                has_spread = spread_total != 0.0
+                score_sum = score_sum + spread_total
+                count = count + has_spread.astype(dtype)
+        with jax.named_scope("score"):
+            final = score_sum / count
 
-        win, any_emitted, step_pulls = _walk(
-            final, feasible, offset, tg.limit[pick_idx], n_candidates
-        )
-        ok = active & any_emitted
-        dead = dead.at[t].set(dead[t] | (active & ~any_emitted))
-        row = jnp.where(ok, perm[win], NO_NODE)
-        pulls = jnp.where(active, step_pulls, 0)
-        safe_win = jnp.where(ok, win, 0)
-        upd = lambda arr, delta: arr.at[safe_win].add(
-            jnp.where(ok, delta, jnp.zeros_like(delta))
-        )
-        cpu_used = upd(cpu_used, ask_cpu_k)
-        mem_used = upd(mem_used, ask_mem_k)
-        disk_used = upd(disk_used, ask_disk_k)
-        collisions = collisions.at[t, safe_win].add(
-            jnp.where(ok, 1, 0)
-        )
-        offset = jnp.mod(offset + pulls, n_candidates)
-        out = {
-            "cpu": cpu_used,
-            "mem": mem_used,
-            "disk": disk_used,
-            "coll": collisions,
-            "off": offset,
-            "dead": dead,
-        }
-        if ports_on:
-            # the winner occupies its group's static ports for every
-            # later pick (and, chained, every later eval)
-            win_mask = ok & (
-                jnp.arange(ports_c.shape[1]) == safe_win
+        with jax.named_scope("walk"):
+            win, any_emitted, step_pulls = _walk(
+                final, feasible, offset, tg.limit[pick_idx], n_candidates
             )
-            out["ports"] = ports_c | (
-                ask_t_ports[:, None] & win_mask[None, :]
+        with jax.named_scope("usage_update"):
+            ok = active & any_emitted
+            dead = dead.at[t].set(dead[t] | (active & ~any_emitted))
+            row = jnp.where(ok, perm[win], NO_NODE)
+            pulls = jnp.where(active, step_pulls, 0)
+            safe_win = jnp.where(ok, win, 0)
+            upd = lambda arr, delta: arr.at[safe_win].add(
+                jnp.where(ok, delta, jnp.zeros_like(delta))
             )
-        if devs_on:
-            out["dev"] = devs_c.at[:, safe_win].add(
-                jnp.where(ok, -ask_t_dev, 0)
+            cpu_used = upd(cpu_used, ask_cpu_k)
+            mem_used = upd(mem_used, ask_mem_k)
+            disk_used = upd(disk_used, ask_disk_k)
+            collisions = collisions.at[t, safe_win].add(
+                jnp.where(ok, 1, 0)
             )
-        if spread is not None:
-            # the placed node's value slot gains one proposed use per
-            # stanza — of the PICKING group only, when group-scoped
-            slot_ok = jnp.asarray(ok)
-            if spread.group is not None:
-                slot_ok = ok & (spread.group == t)
-                slot_ok = slot_ok[:, None]
-            out["spread_prop"] = spread_prop + jnp.where(
-                slot_ok, onehot_p[:, safe_win, :], 0.0
-            )
-            out["spread_clr"] = spread_clr
+            offset = jnp.mod(offset + pulls, n_candidates)
+            out = {
+                "cpu": cpu_used,
+                "mem": mem_used,
+                "disk": disk_used,
+                "coll": collisions,
+                "off": offset,
+                "dead": dead,
+            }
+            if ports_on:
+                # the winner occupies its group's static ports for every
+                # later pick (and, chained, every later eval)
+                win_mask = ok & (
+                    jnp.arange(ports_c.shape[1]) == safe_win
+                )
+                out["ports"] = ports_c | (
+                    ask_t_ports[:, None] & win_mask[None, :]
+                )
+            if devs_on:
+                out["dev"] = devs_c.at[:, safe_win].add(
+                    jnp.where(ok, -ask_t_dev, 0)
+                )
+            if spread is not None:
+                # the placed node's value slot gains one proposed use per
+                # stanza — of the PICKING group only, when group-scoped
+                slot_ok = jnp.asarray(ok)
+                if spread.group is not None:
+                    slot_ok = ok & (spread.group == t)
+                    slot_ok = slot_ok[:, None]
+                out["spread_prop"] = spread_prop + jnp.where(
+                    slot_ok, onehot_p[:, safe_win, :], 0.0
+                )
+                out["spread_clr"] = spread_clr
         return out, (row, app, pulls)
 
     carry0 = {
@@ -974,11 +992,18 @@ def chained_plan_picks_cols(
         oc = next(it) if pattern[8] else None
         dhg = next(it) if pattern[9] else None
         if p is not None:
-            used = (
-                used[0].at[p.rows].add(p.cpu.astype(used[0].dtype)),
-                used[1].at[p.rows].add(p.mem.astype(used[1].dtype)),
-                used[2].at[p.rows].add(p.disk.astype(used[2].dtype)),
-            )
+            with jax.named_scope("usage_update"):
+                used = (
+                    used[0].at[p.rows].add(
+                        p.cpu.astype(used[0].dtype)
+                    ),
+                    used[1].at[p.rows].add(
+                        p.mem.astype(used[1].dtype)
+                    ),
+                    used[2].at[p.rows].add(
+                        p.disk.astype(used[2].dtype)
+                    ),
+                )
         tg_in = TGInputs(
             tg_idx=b.tg_idx,
             feasible=b.feasible,

@@ -89,7 +89,7 @@ from ..decisions import DECISIONS
 from ..explain import EXPLAIN
 from ..raft import NotLeaderError
 from ..raft import chaos as _chaos
-from ..trace import TRACE
+from ..trace import TRACE, cpu_ms_since, profiler_annotation
 from .worker import Worker
 
 BATCH_MAX = 64
@@ -976,6 +976,10 @@ class BatchWorker(Worker):
             "replay": 0.0,
             "sequential": 0.0,
         }
+        # when the chunk chain last went from a launch in flight to
+        # none (None while one is): the 0<->1 transition clock behind
+        # `batch_worker.device_unfed_ms`
+        self._unfed_since: Optional[float] = None
         # happens-before sanitizer (NOMAD_TPU_TSAN=1): instruments
         # as family "Worker" — the flowgraph collapses BatchWorker
         # onto its root class, and the SHARED_STATE_ALLOWLIST keys
@@ -1242,26 +1246,53 @@ class BatchWorker(Worker):
                 exemplar=exemplar,
             )
 
+    def _device_fed(self, now: float) -> None:
+        """The chunk chain goes from no launch in flight to one:
+        the time since it last had none was time in which the device
+        had nothing of this worker's to run.  Over a window the
+        counter's complement bounds the device's busy time from
+        above (the device can only be busy while a launch is in
+        flight), where a profile sees a few seconds."""
+        since = self._unfed_since
+        self._unfed_since = None
+        metrics = getattr(self.server, "metrics", None)
+        if since is not None and metrics is not None:
+            metrics.incr(
+                "batch_worker.device_unfed_ms",
+                (now - since) * 1000.0,
+            )
+
+    def _device_unfed(self) -> None:
+        """The last launch in flight was fetched (or lost)."""
+        import time as _time
+
+        self._unfed_since = _time.monotonic()
+
     def _observe_chunk(
         self, stage: str, run, base: int, c0: int, c1_real: int,
         t0: float, dt: float, **attrs,
-    ) -> None:
+    ) -> List[Optional[int]]:
         """Observe a chunk-wide stage interval and attribute it to
         every member eval's trace: first member as the metrics
         exemplar, and a per-member span carrying its chain position
         plus the membership count (so trace aggregations can divide
         the shared dt back out to match the timings accounting).
-        ``base`` is the run index of the chunk's arena's eval 0."""
+        ``base`` is the run index of the chunk's arena's eval 0.
+        Returns each member's span id on its own trace: the cause of
+        the work the stage hands on (a fetch's rows go to the replay
+        pool)."""
         chunk_evs = [run[base + e][0] for e in range(c0, c1_real)]
         self._observe(
             stage, dt,
             exemplar=chunk_evs[0].id if chunk_evs else None,
         )
-        for pos, c_ev in enumerate(chunk_evs):
+        return [
             TRACE.add_span(
                 c_ev.id, f"batch_worker.{stage}", t0, dt,
                 chain_pos=c0 + pos, members=len(chunk_evs), **attrs,
             )
+            for pos, c_ev in enumerate(chunk_evs)
+        ]
 
     def _sample_eval_latency(self, ev: Evaluation) -> None:
         """Per-eval service latency (dequeue -> processed), the
@@ -1591,9 +1622,6 @@ class BatchWorker(Worker):
             ) * self._replay_ewma_ms
             if est <= self.latency_budget_ms:
                 cap = c
-        metrics = getattr(self.server, "metrics", None)
-        if metrics is not None:
-            metrics.set_gauge("batch_worker.adaptive_cap", cap)
         if DECISIONS.enabled and cap != self._last_adaptive_cap:
             self._last_adaptive_cap = cap
             self._record_decision(
@@ -1987,6 +2015,9 @@ class BatchWorker(Worker):
                 continue
             # ---- prescore pipeline: assemble -> launch -> fetch ----
             t0 = _time.monotonic()
+            # (a chunk-wide stage reads the CPU clock when its first
+            # member's trace samples CPU time)
+            asm_cpu0 = TRACE.cpu_clock(run[idx][0].id)
             # the backend this chain's inputs are staged for: a
             # supervisor flip mid-chain (probe-driven failover, or a
             # recovery) strands the staged dev_cols/handles on the old
@@ -2003,13 +2034,14 @@ class BatchWorker(Worker):
             )
             asm = None
             try:
-                asm = self._guard_device(
-                    "assemble",
-                    lambda: self._assemble(
-                        snap, run[idx:j], sims, chunk=chunk_w
-                    ),
-                    exemplar=run[idx][0].id,
-                )
+                with profiler_annotation("batch_worker.assemble"):
+                    asm = self._guard_device(
+                        "assemble",
+                        lambda: self._assemble(
+                            snap, run[idx:j], sims, chunk=chunk_w
+                        ),
+                        exemplar=run[idx][0].id,
+                    )
             except Exception:  # noqa: BLE001
                 self._count("errors")
                 LOG.warning(
@@ -2017,6 +2049,7 @@ class BatchWorker(Worker):
                     len(sims), exc_info=True,
                 )
             asm_dt = _time.monotonic() - t0
+            asm_cpu_ms = cpu_ms_since(asm_cpu0)
             self._observe(
                 "assemble", asm_dt, exemplar=run[idx][0].id
             )
@@ -2028,6 +2061,7 @@ class BatchWorker(Worker):
                 TRACE.add_span(
                     m_ev.id, "batch_worker.assemble", t0, asm_dt,
                     members=len(sims), ok=asm is not None,
+                    cpu_ms=asm_cpu_ms,
                 )
             k = idx
             rescore = False
@@ -2161,21 +2195,25 @@ class BatchWorker(Worker):
                             else "launch"
                         )
                         t0 = _time.monotonic()
+                        cpu0 = TRACE.cpu_clock(run[base + c0][0].id)
                         handle = None
                         try:
-                            handle = self._guard_device(
-                                launch_stage,
-                                lambda: self._launch_chunk(
-                                    casm, c0, c1, carry,
-                                    # first slice of each arena: the
-                                    # cold-compile shield keys on the
-                                    # launch signature, which is
-                                    # identical for that arena's
-                                    # later slices
-                                    check_ready=c0 == 0,
-                                ),
-                                exemplar=run[base + c0][0].id,
-                            )
+                            with profiler_annotation(
+                                f"batch_worker.{launch_stage}"
+                            ):
+                                handle = self._guard_device(
+                                    launch_stage,
+                                    lambda: self._launch_chunk(
+                                        casm, c0, c1, carry,
+                                        # first slice of each arena:
+                                        # the cold-compile shield keys
+                                        # on the launch signature,
+                                        # which is identical for that
+                                        # arena's later slices
+                                        check_ready=c0 == 0,
+                                    ),
+                                    exemplar=run[base + c0][0].id,
+                                )
                             if handle is None and not (
                                 casm.use_mesh and self._mesh is None
                             ):
@@ -2196,11 +2234,14 @@ class BatchWorker(Worker):
                             launch_stage, run, base, c0,
                             min(c1, casm.E_real), t0, dt,
                             chunk=ci, ok=handle is not None,
+                            cpu_ms=cpu_ms_since(cpu0),
                         )
                         if handle is None:
                             stalled = True
                             break
                         carry = handle[2]
+                        if not pending:
+                            self._device_fed(t0)
                         pending.append((chunks[ci], handle, dt))
                         ci += 1
                         # chaos seam: deterministic revoke-mid-launch
@@ -2232,12 +2273,16 @@ class BatchWorker(Worker):
                         "mesh_fetch" if casm.use_mesh else "fetch"
                     )
                     t0 = _time.monotonic()
+                    cpu0 = TRACE.cpu_clock(run[base + c0][0].id)
                     try:
-                        rows_arr, pulls_arr = self._guard_device(
-                            fetch_stage,
-                            lambda: self._fetch(handle),
-                            exemplar=run[base + c0][0].id,
-                        )
+                        with profiler_annotation(
+                            f"batch_worker.{fetch_stage}"
+                        ):
+                            rows_arr, pulls_arr = self._guard_device(
+                                fetch_stage,
+                                lambda: self._fetch(handle),
+                                exemplar=run[base + c0][0].id,
+                            )
                     except Exception:  # noqa: BLE001
                         self._count("errors")
                         LOG.warning(
@@ -2247,6 +2292,7 @@ class BatchWorker(Worker):
                         # they share its failure: drop them and let the
                         # exact path cover the rest of the run
                         pending.clear()
+                        self._device_unfed()
                         self._mark_mirror_dirty()
                         stalled = True
                         self._observe(
@@ -2254,9 +2300,12 @@ class BatchWorker(Worker):
                         )
                         continue
                     dt = _time.monotonic() - t0
-                    self._observe_chunk(
+                    if not pending:
+                        self._device_unfed()
+                    fetch_sids = self._observe_chunk(
                         fetch_stage, run, base, c0,
                         min(c1, casm.E_real), t0, dt,
+                        cpu_ms=cpu_ms_since(cpu0),
                     )
                     # feed the adaptive sizing loop: this chunk's
                     # blocking device-path cost (dispatch + the fetch
@@ -2291,6 +2340,8 @@ class BatchWorker(Worker):
                                     self._speculate_one, snap,
                                     wave_readiness, ev, job, sim,
                                     rows, pulls,
+                                    # the fetch whose rows it replays
+                                    fetch_sids[e - c0],
                                 ),
                             ))
                             continue
@@ -2987,15 +3038,17 @@ class BatchWorker(Worker):
             return True
         t0 = _time.monotonic()
         try:
-            clean = self._process_prescored(
-                ev, token, job, rows, sim, pulls=pulls
-            )
+            # an OPEN span (the ack inside settles the trace): the
+            # replay's plan, eval write, publish and ack hang under it
+            with TRACE.span(
+                ev.id, "batch_worker.replay", mode="serial"
+            ) as replay_span:
+                clean = self._process_prescored(
+                    ev, token, job, rows, sim, pulls=pulls
+                )
+                replay_span.note(clean=clean)
             replay_dt = _time.monotonic() - t0
             self._observe("replay", replay_dt, exemplar=ev.id)
-            TRACE.add_span(
-                ev.id, "batch_worker.replay", t0, replay_dt,
-                mode="serial", clean=clean,
-            )
             self._replay_ewma_ms = (
                 0.8 * self._replay_ewma_ms
                 + 0.2 * replay_dt * 1000.0
@@ -3031,6 +3084,7 @@ class BatchWorker(Worker):
     def _speculate_one(
         self, snap, wave_readiness: int, ev, job, sim: _Sim,
         rows: List[int], pulls: Optional[List[int]],
+        cause: Optional[int] = None,
     ) -> Optional[_Speculation]:
         """Phase A (pool thread): replay one prescored eval against
         the shared wave snapshot with every side effect captured
@@ -3040,9 +3094,11 @@ class BatchWorker(Worker):
         try:
             # span runs on the pool thread, so the trace records WHICH
             # replay-spec thread carried this eval (straggler
-            # attribution across the wave)
+            # attribution across the wave); its cause is the fetch
+            # that brought its rows (the id came with the work item)
             with TRACE.span(
-                ev.id, "replay.speculate", speculative=True
+                ev.id, "replay.speculate", cause=cause,
+                speculative=True,
             ):
                 return self._speculate_inner(
                     snap, wave_readiness, ev, job, sim, rows, pulls
@@ -3182,10 +3238,23 @@ class BatchWorker(Worker):
         the end of the (possibly admission-extended) chain.
         ``state`` carries the in-order commit's job ledger and
         expected-touch accounting across those incremental drains."""
-        import time as _time
-
         if state is None:
             state = {"job_ledger": set(), "expect": {}}
+        # once a wave drain, not once a member: the host stage on the
+        # profiler's clock, under the member spans' name
+        with profiler_annotation("replay.commit"):
+            return self._commit_wave_members(
+                wave, k, wave_base, wave_readiness, state,
+                drain_all, leader_gen,
+            )
+
+    def _commit_wave_members(
+        self, wave, k: int, wave_base: Dict[str, int],
+        wave_readiness: int, state: dict, drain_all: bool,
+        leader_gen: Optional[int],
+    ) -> Tuple[int, bool]:
+        import time as _time
+
         job_ledger: Set[tuple] = state["job_ledger"]
         wave_expect: Dict[str, int] = state["expect"]
         rescore = False
@@ -3220,34 +3289,35 @@ class BatchWorker(Worker):
             ok: Optional[bool] = None
             committed = False
             if spec is not None:
-                t_c = _time.monotonic()
-                try:
-                    ok = self._commit_speculation(
-                        spec, ev, token, wave_base, wave_expect,
-                        wave_readiness, job_ledger,
-                        leader_gen=leader_gen,
-                    )
-                    committed = ok is not None
-                except NotLeaderError:
-                    # the plan applier (or the replicated FSM fence)
-                    # rejected the commit: leadership is gone — nack
-                    # this lease and abort the whole wave; run()'s
-                    # handler nacks the rest
-                    self._nack_quietly(ev, token)
-                    raise
-                except Exception:  # noqa: BLE001
-                    self._count("errors")
-                    LOG.warning(
-                        "speculative commit failed for eval %s",
-                        ev.id, exc_info=True,
-                    )
-                    self._nack_quietly(ev, token)
-                    job_ledger.add((ev.namespace, ev.job_id))
-                    ok = False  # chain past this eval is suspect
-                if committed:
-                    TRACE.add_span(
-                        ev.id, "replay.commit", t_c,
-                        _time.monotonic() - t_c, clean=bool(ok),
+                # an OPEN span: the plan this commit submits, and the
+                # eval write, publish and ack that follow, hang under
+                # it (the applier's threads get its id with the plan)
+                with TRACE.span(ev.id, "replay.commit") as commit_span:
+                    try:
+                        ok = self._commit_speculation(
+                            spec, ev, token, wave_base, wave_expect,
+                            wave_readiness, job_ledger,
+                            leader_gen=leader_gen,
+                        )
+                        committed = ok is not None
+                    except NotLeaderError:
+                        # the plan applier (or the replicated FSM
+                        # fence) rejected the commit: leadership is
+                        # gone — nack this lease and abort the whole
+                        # wave; run()'s handler nacks the rest
+                        self._nack_quietly(ev, token)
+                        raise
+                    except Exception:  # noqa: BLE001
+                        self._count("errors")
+                        LOG.warning(
+                            "speculative commit failed for eval %s",
+                            ev.id, exc_info=True,
+                        )
+                        self._nack_quietly(ev, token)
+                        job_ledger.add((ev.namespace, ev.job_id))
+                        ok = False  # chain past this eval is suspect
+                    commit_span.note(
+                        committed=committed, clean=bool(ok)
                     )
             if committed:
                 dt = _time.monotonic() - t0
@@ -3441,14 +3511,15 @@ class BatchWorker(Worker):
         job_ledger.add(key)
         self.evals_processed += 1
         TRACE.annotate(ev.id, outcome="speculative")
-        EXPLAIN.publish(
-            spec.explain, getattr(self.server, "metrics", None)
-        )
-        if leader_gen is not None:
-            # the published explanation names the leadership
-            # generation whose wave committed it (failover forensics:
-            # "which leader placed this?")
-            EXPLAIN.annotate(ev.id, LeaderGen=leader_gen)
+        with TRACE.span(ev.id, "explain.publish"):
+            EXPLAIN.publish(
+                spec.explain, getattr(self.server, "metrics", None)
+            )
+            if leader_gen is not None:
+                # the published explanation names the leadership
+                # generation whose wave committed it (failover
+                # forensics: "which leader placed this?")
+                EXPLAIN.annotate(ev.id, LeaderGen=leader_gen)
         self.server.broker.ack(ev.id, token)
         self._count("prescored")
         self._count_replay("speculative")
@@ -3462,13 +3533,13 @@ class BatchWorker(Worker):
         # trace) inside, and the annotated outcome must be there first
         TRACE.annotate(ev.id, outcome="sequential")
         t0 = _time.monotonic()
-        try:
-            self.process_eval(ev, token)
-        except Exception:  # noqa: BLE001
-            self._nack_quietly(ev, token)
+        with TRACE.span(ev.id, "batch_worker.sequential"):
+            try:
+                self.process_eval(ev, token)
+            except Exception:  # noqa: BLE001
+                self._nack_quietly(ev, token)
         dt = _time.monotonic() - t0
         self._observe("sequential", dt, exemplar=ev.id)
-        TRACE.add_span(ev.id, "batch_worker.sequential", t0, dt)
         self._sample_eval_latency(ev)
         # failover forensics: every explain record names the
         # leadership generation whose pipeline produced it
@@ -5543,9 +5614,10 @@ class BatchWorker(Worker):
         )
         self.evals_processed += 1
         TRACE.annotate(ev.id, outcome="prescored")
-        EXPLAIN.record_eval(
-            ev, scheduler, getattr(self.server, "metrics", None)
-        )
+        with TRACE.span(ev.id, "explain.publish"):
+            EXPLAIN.record_eval(
+                ev, scheduler, getattr(self.server, "metrics", None)
+            )
         self.server.broker.ack(ev.id, token)
         if made and made[0].entered_passthrough:
             self._count("preempt_passthroughs")

@@ -11,7 +11,6 @@ from __future__ import annotations
 
 import heapq
 import itertools
-from collections import deque
 import threading
 import time
 from typing import Dict, List, Optional, Tuple
@@ -65,21 +64,23 @@ def job_family(ev: Evaluation) -> Tuple[str, str]:
 
 
 class _ReadyQueue:
-    """Priority heap: highest priority first, then FIFO by create index."""
+    """Priority heap: highest priority first, then FIFO by create
+    index.  An entry carries the eval's stamp (see
+    ``EvalBroker._enqueue_locked``) beside it."""
 
     def __init__(self) -> None:
-        self.heap: List[Tuple[int, int, Evaluation]] = []
+        self.heap: List[Tuple[int, int, Evaluation, tuple]] = []
         self._counter = itertools.count()
 
-    def push(self, ev: Evaluation) -> None:
+    def push(self, ev: Evaluation, stamp: tuple) -> None:
         heapq.heappush(
-            self.heap, (-ev.priority, next(self._counter), ev)
+            self.heap, (-ev.priority, next(self._counter), ev, stamp)
         )
 
-    def pop(self) -> Optional[Evaluation]:
+    def pop(self) -> Optional[Tuple[Evaluation, tuple]]:
         if not self.heap:
             return None
-        return heapq.heappop(self.heap)[2]
+        return heapq.heappop(self.heap)[2:]
 
     def peek_priority(self) -> Optional[int]:
         if not self.heap:
@@ -110,7 +111,8 @@ class EvalBroker:
         # (namespace, job_id) -> outstanding eval id
         self._job_evals: Dict[Tuple[str, str], str] = {}
         # (namespace, job_id) -> heap of waiting evals (priority desc,
-        # create_index asc) -- reference eval_broker.go:117
+        # create_index asc; the eval's stamp rides along) -- reference
+        # eval_broker.go:117
         self._pending: Dict[Tuple[str, str], List] = {}
         self._pending_counter = itertools.count()
         # eval id -> monotonic instant it became READY (insertion
@@ -131,10 +133,6 @@ class EvalBroker:
         self._remote_leases: Dict[str, str] = {}
         self._ticker: Optional[threading.Thread] = None
         self.ticks = 0
-        # tiny event ring for post-mortem debugging (eval id prefix,
-        # action, monotonic ts) — cheap, and invaluable when an eval
-        # "disappears" between enqueue and ack
-        self.events: "deque" = deque(maxlen=128)
         self.stats = {
             "total_ready": 0,
             "total_unacked": 0,
@@ -264,16 +262,34 @@ class EvalBroker:
                 self._enqueue_locked(ev, ev.type)
             self._lock.notify_all()
 
-    def _enqueue_locked(self, ev: Evaluation, queue: str) -> None:
-        self.events.append((time.monotonic(), "enq", ev.id[:6], queue))
+    def _enqueue_locked(
+        self, ev: Evaluation, queue: str,
+        stamp: Optional[tuple] = None,
+    ) -> None:
         if not self._enabled:
             return
         if ev.id in self._unack or any(
-            ev.id == q_ev.id
+            ev.id == entry[2].id
             for q in self._ready.values()
-            for _, _, q_ev in q.heap
+            for entry in q.heap
         ):
             return
+        if stamp is None:
+            # the flight recorder's stamp, kept on the broker's own
+            # entry for the eval: (enqueued at, ingress start, ingress
+            # thread).  The trace that begins at dequeue dates back to
+            # it — `ingress.register` when an HTTP handler on this
+            # thread is registering the eval, then `broker.wait`.  A
+            # nack's re-enqueue stamps anew; an eval parked behind its
+            # job's outstanding eval keeps its first stamp.
+            t_in = TRACE.ingress_start()
+            stamp = (
+                time.monotonic(),
+                t_in,
+                threading.current_thread().name
+                if t_in is not None
+                else None,
+            )
         if ev.wait_until and ev.wait_until > time.time():
             heapq.heappush(
                 self._delayed,
@@ -287,12 +303,17 @@ class EvalBroker:
             if outstanding and outstanding != ev.id:
                 heapq.heappush(
                     self._pending.setdefault(job_key, []),
-                    (-ev.priority, next(self._pending_counter), ev),
+                    (
+                        -ev.priority,
+                        next(self._pending_counter),
+                        ev,
+                        stamp,
+                    ),
                 )
                 self.stats["total_blocked"] += 1
                 return
             self._job_evals[job_key] = ev.id
-        self._ready.setdefault(queue, _ReadyQueue()).push(ev)
+        self._ready.setdefault(queue, _ReadyQueue()).push(ev, stamp)
         if queue != FAILED_QUEUE:
             self._ready_ts[ev.id] = time.monotonic()
         self.stats["total_ready"] += 1
@@ -308,27 +329,16 @@ class EvalBroker:
         with self._lock:
             while True:
                 self._promote_delayed_locked()
-                ev = self._pop_ready_locked(schedulers)
-                if ev is not None:
+                popped = self._pop_ready_locked(schedulers)
+                if popped is not None:
+                    ev = popped[0]
                     token = new_id()
                     self._unack[ev.id] = (
                         ev, token, time.monotonic() + self.nack_timeout,
                     )
                     self._ensure_ticker_locked()
                     self.stats["total_unacked"] += 1
-                    self.events.append((time.monotonic(), "deq", ev.id[:6], token[:6]))
-                    # flight recorder: the dequeue is the trace root —
-                    # every downstream span (pipeline stages, replay,
-                    # plan apply, store commit) attaches to it by
-                    # eval id
-                    TRACE.begin(
-                        ev.id,
-                        queue=ev.type,
-                        priority=ev.priority,
-                        namespace=ev.namespace,
-                        job_id=ev.job_id,
-                        triggered_by=ev.triggered_by,
-                    )
+                    self._begin_trace(*popped)
                     return ev, token
                 if not self._enabled:
                     return None, ""
@@ -340,7 +350,36 @@ class EvalBroker:
                     wait = min(wait, remaining)
                 self._lock.wait(wait)
 
-    def _pop_ready_locked(self, schedulers) -> Optional[Evaluation]:
+    def _begin_trace(self, ev: Evaluation, stamp: tuple) -> None:
+        """Flight recorder: a delivery begins the eval's trace (a
+        redelivery a new generation of it), dated back to the stamp of
+        its queue entry — every downstream span (pipeline stages,
+        replay, plan apply, store commit) attaches to it by eval id."""
+        t_enq, t_in, ingress_thread = stamp
+        trace = TRACE.begin(
+            ev.id,
+            t0=t_enq if t_in is None else t_in,
+            queue=ev.type,
+            priority=ev.priority,
+            namespace=ev.namespace,
+            job_id=ev.job_id,
+            triggered_by=ev.triggered_by,
+        )
+        if trace is None:
+            return
+        if t_in is not None:
+            trace.add_span(
+                "ingress.register", t_in, t_enq - t_in, {},
+                thread=ingress_thread,
+            )
+        trace.add_span(
+            "broker.wait", t_enq, time.monotonic() - t_enq,
+            {"queue": ev.type, "ready": self.stats["total_ready"]},
+        )
+
+    def _pop_ready_locked(
+        self, schedulers
+    ) -> Optional[Tuple[Evaluation, tuple]]:
         best_queue = None
         best_priority = None
         for name in schedulers:
@@ -354,10 +393,10 @@ class EvalBroker:
         if best_queue is None:
             return None
         self.stats["total_ready"] -= 1
-        ev = best_queue.pop()
-        if ev is not None:
-            self._ready_ts.pop(ev.id, None)
-        return ev
+        popped = best_queue.pop()
+        if popped is not None:
+            self._ready_ts.pop(popped[0].id, None)
+        return popped
 
     def drain_family(
         self,
@@ -435,23 +474,13 @@ class EvalBroker:
             # path settling: the sweeper is (re)armed with the leases
             self._ensure_ticker_locked()
             for _ in range(count):
-                ev = self._pop_ready_locked(schedulers)
+                ev, stamp = self._pop_ready_locked(schedulers)
                 token = new_id()
                 self._unack[ev.id] = (
                     ev, token, time.monotonic() + self.nack_timeout,
                 )
                 self.stats["total_unacked"] += 1
-                self.events.append(
-                    (time.monotonic(), "deq", ev.id[:6], token[:6])
-                )
-                TRACE.begin(
-                    ev.id,
-                    queue=ev.type,
-                    priority=ev.priority,
-                    namespace=ev.namespace,
-                    job_id=ev.job_id,
-                    triggered_by=ev.triggered_by,
-                )
+                self._begin_trace(ev, stamp)
                 out.append((ev, token))
             return out
 
@@ -556,6 +585,7 @@ class EvalBroker:
     # ------------------------------------------------------------------
 
     def ack(self, eval_id: str, token: str) -> None:
+        t_ack = time.monotonic()
         with self._lock:
             entry = self._unack.get(eval_id)
             if entry is None or entry[1] != token:
@@ -567,20 +597,28 @@ class EvalBroker:
                 self.stats["total_remote_unacked"] = len(
                     self._remote_leases
                 )
-            self.events.append((time.monotonic(), "ack", eval_id[:6], ""))
-            TRACE.finish(eval_id, "ack")
+            # the ack settles the trace, and is its last span.
+            # Settled under the lock (an unblocked eval may be
+            # enqueued, and begin a new generation, the moment it is
+            # released); folded outside it
+            TRACE.add_span(
+                eval_id, "broker.ack", t_ack,
+                time.monotonic() - t_ack,
+            )
+            settled = TRACE.finish(eval_id, "ack")
             self._delivery_count.pop(eval_id, None)
             job_key = (ev.namespace, ev.job_id)
             if self._job_evals.get(job_key) == eval_id:
                 del self._job_evals[job_key]
                 pending = self._pending.get(job_key)
                 if pending:
-                    _, _, nxt = heapq.heappop(pending)
+                    _, _, nxt, stamp = heapq.heappop(pending)
                     if not pending:
                         del self._pending[job_key]
                     self.stats["total_blocked"] -= 1
-                    self._enqueue_locked(nxt, nxt.type)
+                    self._enqueue_locked(nxt, nxt.type, stamp)
             self._lock.notify_all()
+        TRACE.publish(settled, self.metrics)
 
     def nack(self, eval_id: str, token: str) -> None:
         with self._lock:
@@ -594,7 +632,6 @@ class EvalBroker:
                 self.stats["total_remote_unacked"] = len(
                     self._remote_leases
                 )
-            self.events.append((time.monotonic(), "nack", eval_id[:6], ""))
             TRACE.finish(eval_id, "nack")
             job_key = (ev.namespace, ev.job_id)
             if self._job_evals.get(job_key) == eval_id:
@@ -665,4 +702,4 @@ class EvalBroker:
 
     def failed(self) -> List[Evaluation]:
         q = self._ready.get(FAILED_QUEUE)
-        return [e for _, _, e in q.heap] if q else []
+        return [entry[2] for entry in q.heap] if q else []

@@ -24,12 +24,13 @@ from __future__ import annotations
 import os
 import queue as _queue
 import threading
+import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Callable, Dict, List, Optional, Tuple
 
 from ..raft import NotLeaderError
 from ..state.store import StateStore
-from ..trace import TRACE
+from ..trace import TRACE, cpu_ms_since, profiler_annotation
 from ..structs import (
     Allocation,
     NetworkIndex,
@@ -412,6 +413,7 @@ class PlanApplier:
             import time as _time
 
             start = _time.monotonic()
+            cpu0 = TRACE.cpu_clock(pending.plan.eval_id)
             with self._lock:
                 overlay = list(self._inflight)
                 epoch = self._epoch
@@ -421,28 +423,38 @@ class PlanApplier:
                 else self.store
             )
             try:
-                result, full = evaluate_plan(state, pending.plan, self.pool)
+                with profiler_annotation("plan.evaluate"):
+                    result, full = evaluate_plan(
+                        state, pending.plan, self.pool
+                    )
             except Exception as exc:  # noqa: BLE001
                 pending.respond(None, exc)
                 continue
             if overlay:
                 self.overlap_verifies += 1
-                if self.metrics is not None:
-                    self.metrics.incr("plan.overlap_verify")
-            verify_dt = _time.monotonic() - start
+            end = pending.t_evaluated = _time.monotonic()
+            verify_dt = end - start
             if self.metrics is not None:
                 # (reference plan_apply.go:401 plan.evaluate timing)
                 self.metrics.add_sample(
                     "plan.evaluate", verify_dt * 1000.0,
                     exemplar=pending.plan.eval_id or None,
                 )
-            # flight recorder: the verification interval on the
-            # submitting eval's trace (applier-thread attribution)
-            if pending.plan.eval_id:
+            # flight recorder: the plan's wait in the queue and the
+            # verification interval on the submitting eval's trace,
+            # both under the submitter's span (the cause the plan
+            # carries), recorded by this thread
+            eval_id = pending.plan.eval_id
+            if eval_id:
                 TRACE.add_span(
-                    pending.plan.eval_id, "plan.evaluate",
-                    start, verify_dt,
+                    eval_id, "plan.queue_wait", pending.t_enqueued,
+                    start - pending.t_enqueued, cause=pending.cause,
+                )
+                TRACE.add_span(
+                    eval_id, "plan.evaluate", start, verify_dt,
+                    cause=pending.cause,
                     overlay=bool(overlay), full=full,
+                    cpu_ms=cpu_ms_since(cpu0),
                 )
             with self._lock:
                 self._inflight.append(result)
@@ -479,6 +491,15 @@ class PlanApplier:
                 )
             except _queue.Empty:
                 continue
+            if pending.plan.eval_id and pending.t_evaluated is not None:
+                # the hand-off from the verifier: verified, and
+                # waiting for this thread to take it off the queue
+                TRACE.add_span(
+                    pending.plan.eval_id, "plan.stage_wait",
+                    pending.t_evaluated,
+                    time.monotonic() - pending.t_evaluated,
+                    cause=pending.cause,
+                )
             if self._not_leader():
                 # staged between verify and commit when leadership
                 # moved: the optimistic result must never reach the
@@ -507,7 +528,9 @@ class PlanApplier:
                         # so they also re-verify before committing
                         self._epoch += 1
                     result = result2
-                self._commit(pending.plan, result, full)
+                self._commit(
+                    pending.plan, result, full, cause=pending.cause
+                )
                 with self._lock:
                     self._remove_inflight_locked(result)
                 pending.respond(result, None)
@@ -526,7 +549,10 @@ class PlanApplier:
                 del self._inflight[i]
                 break
 
-    def _commit(self, plan: Plan, result: PlanResult, full: bool) -> None:
+    def _commit(
+        self, plan: Plan, result: PlanResult, full: bool,
+        cause: Optional[int] = None,
+    ) -> None:
         import time as _time
 
         start = _time.monotonic()
@@ -537,37 +563,39 @@ class PlanApplier:
             or result.deployment is not None
             or result.deployment_updates
         ):
-            # the producing wave's captured generation, passed only
-            # when stamped (so store facades without the kwarg keep
-            # working for unstamped plans): the replicated fence must
-            # judge the plan by the leadership it RAN under, not by
-            # whoever leads when it reaches the store
-            gen = getattr(plan, "leader_gen", None)
-            if gen is not None:
-                index = self.store.upsert_plan_results(
-                    result, plan.eval_id, leader_gen=gen
-                )
-            else:
-                index = self.store.upsert_plan_results(
-                    result, plan.eval_id
-                )
-            result.alloc_index = index
-            self.applied += 1
-            self._notify_capacity_change(result, index)
             # flight recorder: the commit interval + committed index
-            # close the eval's write path (dequeue -> ... -> commit)
-            if plan.eval_id:
-                TRACE.add_span(
-                    plan.eval_id, "plan.apply", start,
-                    _time.monotonic() - start, index=index,
-                )
+            # close the eval's write path (create -> ... -> commit).
+            # An OPEN span under the submitter's (the cause the plan
+            # carried here), so the store's own `store.commit` nests
+            # under it on this thread.
+            with TRACE.span(
+                plan.eval_id, "plan.apply", cause=cause
+            ) as span, profiler_annotation("plan.apply"):
+                # the producing wave's captured generation, passed
+                # only when stamped (so store facades without the
+                # kwarg keep working for unstamped plans): the
+                # replicated fence must judge the plan by the
+                # leadership it RAN under, not by whoever leads when
+                # it reaches the store
+                gen = getattr(plan, "leader_gen", None)
+                if gen is not None:
+                    index = self.store.upsert_plan_results(
+                        result, plan.eval_id, leader_gen=gen
+                    )
+                else:
+                    index = self.store.upsert_plan_results(
+                        result, plan.eval_id
+                    )
+                result.alloc_index = index
+                self.applied += 1
+                self._notify_capacity_change(result, index)
+                span.note(index=index)
         if self.metrics is not None:
             # (reference plan_apply.go:185 plan.evaluate/apply timings)
             self.metrics.add_sample(
                 "plan.apply", (_time.monotonic() - start) * 1000.0,
                 exemplar=plan.eval_id or None,
             )
-            self.metrics.incr("plan.applied")
             if not full:
                 self.metrics.incr("plan.partial_commit")
 

@@ -7,6 +7,7 @@ from __future__ import annotations
 import heapq
 import itertools
 import threading
+import time
 from typing import List, Optional, Tuple
 
 from ..raft import NotLeaderError
@@ -14,8 +15,19 @@ from ..structs import Plan, PlanResult
 
 
 class PendingPlan:
-    def __init__(self, plan: Plan) -> None:
+    """A plan on its way through the applier.  It crosses three
+    threads and back (submitter -> verifier -> committer -> submitter),
+    so it carries what the flight recorder needs to keep the eval's
+    trace one tree: ``cause``, the id of the submitter's open span
+    (every plan.* span names it as its parent), and the instants of
+    the hand-offs, from which the three waits are read."""
+
+    def __init__(self, plan: Plan, cause: Optional[int] = None) -> None:
         self.plan = plan
+        self.cause = cause
+        self.t_enqueued = time.monotonic()
+        self.t_evaluated: Optional[float] = None
+        self.t_responded: Optional[float] = None
         self._event = threading.Event()
         self._result: Optional[PlanResult] = None
         self._error: Optional[Exception] = None
@@ -25,6 +37,7 @@ class PendingPlan:
     ) -> None:
         self._result = result
         self._error = error
+        self.t_responded = time.monotonic()
         self._event.set()
 
     def wait(self, timeout: Optional[float] = None) -> PlanResult:
@@ -59,11 +72,13 @@ class PlanQueue:
         self._heap = []
         self.stats["depth"] = 0
 
-    def enqueue(self, plan: Plan) -> PendingPlan:
+    def enqueue(
+        self, plan: Plan, cause: Optional[int] = None
+    ) -> PendingPlan:
         with self._lock:
             if not self._enabled:
                 raise NotLeaderError(None)
-            pending = PendingPlan(plan)
+            pending = PendingPlan(plan, cause)
             heapq.heappush(
                 self._heap,
                 (-plan.priority, next(self._counter), pending),

@@ -237,6 +237,16 @@ class Server:
 
         self.broker.metrics = self.metrics
         self.metrics.preregister(counters=BROKER_COUNTERS)
+        # the flight recorder's fold (an acked eval's trace, folded by
+        # layer where it closes) lands on this server's telemetry
+        # through the broker's ack: zero-register the trace.* family
+        # (absence of samples must mean "no eval acked yet" or
+        # NOMAD_TPU_TRACE=0, not "not exported")
+        from ..trace import FOLD_COUNTERS, FOLD_SAMPLES
+
+        self.metrics.preregister(
+            counters=FOLD_COUNTERS, samples=FOLD_SAMPLES
+        )
         self.blocked = BlockedEvals(self.broker)
         self.plan_queue = PlanQueue()
         self.applier = PlanApplier(

@@ -203,8 +203,23 @@ class Worker:
         plan.snapshot_index = self.store.latest_index()
         t0 = _time.monotonic()
         try:
-            pending = self.server.plan_queue.enqueue(plan)
-            result = pending.wait(timeout=10.0)
+            # the plan crosses to the applier's threads: the span this
+            # thread holds open for the eval travels with it as the
+            # cause of every plan.* span
+            pending = self.server.plan_queue.enqueue(
+                plan, cause=TRACE.current(plan.eval_id)
+            )
+            try:
+                result = pending.wait(timeout=10.0)
+            finally:
+                if pending.t_responded is not None:
+                    # the wake-up: the committer responded, this
+                    # thread resumes (it has to win the GIL first)
+                    TRACE.add_span(
+                        plan.eval_id, "plan.respond_wait",
+                        pending.t_responded,
+                        _time.monotonic() - pending.t_responded,
+                    )
             if result is None:
                 raise RuntimeError("plan rejected")
             if result.refresh_index:
@@ -217,13 +232,21 @@ class Worker:
             self.plan_wait_s += _time.monotonic() - t0
 
     def update_eval(self, ev: Evaluation) -> None:
-        self.store.upsert_evals([ev])
+        with TRACE.span(ev.id, "store.upsert_evals"):
+            self.store.upsert_evals([ev])
         self.server.on_eval_update(ev)
 
     def create_eval(self, ev: Evaluation) -> None:
-        self.store.upsert_evals([ev])
+        # a follow-up or blocked eval is written for the eval in
+        # process, whose trace carries the write
+        with TRACE.span(
+            ev.previous_eval or ev.id, "store.upsert_evals",
+            created=ev.id,
+        ):
+            self.store.upsert_evals([ev])
         self.server.on_eval_update(ev)
 
     def reblock_eval(self, ev: Evaluation) -> None:
-        self.store.upsert_evals([ev])
+        with TRACE.span(ev.id, "store.upsert_evals"):
+            self.store.upsert_evals([ev])
         self.server.blocked.block(ev)

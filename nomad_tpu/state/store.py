@@ -28,7 +28,7 @@ from typing import Callable, Dict, Iterable, List, Optional, Tuple
 
 import numpy as np
 
-from ..trace import TRACE
+from ..trace import TRACE, cpu_ms_since
 from ..structs import (
     Allocation,
     ALLOC_CLIENT_STATUS_FAILED,
@@ -1138,6 +1138,8 @@ class StateStore:
         # leadership fence); the direct single-process store accepts
         # and ignores it so the plan applier can pass one call shape
         with self._lock:
+            t_locked = time.monotonic()
+            cpu0 = TRACE.cpu_clock(eval_id)
             updates: List[Allocation] = []
             for allocs in result.node_update.values():
                 updates.extend(allocs)
@@ -1192,10 +1194,14 @@ class StateStore:
             self._notify_alloc_watchers(updates)
             if eval_id:
                 # flight recorder: the eval's plan reached durable
-                # state at this raft index — the trace's commit mark
-                TRACE.event(
-                    eval_id, "store.commit", index=index,
+                # state at this raft index — the store's own time,
+                # from the lock taken to the index published (the
+                # wait FOR the lock stays with the caller's span)
+                TRACE.add_span(
+                    eval_id, "store.commit", t_locked,
+                    time.monotonic() - t_locked, index=index,
                     allocs=len(updates),
+                    cpu_ms=cpu_ms_since(cpu0),
                 )
             return index
 

@@ -169,6 +169,16 @@ class Metrics:
         with self._lock:
             self._samples[name].add(value, exemplar)
 
+    def add_samples(
+        self, samples, exemplar: Optional[str] = None
+    ) -> None:
+        """Several (name, value) samples under one lock take (the
+        flight recorder's fold adds a trace's whole layer split)."""
+        store = self._samples
+        with self._lock:
+            for name, value in samples:
+                store[name].add(value, exemplar)
+
     def get_counter(self, name: str) -> float:
         """O(1) single-counter read (tests/operators polling one hot
         counter — e.g. the optimistic-replay `replay.*` family —

@@ -172,7 +172,9 @@ def test_http_job_lifecycle(api):
         timeout=30,
     ), (
         f"evals={[(e.status, e.triggered_by) for e in server.store.evals_by_job('default', 'web-app')]} "
-        f"broker={server.broker.stats} events={list(server.broker.events)}"
+        f"broker={server.broker.stats} "
+        # where the eval got to: its flight-recorder trace
+        f"trace={_get(base, '/v1/traces/' + resp['EvalID'])}"
     )
     allocs = _get(base, "/v1/job/web-app/allocations")
 

@@ -2454,3 +2454,70 @@ def test_deq_ts_is_bounded_and_popped_on_nack():
         assert "nacked" not in worker._deq_ts
     finally:
         server.stop()
+
+
+def test_chained_kernel_carries_phase_scopes_under_its_old_name():
+    """The phases of a pick-step are `jax.named_scope`s (score, spread,
+    walk, usage_update), so an operation of a device profile can be
+    put to a phase from its metadata — and the jit name, by which the
+    benchmark finds the kernel (`jit_chained_plan_picks_cols*`) and a
+    launch shape's compile (`jit(chained_plan_picks_cols`), is what it
+    was."""
+    import re
+
+    import numpy as np
+
+    from nomad_tpu.ops.batch import (
+        ChainInputs,
+        SpreadInputs,
+        chained_plan_picks_cols,
+        chained_plan_picks_cols_donated,
+    )
+
+    assert chained_plan_picks_cols.__name__ == "chained_plan_picks_cols"
+    assert (
+        chained_plan_picks_cols_donated().__name__
+        == "chained_plan_picks_cols_donated"
+    )
+    C, E, P, T, S, V = 8, 2, 4, 1, 1, 3
+    col = lambda v: np.full(C, v)  # noqa: E731
+    stacked = ChainInputs(
+        feasible=np.ones((E, T, C), bool),
+        perm=np.tile(np.arange(C, dtype=np.int32), (E, 1)),
+        ask_cpu=np.full((E, P), 100.0),
+        ask_mem=np.full((E, P), 256.0),
+        ask_disk=np.full((E, P), 300.0),
+        desired_count=np.full((E, P), 4, np.int32),
+        limit=np.full((E, P), 3, np.int32),
+        distinct_hosts=np.zeros(E, bool),
+        tg_idx=np.zeros((E, P), np.int32),
+    )
+    spread = SpreadInputs(
+        codes=np.zeros((E, S, C), np.int32),
+        desired=np.ones((E, S, V + 1)),
+        used0=np.zeros((E, S, V + 1), np.int32),
+        proposed0=np.zeros((E, S, V + 1), np.int32),
+        cleared0=np.zeros((E, S, V + 1), np.int32),
+        weight=np.ones((E, S)),
+        active=np.ones((E, S), bool),
+        even=np.zeros((E, S), bool),
+    )
+    text = (
+        chained_plan_picks_cols.lower(
+            col(4000.0), col(8192.0), col(1e5),
+            col(0.0), col(0.0), col(0.0),
+            stacked, np.full(E, C, np.int32), P,
+            wanted=np.full(E, 4, np.int32), spread=spread,
+            return_carry=True,
+        )
+        .compile()
+        .as_text()
+    )
+    assert text.startswith("HloModule jit_chained_plan_picks_cols")
+    ops = set(re.findall(r'op_name="([^"]+)"', text))
+    scoped = {
+        op for op in ops
+        if op.startswith("jit(chained_plan_picks_cols)/")
+    }
+    for scope in ("score", "spread", "walk", "usage_update"):
+        assert any(f"/{scope}/" in op for op in scoped), scope

@@ -81,3 +81,49 @@ def test_lint_detects_a_dropped_stage(tmp_path, monkeypatch):
     ok, problems = lint.check()
     assert not ok
     assert any("simulate" in p for p in problems)
+
+
+def _span_layers_problems(lint, tmp_path, monkeypatch, old, new):
+    with open(lint.TRACE_MOD) as fh:
+        src = fh.read()
+    assert old in src
+    bad = tmp_path / "trace.py"
+    bad.write_text(src.replace(old, new))
+    monkeypatch.setattr(lint, "TRACE_MOD", str(bad))
+    ok, problems = lint.check()
+    assert not ok
+    return problems
+
+
+def test_lint_holds_layer_of_to_span_names(tmp_path, monkeypatch):
+    """A registered span name with no LAYER_OF entry fails the lint:
+    the fold would look through it and its layer's metric would lose
+    it silently."""
+    problems = _span_layers_problems(
+        _load(), tmp_path, monkeypatch,
+        '    "plan.stage_wait": "plan_handoff",\n', "",
+    )
+    assert any(
+        "plan.stage_wait" in p and "LAYER_OF" in p for p in problems
+    ), problems
+
+
+def test_lint_detects_a_layer_with_no_span_name(tmp_path, monkeypatch):
+    """The other direction: the replay pool's only source marked
+    event-only leaves `trace.self.replay_pool` reading 0 forever."""
+    problems = _span_layers_problems(
+        _load(), tmp_path, monkeypatch,
+        '"replay.speculate": "replay_pool"', '"replay.speculate": EVENT',
+    )
+    assert any(
+        "replay_pool" in p and "no span name" in p for p in problems
+    ), problems
+
+
+def test_every_span_name_has_a_layer_or_is_event_only():
+    """The table the lint reads is the table the fold uses."""
+    from nomad_tpu.trace import LAYER_OF, LAYERS, SPAN_NAMES
+
+    assert set(LAYER_OF) == set(SPAN_NAMES)
+    used = {v for v in LAYER_OF.values() if v is not None}
+    assert used == set(LAYERS)

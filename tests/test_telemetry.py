@@ -192,3 +192,25 @@ def test_get_counter_and_gauge_under_concurrent_writers():
     assert m.get_gauge("g.shared") == float(n_incr - 1)
     assert m.get_gauge("g.never_set") is None
     assert m.get_counter("c.never_bumped") == 0.0
+
+
+def test_add_samples_adds_several_summaries_under_one_exemplar():
+    """The flight recorder's fold adds a trace's whole layer split in
+    one call: every name gets its sample, tagged with the eval."""
+    from nomad_tpu.telemetry import Metrics
+
+    m = Metrics()
+    m.preregister(samples=("trace.life",))
+    m.add_samples(
+        [("trace.life", 12.5), ("trace.self.broker", 10.0)],
+        exemplar="ev-1",
+    )
+    m.add_samples(iter([("trace.life", 7.5)]))
+    dump = m.dump()["samples"]
+    assert dump["trace.life"]["count"] == 2
+    assert dump["trace.life"]["sum"] == 20.0
+    assert dump["trace.self.broker"]["count"] == 1
+    assert dump["trace.life"]["exemplars"] == [
+        {"value": 12.5, "trace_id": "ev-1"}
+    ]
+    assert "trace_self_broker_sum 10.0" in m.prometheus_text()

@@ -287,8 +287,11 @@ def test_traces_http_endpoints():
 
 
 def _assert_well_nested(trace):
-    """Every span's parent exists and encloses it (small epsilon for
-    float math); no orphan (never-closed) spans."""
+    """Every span's parent exists, starts no later than it and, on
+    the same thread, encloses it (small epsilon for float math); no
+    orphan (never-closed) spans.  A span another thread recorded names
+    its cause as parent, and may run after it: `replay.speculate`
+    replays the rows its `batch_worker.fetch` brought."""
     assert trace["orphans"] == 0, trace
     by_id = {s["id"]: s for s in trace["spans"]}
     eps = 1e-3  # ms
@@ -300,6 +303,8 @@ def _assert_well_nested(trace):
         assert parent in by_id, span
         p = by_id[parent]
         assert span["off_ms"] >= p["off_ms"] - eps, (span, p)
+        if span["thread"] != p["thread"]:
+            continue
         assert (
             span["off_ms"] + span["dur_ms"]
             <= p["off_ms"] + p["dur_ms"] + eps
@@ -500,3 +505,396 @@ def test_trace_overhead_under_budget_on_config2_like_run():
         f"tracing overhead {overhead_pct:.1f}% "
         f"(on={t_on:.2f}s off={t_off:.2f}s)"
     )
+
+
+# -- one causal tree, folded by layer (PR 27) -------------------------
+
+
+def _on_thread(name, fn):
+    """Run ``fn`` on a thread of its own (a span's recording thread is
+    part of the record)."""
+    import threading
+
+    th = threading.Thread(target=fn, name=name)
+    th.start()
+    th.join()
+
+
+def _built_trace():
+    """An eval's life laid out by hand, 1 s before now: ingress and
+    broker wait, two overlapping sibling stages, two chunk-wide stages
+    with members=8, a cross-thread speculation caused by the fetch, a
+    commit whose plan crosses to the applier's threads and back."""
+    t = Tracer(ring=8)
+    b = time.monotonic() - 1.0
+    t.begin("ev-f", t0=b, queue="service")
+    t.add_span("ev-f", "ingress.register", b, 0.001, thread="http")
+    t.add_span("ev-f", "broker.wait", b + 0.001, 0.100)
+    # overlapping siblings: the later start owns the overlap
+    t.add_span("ev-f", "batch_worker.simulate", b + 0.102, 0.004)
+    t.add_span("ev-f", "batch_worker.admit", b + 0.104, 0.005)
+    t.add_span(
+        "ev-f", "batch_worker.assemble", b + 0.110, 0.008,
+        members=8, cpu_ms=4.0,
+    )
+    fetch = t.add_span(
+        "ev-f", "batch_worker.fetch", b + 0.120, 0.016,
+        members=8, cpu_ms=0.5,
+    )
+    # the pool thread replays the rows AFTER the fetch that caused it
+    _on_thread(
+        "replay-spec_0",
+        lambda: t.add_span(
+            "ev-f", "replay.speculate", b + 0.140, 0.010,
+            cause=fetch, cpu_ms=9.0,
+        ),
+    )
+    t.add_span("ev-f", "replay.commit_wait", b + 0.145, 0.015)
+    commit = t.add_span("ev-f", "replay.commit", b + 0.160, 0.020)
+
+    def verifier():
+        t.add_span(
+            "ev-f", "plan.queue_wait", b + 0.161, 0.001, cause=commit
+        )
+        t.add_span(
+            "ev-f", "plan.evaluate", b + 0.162, 0.003, cause=commit,
+            cpu_ms=2.5,
+        )
+
+    def committer():
+        t.add_span(
+            "ev-f", "plan.stage_wait", b + 0.165, 0.001, cause=commit
+        )
+        apply = t.add_span(
+            "ev-f", "plan.apply", b + 0.166, 0.004, cause=commit,
+            cpu_ms=3.0,
+        )
+        t.add_span(
+            "ev-f", "store.commit", b + 0.167, 0.002, cause=apply,
+            cpu_ms=1.5,
+        )
+
+    _on_thread("plan-verifier", verifier)
+    _on_thread("plan-applier", committer)
+    t.add_span(
+        "ev-f", "plan.respond_wait", b + 0.170, 0.001, cause=commit
+    )
+    return t, fetch, commit
+
+
+def test_fold_partitions_a_built_trace_by_layer():
+    from nomad_tpu.trace import LAYERS
+
+    t, fetch, commit = _built_trace()
+    trace = t.finish("ev-f", "ack")
+    assert trace is not None
+    fold = trace.fold()
+    assert fold is not None
+    ms = fold.self_ms
+    assert list(ms) == list(LAYERS)
+    approx = lambda v: __import__("pytest").approx(v, abs=1e-6)
+    assert ms["ingress"] == approx(1.0)
+    assert ms["broker"] == approx(100.0)
+    # simulate 2 (admit, the later start, owns the overlap) + admit 5
+    # + assemble 8/8 + fetch 16/8 + the commit's own 20 - 10
+    assert ms["bw_host"] == approx(2.0 + 5.0 + 1.0 + 2.0 + 10.0)
+    # the cross-thread child runs outside its cause's interval and
+    # still counts once; it is deeper than the wait it overlaps
+    assert ms["replay_pool"] == approx(10.0)
+    assert ms["plan_handoff"] == approx(3.0)
+    assert ms["plan_applier"] == approx(3.0 + 2.0)
+    assert ms["store"] == approx(2.0)
+    # commit_wait's remainder, the chunk-mates' 7/8, the root's own
+    covered = 1 + 100 + 7 + 8 + 16 + 20 + 20
+    assert ms["pipeline_wait"] == approx(
+        10.0 + 7.0 + 14.0 + (fold.life_ms - covered)
+    )
+    # the eight sum to the life within 1 us
+    assert abs(sum(ms.values()) - fold.life_ms) < 1e-3
+    # CPU: assemble's share, the pool's, the applier's less the
+    # store's (same thread); the fetch waits on the device by design
+    assert fold.cpu_ms["bw_host"] == approx(0.5)
+    assert fold.cpu_ms["replay_pool"] == approx(9.0)
+    assert fold.cpu_ms["plan_applier"] == approx(2.5 + 3.0 - 1.5)
+    assert fold.cpu_ms["store"] == approx(1.5)
+    assert fold.cpu_wall_ms == approx(1.0 + 10.0 + 3.0 + 2.0 + 2.0)
+    # the served form carries the split and each span's self time
+    doc = t.get("ev-f")
+    assert doc["layers_ms"] == fold.self_ms
+    by_id = {s["id"]: s for s in doc["spans"]}
+    assert by_id[commit]["self_ms"] == approx(10.0)
+    assert by_id[fetch]["self_ms"] == approx(16.0)
+    spec = next(
+        s for s in doc["spans"] if s["name"] == "replay.speculate"
+    )
+    assert spec["parent"] == fetch
+    assert spec["thread"] == "replay-spec_0"
+    assert spec["cpu_ms"] == 9.0
+
+
+def test_fold_reaches_telemetry_and_an_open_span_counts_unfolded():
+    from nomad_tpu.telemetry import Metrics
+    from nomad_tpu.trace import FOLD_SAMPLES
+
+    metrics = Metrics()
+    t, _fetch, _commit = _built_trace()
+    # the spans the finishing thread holds open enclose the finish
+    # itself (broker.ack inside replay.commit): ended there, no orphan
+    with t.span("ev-f", "batch_worker.replay"):
+        with t.span("ev-f", "broker.ack"):
+            settled = t.finish("ev-f", "ack")
+    t.publish(settled, metrics)
+    assert settled.orphans == 0
+    assert metrics.get_counter("trace.folded") == 1.0
+    assert metrics.get_counter("trace.unfolded") == 0.0
+    dump = metrics.dump()["samples"]
+    assert set(FOLD_SAMPLES) <= set(dump)
+    assert all(dump[name]["count"] == 1 for name in FOLD_SAMPLES)
+    total = sum(
+        dump[n]["sum"] for n in FOLD_SAMPLES
+        if n.startswith("trace.self.")
+    )
+    assert abs(total - dump["trace.life"]["sum"]) < 1e-3
+    assert dump["trace.life"]["exemplars"][0]["trace_id"] == "ev-f"
+
+    # a span ANOTHER thread still holds open: the trace cannot be
+    # folded, and the meter says so
+    t.begin("ev-open")
+    opened = []
+    _on_thread(
+        "replay-spec_1",
+        lambda: opened.append(
+            t.span("ev-open", "replay.speculate").__enter__()
+        ),
+    )
+    settled = t.finish("ev-open", "ack")
+    assert settled.orphans == 1
+    assert settled.fold() is None
+    settled.folds = True  # (one ack in FOLD_SAMPLE is the fold's)
+    t.publish(settled, metrics)
+    # an acked eval whose trace left the ring is unfolded too
+    t.publish(t.finish("ev-never-traced", "ack"), metrics)
+    assert metrics.get_counter("trace.folded") == 1.0
+    assert metrics.get_counter("trace.unfolded") == 2.0
+    # the recorder off: nothing is counted at all
+    t.set_enabled(False)
+    t.publish(None, metrics)
+    assert metrics.get_counter("trace.unfolded") == 2.0
+
+
+def test_redelivered_eval_starts_its_own_broker_wait():
+    """A nack re-enqueues the eval; the generation the next dequeue
+    begins is dated back to THAT enqueue, and carries its own
+    `broker.wait` (the first generation keeps its own)."""
+    from nomad_tpu.server.eval_broker import EvalBroker
+
+    TRACE.clear()
+    broker = EvalBroker(nack_timeout=60.0)
+    broker.set_enabled(True)
+    try:
+        ev = mock.evaluation()
+        broker.enqueue(ev)
+        time.sleep(0.02)
+        got, token = broker.dequeue([ev.type], timeout=1.0)
+        assert got is ev
+        first = TRACE.get(ev.id)
+        time.sleep(0.02)
+        broker.nack(ev.id, token)
+        time.sleep(0.03)
+        got, token = broker.dequeue([ev.type], timeout=1.0)
+        assert got is ev
+        second = TRACE.get(ev.id)
+        assert second["trace_id"] != first["trace_id"]
+        assert TRACE.get(first["trace_id"])["outcome"] == "nack"
+        for doc, waited in ((first, 20.0), (second, 30.0)):
+            wait = next(
+                s for s in doc["spans"] if s["name"] == "broker.wait"
+            )
+            # the trace begins where the wait does: at the (re-)enqueue
+            assert wait["off_ms"] == 0.0 and wait["parent"] is None
+            assert wait["dur_ms"] >= waited
+            assert wait["attrs"]["queue"] == ev.type
+            dequeue = next(
+                s for s in doc["spans"]
+                if s["name"] == "broker.dequeue"
+            )
+            # the wait ends at the dequeue mark (recorded first)
+            assert abs(dequeue["off_ms"] - wait["dur_ms"]) < 1.0
+        # the second wait does not reach back into the first delivery
+        assert second["start"] > first["start"] + 0.04
+        broker.ack(ev.id, token)
+        assert TRACE.get(ev.id)["outcome"] == "ack"
+    finally:
+        broker.set_enabled(False)
+        TRACE.clear()
+
+
+def test_served_evals_fold_into_one_causal_tree():
+    """20 registrations over HTTP on a small batch-pipeline server:
+    every acked eval is folded, its trace begins at the handler, the
+    plan's spans hang under the submitter's span (not beside it), and
+    `store.commit` is a child of `plan.apply`."""
+    from nomad_tpu.api import start_http_server
+    from nomad_tpu.api.codec import job_to_dict
+    from nomad_tpu.trace import FOLD_SAMPLE, LAYERS
+
+    TRACE.clear()
+    server = Server(num_schedulers=1, seed=27, batch_pipeline=True)
+    server.start()
+    http = start_http_server(server, port=0)
+    base = f"http://127.0.0.1:{http.port}"
+    try:
+        for node in make_nodes(16, seed=9, dcs=4, big=True):
+            server.register_node(node)
+        eval_ids = []
+        for i in range(20):
+            job = mock.job(id=f"tree-{i}")
+            job.task_groups[0].count = 2
+            job.task_groups[0].tasks[0].resources.cpu = 200
+            req = urllib.request.Request(
+                base + "/v1/jobs",
+                data=json.dumps({"Job": job_to_dict(job)}).encode(),
+                method="POST",
+            )
+            with urllib.request.urlopen(req, timeout=10) as resp:
+                eval_ids.append(json.loads(resp.read())["EvalID"])
+        assert server.drain_to_idle(120)
+
+        # one ack in FOLD_SAMPLE is folded into the telemetry (every
+        # eval's trace folds on demand, below); none of those fails
+        dump = _get_json(base, "/v1/metrics")
+        folded = dump["counters"]["trace.folded"]
+        assert 20 // FOLD_SAMPLE <= folded <= 20 // FOLD_SAMPLE + 1
+        assert dump["counters"]["trace.unfolded"] == 0.0
+        samples = dump["samples"]
+        assert samples["trace.life"]["count"] == folded
+        split = sum(samples[f"trace.self.{k}"]["sum"] for k in LAYERS)
+        assert abs(split - samples["trace.life"]["sum"]) < folded * 1e-3
+        assert samples["trace.self.broker"]["sum"] > 0.0
+        assert samples["trace.self.ingress"]["sum"] > 0.0
+        assert samples["trace.cpu_wall"]["count"] <= folded
+
+        submitters = {
+            "replay.commit", "batch_worker.replay",
+            "batch_worker.sequential", "worker.invoke_scheduler",
+        }
+        for eval_id in eval_ids:
+            doc = _get_json(base, f"/v1/traces/{eval_id}")
+            assert doc["complete"] and doc["orphans"] == 0, doc
+            spans = doc["spans"]
+            by_id = {s["id"]: s for s in spans}
+            names = [s["name"] for s in spans]
+            # the life begins at the handler, then the broker's wait,
+            # both before the first pipeline stage
+            ingress = next(
+                s for s in spans if s["name"] == "ingress.register"
+            )
+            wait = next(s for s in spans if s["name"] == "broker.wait")
+            assert ingress["off_ms"] == 0.0
+            assert ingress["parent"] is None and wait["parent"] is None
+            assert not ingress["thread"].startswith("worker")
+            first_stage = min(
+                s["off_ms"] for s in spans
+                if s["name"].startswith(("batch_worker.", "replay."))
+            )
+            assert wait["off_ms"] + wait["dur_ms"] <= first_stage + 1e-3
+            # the plan's life under the span that submitted it
+            for name in (
+                "plan.queue_wait", "plan.evaluate", "plan.stage_wait",
+                "plan.apply", "plan.respond_wait",
+            ):
+                span = next(s for s in spans if s["name"] == name)
+                assert span["parent"] is not None, (name, names)
+                assert by_id[span["parent"]]["name"] in submitters, (
+                    name, by_id[span["parent"]]["name"],
+                )
+            evaluate = next(
+                s for s in spans if s["name"] == "plan.evaluate"
+            )
+            assert evaluate["thread"] == "plan-verifier"
+            assert by_id[evaluate["parent"]]["thread"] != "plan-verifier"
+            commit = next(
+                s for s in spans if s["name"] == "store.commit"
+            )
+            assert by_id[commit["parent"]]["name"] == "plan.apply"
+            assert commit["dur_ms"] > 0.0
+            # the broker's own ack is the eval's last span, under the
+            # span that acked
+            ack = next(s for s in spans if s["name"] == "broker.ack")
+            assert by_id[ack["parent"]]["name"] in submitters
+            assert abs(
+                ack["off_ms"] + ack["dur_ms"] - doc["life_ms"]
+            ) < 0.5
+            # the served split partitions this eval's life
+            assert abs(
+                sum(doc["layers_ms"].values()) - doc["life_ms"]
+            ) < 1e-3
+            _assert_well_nested(doc)
+    finally:
+        http.stop()
+        server.stop()
+        TRACE.clear()
+
+
+def test_one_trace_in_cpu_sample_reads_the_thread_cpu_clock():
+    """The thread CPU clock is a system call (microseconds on a
+    sandboxed host), so one trace in CPU_SAMPLE pays for it: its open
+    spans carry `cpu_ms`, a caller that times a span by hand gets a
+    clock reading for it, and every other trace gets neither — the
+    fold then leaves those spans out of BOTH sides of the off-CPU
+    share."""
+    from nomad_tpu.trace import CPU_SAMPLE, cpu_ms_since
+
+    t = Tracer(ring=2 * CPU_SAMPLE)
+    sampled = []
+    for i in range(2 * CPU_SAMPLE):
+        eval_id = f"ev-c{i}"
+        t.begin(eval_id)
+        clock = t.cpu_clock(eval_id)
+        with t.span(eval_id, "replay.commit"):
+            sum(range(2000))
+        t.add_span(
+            eval_id, "plan.evaluate", time.monotonic(), 0.0,
+            cpu_ms=cpu_ms_since(clock),
+        )
+        fold = t.finish(eval_id, "ack").fold()
+        spans = {s["name"]: s for s in t.get(eval_id)["spans"]}
+        if clock is not None:
+            sampled.append(i)
+            assert spans["replay.commit"]["cpu_ms"] is not None
+            assert spans["plan.evaluate"]["cpu_ms"] >= 0.0
+            assert fold.cpu_wall_ms > 0.0
+            assert len(list(fold.samples())) == 14
+        else:
+            assert spans["replay.commit"]["cpu_ms"] is None
+            assert spans["plan.evaluate"]["cpu_ms"] is None
+            assert fold.cpu_wall_ms == 0.0
+            # the life and the eight self times, no CPU side
+            assert len(list(fold.samples())) == 9
+    assert sampled == [0, CPU_SAMPLE]
+    assert cpu_ms_since(None) is None
+
+
+def test_one_ack_in_fold_sample_reaches_the_telemetry():
+    """Every trace folds on demand; the ack folds one in FOLD_SAMPLE
+    into the telemetry, so the `trace.*` series are means over the
+    folded evals and the recorder stays cheap on the commit chain."""
+    from nomad_tpu.telemetry import Metrics
+    from nomad_tpu.trace import CPU_SAMPLE, FOLD_SAMPLE
+
+    assert CPU_SAMPLE % FOLD_SAMPLE == 0  # a CPU-sampled trace folds
+    t = Tracer(ring=4 * FOLD_SAMPLE)
+    metrics = Metrics()
+    for i in range(3 * FOLD_SAMPLE):
+        eval_id = f"ev-s{i}"
+        t.begin(eval_id)
+        with t.span(eval_id, "replay.commit"):
+            pass
+        settled = t.finish(eval_id, "ack")
+        assert settled.folds == (i % FOLD_SAMPLE == 0)
+        t.publish(settled, metrics)
+        # any finished trace still says where its life went
+        assert "layers_ms" in t.get(eval_id)
+    assert metrics.get_counter("trace.folded") == 3.0
+    assert metrics.get_counter("trace.unfolded") == 0.0
+    assert metrics.dump()["samples"]["trace.life"]["count"] == 3

@@ -157,3 +157,62 @@ def test_main_usage_error(capsys):
     )
     err = capsys.readouterr().err
     assert "waterfall" in err
+
+
+def test_cross_thread_child_renders_under_its_cause_with_self_time():
+    """The waterfall is the trace's one tree, depth first: a span
+    another thread recorded sits under the span that caused it even
+    where its offset falls among other spans, and a folded trace
+    prints each span's self time and the layer split."""
+    trace = {
+        "trace_id": "eval-t#3",
+        "outcome": "speculative",
+        "duration_ms": 30.0,
+        "life_ms": 30.0,
+        "layers_ms": {
+            "broker": 10.0, "pipeline_wait": 12.5, "bw_host": 2.5,
+            "replay_pool": 3.0, "plan_handoff": 0.0,
+            "plan_applier": 2.0,
+        },
+        "spans": [
+            {"id": 0, "parent": None, "name": "broker.wait",
+             "off_ms": 0.0, "dur_ms": 10.0, "self_ms": 10.0},
+            {"id": 1, "parent": None, "name": "batch_worker.fetch",
+             "off_ms": 11.0, "dur_ms": 2.0, "self_ms": 2.0,
+             "thread": "worker", "attrs": {"members": 8}},
+            # starts AFTER the commit below, caused by the fetch above
+            {"id": 4, "parent": 1, "name": "replay.speculate",
+             "off_ms": 16.0, "dur_ms": 3.0, "self_ms": 3.0,
+             "cpu_ms": 1.25, "thread": "replay-spec_0"},
+            {"id": 2, "parent": None, "name": "replay.commit",
+             "off_ms": 14.0, "dur_ms": 2.5, "self_ms": 0.5,
+             "thread": "worker"},
+            {"id": 3, "parent": 2, "name": "plan.evaluate",
+             "off_ms": 14.2, "dur_ms": 2.0, "self_ms": 2.0,
+             "thread": "plan-verifier"},
+        ],
+    }
+    lines = trace_report.render_trace(trace).splitlines()
+    assert lines[1].startswith("  layers: broker=10.00ms")
+    assert "plan_handoff" not in lines[1]  # empty layers are left out
+    rows = [ln for ln in lines if "ms  " in ln and "layers:" not in ln]
+    order = [
+        next(n for n in (
+            "broker.wait", "batch_worker.fetch", "replay.speculate",
+            "replay.commit", "plan.evaluate",
+        ) if n in ln)
+        for ln in rows
+    ]
+    assert order == [
+        "broker.wait", "batch_worker.fetch", "replay.speculate",
+        "replay.commit", "plan.evaluate",
+    ]
+    spec = next(ln for ln in rows if "replay.speculate" in ln)
+    assert "  replay.speculate" in spec and "self=3.00ms" in spec
+    assert "cpu_ms=1.25" in spec and "thread=replay-spec_0" in spec
+    evaluate = next(ln for ln in rows if "plan.evaluate" in ln)
+    assert "  plan.evaluate" in evaluate
+    commit = next(ln for ln in rows if "replay.commit" in ln)
+    assert "2.50ms" in commit and "self=0.50ms" in commit
+    # a trace that was never folded has no self-time column at all
+    assert "self=" not in trace_report.render_trace(_trace())

@@ -74,7 +74,8 @@ def _context() -> Context:
 
 
 def check() -> Tuple[bool, List[str]]:
-    """Run the 11 migrated stage-accounting rules; returns
+    """Run the migrated stage-accounting rules (the 11 historical
+    checks and ``span-layers``); returns
     ``(ok, [problem strings])`` like the historical monolith."""
     result = run(_context(), MIGRATED_RULES)
     problems = [f.message for f in result.findings]

@@ -8,7 +8,7 @@
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
-echo "== nomadlint: repo-wide run (35 rules, zero findings) =="
+echo "== nomadlint: repo-wide run (36 rules, zero findings) =="
 python -m tools.nomadlint
 
 echo "== nomadlint: selfcheck (every rule trips its bad fixture) =="

@@ -133,6 +133,22 @@ SHARED_STATE_ALLOWLIST: Tuple[Tuple[str, str, str], ...] = (
         "racing lookup sees a complete (old) trace",
     ),
     (
+        "Tracer",
+        r"_segments",
+        "the hot-path resolve skips _lock while the segment map is "
+        "empty (every server that is not a fan-out follower): an "
+        "emptiness test on a dict is GIL-atomic, every mutation "
+        "runs under _lock, and a lease that opens a segment races "
+        "only spans of an eval this server does not hold yet",
+    ),
+    (
+        "Tracer",
+        r"_ingress",
+        "a threading.local: each HTTP handler thread reads and "
+        "writes its own slot (the attribute itself is bound once, "
+        "at construction)",
+    ),
+    (
         "Worker",
         r"_pod",
         "set-once pod-service latch: _attach_pod checks-then-binds "

@@ -1,4 +1,5 @@
-"""The 11 historical stage-accounting checks as individual rules.
+"""The 11 historical stage-accounting checks as individual rules,
+plus ``span-layers`` (the flight recorder's span -> layer table).
 
 These migrated 1:1 from the ``tools/check_stage_accounting.py``
 monolith (which now shims onto them); the check numbers in each
@@ -278,6 +279,130 @@ class SpanRegistryRule(Rule):
             ctx, tmpdir, "trace",
             old='"batch_worker.simulate"',
             new='"batch_worker.renamed_simulate"',
+        )
+
+
+def _literal_assignment(tree: ast.AST, name: str):
+    """The value node of a module-level ``name = ...`` (annotated or
+    not)."""
+    for node in ast.walk(tree):
+        targets = ()
+        if isinstance(node, ast.Assign):
+            targets = node.targets
+        elif isinstance(node, ast.AnnAssign):
+            targets = (node.target,)
+        if any(
+            isinstance(t, ast.Name) and t.id == name for t in targets
+        ):
+            return node.value
+    return None
+
+
+@register
+class SpanLayersRule(Rule):
+    """The flight recorder's fold sums every span's self time into
+    its layer (``trace.self.<layer>`` on /v1/metrics, the benchmark's
+    per-layer self-time metrics).  ``LAYER_OF`` in trace.py must
+    therefore cover ``SPAN_NAMES`` exactly — every name has a layer
+    or is marked event-only (``EVENT``), no entry names a span that
+    is not registered — and every layer of ``LAYERS`` must have at
+    least one span name: a renamed stage cannot silently leave its
+    layer's metric, and a layer cannot silently lose its last
+    source."""
+
+    name = "span-layers"
+    description = "trace.LAYER_OF covers SPAN_NAMES; no empty layer"
+
+    def check(self, ctx: Context) -> List[Finding]:
+        path = ctx.path("trace")
+        tree = ctx.tree(path)
+        registry = astutil.span_registry(tree)
+        table = _literal_assignment(tree, "LAYER_OF")
+        layers = astutil.assigned_strings(tree, "LAYERS")
+        if not isinstance(table, ast.Dict) or not layers:
+            return [
+                Finding(
+                    self.name, path, 0,
+                    "could not find the LAYER_OF dict literal and "
+                    "the LAYERS tuple in nomad_tpu/trace.py",
+                )
+            ]
+        out: List[Finding] = []
+        layer_of = {}
+        for key, value in zip(table.keys, table.values):
+            if not (
+                isinstance(key, ast.Constant)
+                and isinstance(key.value, str)
+            ):
+                continue
+            if isinstance(value, ast.Constant) and isinstance(
+                value.value, str
+            ):
+                layer_of[key.value] = value.value
+            elif (
+                isinstance(value, ast.Name) and value.id == "EVENT"
+            ) or (
+                isinstance(value, ast.Constant)
+                and value.value is None
+            ):
+                layer_of[key.value] = None
+            else:
+                out.append(
+                    Finding(
+                        self.name, path, key.lineno,
+                        f"LAYER_OF[{key.value!r}] is neither a "
+                        "layer literal nor EVENT",
+                    )
+                )
+                layer_of[key.value] = None
+        missing = registry - set(layer_of)
+        if missing:
+            out.append(
+                Finding(
+                    self.name, path, 0,
+                    "span names with no LAYER_OF entry (give each a "
+                    "layer or mark it EVENT — the fold would look "
+                    "through it and its layer's metric lose it): "
+                    f"{sorted(missing)}",
+                )
+            )
+        stray = set(layer_of) - registry
+        if stray:
+            out.append(
+                Finding(
+                    self.name, path, 0,
+                    "LAYER_OF entries that are not in SPAN_NAMES "
+                    f"(a renamed stage left them): {sorted(stray)}",
+                )
+            )
+        used = {v for v in layer_of.values() if v is not None}
+        unknown = used - layers
+        if unknown:
+            out.append(
+                Finding(
+                    self.name, path, 0,
+                    "LAYER_OF names layers missing from LAYERS "
+                    f"(no trace.self.* series): {sorted(unknown)}",
+                )
+            )
+        empty = layers - used
+        if empty:
+            out.append(
+                Finding(
+                    self.name, path, 0,
+                    "layers of LAYERS with no span name in LAYER_OF "
+                    "(their trace.self.* series would read 0 "
+                    f"forever): {sorted(empty)}",
+                )
+            )
+        return out
+
+    @classmethod
+    def bad_fixture(cls, ctx, tmpdir):
+        return cls._mutated(
+            ctx, tmpdir, "trace",
+            old='    "replay.speculate": "replay_pool",\n',
+            new="",
         )
 
 
@@ -1547,6 +1672,7 @@ MIGRATED_RULES = (
     "stage-orphans",
     "bench-stage-export",
     "span-registry",
+    "span-layers",
     "device-metrics",
     "debug-bundle-device",
     "placement-metrics",

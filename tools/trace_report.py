@@ -11,15 +11,29 @@ URL, a file path, or ``-`` for stdin.
     curl -s .../v1/cluster/traces/abc123 | python tools/trace_report.py -
 
 Output per trace: a header line (eval id, outcome, total duration,
-span/drop counts) and one row per span — offset from the trace root,
-a per-server lane tag, a depth-indented name, the span duration, a
-proportional bar, and the non-default attributes — so a slow eval
-reads as a waterfall:
+span/drop counts), for a folded trace the eval's life split by layer
+(the server's ``LAYER_OF`` table; the layers sum to the life), and one
+row per span — offset from the trace root (where the eval was created:
+the start of ``ingress.register``, or of ``broker.wait``), a
+per-server lane tag, a depth-indented name, the span duration, its
+self time (what the span owned of the eval's life: its duration less
+what its children cover), a proportional bar, and the non-default
+attributes — so a slow eval reads as a waterfall:
 
     trace 53a1b2#7 outcome=speculative 12.41ms spans=12
-        0.00ms  [leader  ]  broker.dequeue            0.00ms  queue=service
-        0.21ms  [server-1]  batch_worker.simulate     1.20ms  ==
+      layers: broker=9.80ms pipeline_wait=1.10ms bw_host=0.90ms ...
+        0.00ms  [leader  ]  broker.wait               9.80ms  self=9.80ms  ====
+        9.80ms  [leader  ]  broker.dequeue            0.00ms
+       10.10ms  [leader  ]  replay.commit             1.20ms  self=0.31ms  ==
+       10.15ms  [leader  ]    plan.queue_wait         0.08ms  self=0.08ms  =
         ...
+
+The rows are the trace's ONE tree, depth first: a span sits under the
+span that caused it (its ``parent``) even when another thread recorded
+it — ``plan.evaluate`` (plan-verifier thread) under the ``replay.commit``
+that submitted the plan, ``replay.speculate`` (pool thread) under the
+``batch_worker.fetch`` whose rows it replays — and siblings run in
+start order.
 
 Stitched cross-server traces get one lane per ``server_id``: spans a
 follower recorded and shipped back carry that follower's id in the
@@ -74,6 +88,26 @@ def _depths(spans: List[Dict]) -> Dict[int, int]:
     return depths
 
 
+def _tree_order(spans: List[Dict]) -> List[Dict]:
+    """Depth first over the cause links, siblings by start: a
+    cross-thread child lands under its cause, not wherever its offset
+    falls among another thread's spans."""
+    ids = {s["id"] for s in spans}
+    children: Dict[object, List[Dict]] = {}
+    for s in sorted(spans, key=lambda s: s["off_ms"]):
+        parent = s.get("parent")
+        children.setdefault(
+            parent if parent in ids else None, []
+        ).append(s)
+    out: List[Dict] = []
+    stack = list(reversed(children.get(None, [])))
+    while stack:
+        s = stack.pop()
+        out.append(s)
+        stack.extend(reversed(children.get(s["id"], [])))
+    return out
+
+
 def _fmt_attrs(attrs: Dict) -> str:
     return " ".join(f"{k}={v}" for k, v in sorted(attrs.items()))
 
@@ -94,7 +128,7 @@ def _skew_suspect(span: Dict, total) -> bool:
 
 def render_trace(trace: Dict) -> str:
     """One trace -> waterfall text (no trailing newline)."""
-    spans = sorted(trace.get("spans") or [], key=lambda s: s["off_ms"])
+    spans = _tree_order(trace.get("spans") or [])
     total = trace.get("duration_ms")
     # lane name for spans the serving server recorded itself: the
     # cluster endpoint stamps the winning server as "server"
@@ -123,6 +157,13 @@ def render_trace(trace: Dict) -> str:
         header += f"\n  fan-in: asked={len(reach)}" + (
             f" unreachable={','.join(bad)}" if bad else ""
         )
+    if trace.get("layers_ms"):
+        # a folded trace: where the eval's life went, by layer
+        header += "\n  layers: " + " ".join(
+            f"{layer}={ms:.2f}ms"
+            for layer, ms in trace["layers_ms"].items()
+            if ms
+        )
     if trace.get("attrs"):
         header += "\n  " + _fmt_attrs(trace["attrs"])
     lines = [header]
@@ -133,6 +174,8 @@ def render_trace(trace: Dict) -> str:
     )
     lane_w = max((len(lane) for lane in lanes), default=0)
     scale = total if total else 1.0
+    # the self-time column exists only for a folded trace
+    self_w = 16 if any(s.get("self_ms") for s in spans) else 0
     for s in spans:
         dur = s.get("dur_ms")
         bar = ""
@@ -143,13 +186,19 @@ def render_trace(trace: Dict) -> str:
         lane_txt = (
             f"[{_lane(s, local):<{lane_w}}]  " if multi_lane else ""
         )
+        self_ms = s.get("self_ms")
+        self_txt = (
+            f"self={self_ms:.2f}ms" if self_ms and dur else ""
+        )
         row = (
             f"  {s['off_ms']:9.2f}ms  {lane_txt}{name:<{name_w}}  "
-            f"{dur_txt:>10}  {bar:<{BAR_WIDTH}}"
+            f"{dur_txt:>10}  {self_txt:<{self_w}}{bar:<{BAR_WIDTH}}"
         )
         extras = dict(s.get("attrs") or {})
         if multi_lane:
             extras.pop("server_id", None)  # shown as the lane tag
+        if s.get("cpu_ms") is not None:
+            extras["cpu_ms"] = round(s["cpu_ms"], 3)
         if s.get("thread"):
             extras["thread"] = s["thread"]
         if extras:
