@@ -109,6 +109,20 @@ MAX_PRE_ROWS = 512  # pre-placement delta rows before falling back
 # ladder keeps the number of XLA trace shapes bounded exactly like
 # the old fixed width did.
 CHUNK_BUCKETS = (2, 4, 8)
+# the chunk launch's keyword inputs that carry a leading eval axis
+# (`_Assembled` fields of the same names): what `_launch_chunk`
+# slices a chunk out of, and what a launch shape's family resizes
+_PER_EVAL_KWARGS = (
+    "wanted", "coll0", "affinity", "spread", "deltas", "pre",
+    "port_ask", "dev_ask", "dev_aff", "dev_aff_on", "occ0", "dh_tg",
+)
+# the cold-compile shield's counters, zero-registered at Server
+# construction: evals a cold launch shape sent down the host path, and
+# family members a first sighting scheduled beyond its own shape
+SHIELD_COUNTERS = (
+    "batch_worker.cold_shape_fallbacks",
+    "batch_worker.sibling_compiles",
+)
 # widest chunk bucket, kept under its historical name: the assembly
 # arena, warm_shapes and the mesh path still use it as the default
 # eval-axis alignment
@@ -719,6 +733,7 @@ class BatchWorker(Worker):
         self.fallbacks = 0
         self.errors = 0
         self.cold_shape_fallbacks = 0
+        self.sibling_compiles = 0
         # background shield compiles that FAILED: each parks its launch
         # shape on the sequential host path for good, so a kernel the
         # device compiler refuses must be countable, not one log line
@@ -4047,12 +4062,9 @@ class BatchWorker(Worker):
                             # included), or warmed shapes are never
                             # recognized
                             self._compiled.add(
-                                (
-                                    "chained_plan_picks_cols",
-                                    self._backend_epoch,
-                                )
-                                + self._launch_signature(
-                                    args, kwargs
+                                self._compile_key(
+                                    chained_plan_picks_cols,
+                                    args, kwargs,
                                 )
                             )
 
@@ -5278,23 +5290,15 @@ class BatchWorker(Worker):
         )
         kwargs = dict(
             spread_fit=asm.spread_fit,
-            wanted=asm.wanted[c0:c1],
-            coll0=sl(asm.coll0, c0, c1),
-            affinity=sl(asm.affinity, c0, c1),
-            spread=sl(asm.spread, c0, c1),
-            deltas=sl(asm.deltas, c0, c1),
-            pre=sl(asm.pre, c0, c1),
-            port_ask=sl(asm.port_ask, c0, c1),
             port_used0=ports,
-            dev_ask=sl(asm.dev_ask, c0, c1),
             dev_free0=devs,
-            dev_aff=sl(asm.dev_aff, c0, c1),
-            dev_aff_on=sl(asm.dev_aff_on, c0, c1),
-            occ0=sl(asm.occ0, c0, c1),
-            dh_tg=sl(asm.dh_tg, c0, c1),
             return_carry=True,
         )
-        if check_ready and not self._launch_ready(args, kwargs):
+        for name in _PER_EVAL_KWARGS:
+            kwargs[name] = sl(getattr(asm, name), c0, c1)
+        if check_ready and not self._launch_ready(
+            args, kwargs, family=True
+        ):
             # first sighting of this launch shape: an XLA compile takes
             # seconds and must not stall the scheduling pipeline —
             # compile in the background, schedule these evals exactly
@@ -5309,7 +5313,7 @@ class BatchWorker(Worker):
             # consume the very carry the plain launch below is using
             donated = chained_plan_picks_cols_donated()
             if self._launch_ready(
-                args, kwargs, fn=donated, clone_args=True
+                args, kwargs, fn=donated, clone_args=True, family=True
             ):
                 fn = donated
                 self._count("donated_launches")
@@ -5468,8 +5472,17 @@ class BatchWorker(Worker):
             for l in leaves
         )
 
+    def _compile_key(self, fn, args, kwargs) -> tuple:
+        """The shield's key of one launch shape.  Backend epoch in the
+        key: an executable compiled before a supervisor
+        failover/recovery targeted a different backend."""
+        return (
+            getattr(fn, "__name__", str(fn)),
+            self._backend_epoch,
+        ) + self._launch_signature(args, kwargs)
+
     def _launch_ready(
-        self, args, kwargs, fn=None, clone_args=False
+        self, args, kwargs, fn=None, clone_args=False, family=False
     ) -> bool:
         """Whether this launch shape has a compiled executable.  A new
         shape kicks off a background compile and returns False — the
@@ -5482,6 +5495,13 @@ class BatchWorker(Worker):
         buffers another launch is concurrently reading — the clone
         gives the background run its own device copies to burn.
 
+        ``family=True`` (the chunk path's `chained_plan_picks_cols`
+        launches) compiles, with a shape met for the first time, its
+        whole family (`_launch_family`): a job shape's other chunk
+        widths and the carry-donating variant are certain to follow,
+        and met one by one each would cost its chunk a trip down the
+        host path and a compile of its own, seconds apart.
+
         NOMAD_TPU_SYNC_COMPILE=1 (the test suite, via conftest) makes
         cold compiles block instead, so prescore-rate assertions are
         deterministic."""
@@ -5491,12 +5511,7 @@ class BatchWorker(Worker):
             return True
         if fn is None:
             fn = chained_plan_picks_cols
-        # backend epoch in the key: an executable compiled before a
-        # supervisor failover/recovery targeted a different backend
-        sig = (
-            getattr(fn, "__name__", str(fn)),
-            self._backend_epoch,
-        ) + self._launch_signature(args, kwargs)
+        sig = self._compile_key(fn, args, kwargs)
         with self._compile_lock:
             if sig in self._compiled:
                 return True
@@ -5506,23 +5521,21 @@ class BatchWorker(Worker):
                 # be exactly the stall this shield exists to prevent
                 return False
             self._compiling.add(sig)
+        members = [(sig, fn, args, kwargs, clone_args)]
+        if family:
+            for member in self._launch_family(args, kwargs):
+                self._count("sibling_compiles")
+                # its arguments are copies already
+                members.append(member + (False,))
 
-        def compile_in_background():
+        def compile_in_background(sig, fn, args, kwargs, clone_args):
             ok = True
             try:
                 import jax as _jax
 
-                a, k = args, kwargs
                 if clone_args:
-                    a, k = _jax.tree_util.tree_map(
-                        lambda leaf: (
-                            leaf.copy()
-                            if hasattr(leaf, "copy")
-                            else leaf
-                        ),
-                        (args, kwargs),
-                    )
-                _jax.block_until_ready(fn(*a, **k))
+                    args, kwargs = self._clone_launch(args, kwargs)
+                _jax.block_until_ready(fn(*args, **kwargs))
             except Exception:  # noqa: BLE001
                 ok = False
                 LOG.exception("background kernel compile failed")
@@ -5533,12 +5546,85 @@ class BatchWorker(Worker):
                     sig
                 )
 
-        threading.Thread(
-            target=compile_in_background,
-            name="kernel-compile",
-            daemon=True,
-        ).start()
+        # a thread each: XLA's compile drops the GIL, so a family's
+        # members compile side by side
+        for member in members:
+            threading.Thread(
+                target=compile_in_background,
+                args=member,
+                name="kernel-compile",
+                daemon=True,
+            ).start()
         return False
+
+    @staticmethod
+    def _clone_launch(args, kwargs):
+        import jax
+
+        return jax.tree_util.tree_map(
+            lambda leaf: (
+                leaf.copy() if hasattr(leaf, "copy") else leaf
+            ),
+            (args, kwargs),
+        )
+
+    def _launch_family(self, args, kwargs) -> list:
+        """The members of a launch shape's family that are neither
+        compiled nor compiling, claimed in `_compiling`, each as
+        ``(sig, fn, args, kwargs)`` on arguments of its own.
+
+        The family is what the first sighting of a job shape makes
+        certain to follow: the same arguments at every width of the
+        chunk ladder (the flush picks a width from the backlog) x
+        {plain, carry-donating where donation is on} (every chain
+        past its first chunk).  It never spans what only another job
+        could bring (T, P, the spread, port and device axes).  Each
+        member's arguments are copies made HERE, on the caller's
+        thread, before the caller launches anything: a member's
+        execution then never reads a live buffer that a later
+        donating launch consumes, and never donates one."""
+        fns = [chained_plan_picks_cols]
+        if self._donation_enabled():
+            fns.append(chained_plan_picks_cols_donated())
+        members = []
+        for width in self._chunk_buckets():
+            a, k = self._resize_launch(args, kwargs, width)
+            for fn in fns:
+                sig = self._compile_key(fn, a, k)
+                with self._compile_lock:
+                    if (
+                        sig in self._compiled
+                        or sig in self._compiling
+                        or sig in self._compile_failed
+                    ):
+                        continue
+                    self._compiling.add(sig)
+                members.append((sig, fn, *self._clone_launch(a, k)))
+        return members
+
+    @staticmethod
+    def _resize_launch(args, kwargs, width: int):
+        """A chunk launch's arguments at another chunk width: the
+        leading eval axis of every per-eval leaf (what `_launch_chunk`
+        slices) resized to ``width`` rows, by repeating the chunk's
+        own rows where it grows, so every row stays a well-formed
+        eval.  The columns, the carry and P are not per eval."""
+        rows = np.arange(width) % args[7].shape[0]
+
+        def resize(x):
+            if x is None:
+                return None
+            if isinstance(x, np.ndarray):
+                return x[rows]
+            return type(x)(
+                *[None if f is None else f[rows] for f in x]
+            )
+
+        args = args[:6] + (resize(args[6]), resize(args[7])) + args[8:]
+        kwargs = dict(kwargs)
+        for name in _PER_EVAL_KWARGS:
+            kwargs[name] = resize(kwargs[name])
+        return args, kwargs
 
     # ------------------------------------------------------------------
 

@@ -4,7 +4,8 @@ Plans dequeue in priority order, every touched node is re-verified
 against current state (evaluateNodePlan:629 re-runs AllocsFit), and the
 feasible subset commits through the store's plan-results write path
 (partial commits set a refresh index so the submitting worker retries on
-fresh state).  Two reference mechanisms are reproduced:
+fresh state).  Two reference mechanisms are reproduced, and one is this
+repo's own:
 
 * **Pipelining** (plan_apply.go:45-70): a verifier thread checks plan
   N+1 against an *optimistic* view — base state plus the results of
@@ -18,6 +19,14 @@ fresh state).  Two reference mechanisms are reproduced:
 * **EvaluatePool** (plan_apply_pool.go:18): per-node verification fans
   out across a thread pool (size cores/2) when a plan touches enough
   nodes to pay for the dispatch.
+* **Direct apply** (`PlanApplier.apply`): the pipeline pays when
+  submitters overlap.  A plan that finds the applier idle — nothing
+  queued, verifying, staged or committing — has nothing to overlap
+  with, so its submitter verifies and commits it on its own thread: no
+  queue, no node fan-out, no stage, no wake-up.  One algorithm (verify,
+  then commit, serialized); what the applier observes decides which
+  thread runs it.  At every instant either the pipeline works or one
+  direct apply does, never both.
 """
 from __future__ import annotations
 
@@ -298,11 +307,18 @@ def _verify_csi_claims(store: StateStore, result: PlanResult) -> bool:
     return ok
 
 
+# one increment a plan, by the path it took through the applier: on
+# its submitter's thread, or off the plan queue into the pipeline
+PLAN_COUNTERS = ("plan.direct", "plan.queued")
+
+
 class PlanApplier:
     """Verifier + committer pipeline with capacity-change fanout to
     blocked evals.  Commits are strictly serialized and ordered; the
     verifier runs one (or two, counting the staged slot) plans ahead
-    against an `OptimisticState` overlay."""
+    against an `OptimisticState` overlay.  `apply` is the way in: a
+    plan that finds all of this idle is verified and committed by its
+    submitter, and the pipeline serves the plans that find it busy."""
 
     def __init__(
         self,
@@ -339,8 +355,21 @@ class PlanApplier:
         self._lock = threading.Lock()
         self._inflight: List[PlanResult] = []
         self._epoch = 0  # bumped when an apply fails
+        # who holds the applier (all under _lock).  The pipeline does
+        # while the verifier has a plan in hand (`_verifying`: that
+        # verifier's generation, its stop event) or a verified result
+        # awaits its commit (`_inflight`); a submitter does while it
+        # applies its plan itself (`_direct`).  Each side claims only
+        # when the other holds nothing, so verifications and commits
+        # stay serialized whichever thread runs them.  `_released`
+        # wakes the verifier and stop() when a direct apply lets go.
+        self._released = threading.Condition(self._lock)
+        self._direct = False
+        self._verifying: Optional[threading.Event] = None
         self.applied = 0
         self.overlap_verifies = 0  # verifications that ran on an overlay
+        if metrics is not None:
+            metrics.preregister(counters=PLAN_COUNTERS)
 
     def start(self) -> None:
         # re-entrant after stop() (leadership can be re-established,
@@ -353,6 +382,7 @@ class PlanApplier:
             self.pool = EvaluatePool(self.pool.workers)
         with self._lock:
             self._inflight = []
+            self._verifying = None
         self._verify_thread = threading.Thread(
             target=self._verify_loop,
             args=(self._stop, self._staged),
@@ -370,6 +400,13 @@ class PlanApplier:
 
     def stop(self) -> None:
         self._stop.set()
+        with self._lock:
+            # a direct apply that passed its last fence before the
+            # event was set is in the store now: it lets go when that
+            # write returns, and none that claims the applier later
+            # passes a fence of this generation
+            while self._direct:
+                self._released.wait()
         for t in (self._verify_thread, self._commit_thread):
             if t is not None:
                 t.join(timeout=2.0)
@@ -385,17 +422,119 @@ class PlanApplier:
                 return
 
     # ------------------------------------------------------------------
+    # the way in: direct when the applier is idle, the pipeline when not
+    # ------------------------------------------------------------------
+
+    def apply(
+        self, plan: Plan, cause: Optional[int] = None,
+        timeout: Optional[float] = 10.0,
+    ) -> PlanResult:
+        """Verify and commit one plan; returns when it is answered.
+
+        A plan that finds the applier idle is verified and committed
+        on the calling thread (`plan.direct`).  One that finds it busy
+        is enqueued for the pipeline and waited for, ``timeout``
+        seconds at most (`plan.queued`, counted where the verifier
+        takes it off the queue).  ``cause`` is the id of the caller's
+        open span, the parent of the plan's spans.  Raises
+        NotLeaderError where the plan queue is disabled or leadership
+        went before the commit, TimeoutError, or what the apply
+        raised."""
+        stop = self._claim_direct()
+        if stop is not None:
+            return self._apply_direct(plan, cause, stop)
+        pending = self.plan_queue.enqueue(plan, cause=cause)
+        try:
+            return pending.wait(timeout=timeout)
+        finally:
+            if pending.t_responded is not None:
+                # the wake-up: the committer responded, this thread
+                # resumes (it has to win the GIL first)
+                TRACE.add_span(
+                    plan.eval_id, "plan.respond_wait",
+                    pending.t_responded,
+                    time.monotonic() - pending.t_responded,
+                )
+
+    def _claim_direct(self) -> Optional[threading.Event]:
+        """Take the applier for the calling thread if nothing is
+        queued, verifying, staged or committing: returns the running
+        generation's stop event (its fences read it), or None where
+        the pipeline has or is owed work — or the queue is disabled,
+        which is enqueue's to refuse."""
+        with self._lock:
+            if (
+                self._direct
+                or self._verifying is not None
+                or self._inflight
+                or not self.plan_queue.idle()
+            ):
+                return None
+            self._direct = True
+            return self._stop
+
+    def _apply_direct(
+        self, plan: Plan, cause: Optional[int], stop: threading.Event
+    ) -> PlanResult:
+        """What the two pipeline threads do to one plan, on the thread
+        that claimed the applier: fence, verify on the store as it is
+        (nothing is in flight, so there is no overlay, and per node in
+        a plain loop: a pool's futures are thread crossings too),
+        fence, commit, let go."""
+        self._incr("plan.direct")
+        try:
+            self._fence_direct(plan, stop)
+            start = time.monotonic()
+            with TRACE.span(
+                plan.eval_id, "plan.evaluate", cause=cause,
+                overlay=False,
+            ) as span, profiler_annotation("plan.evaluate"):
+                result, full = evaluate_plan(self.store, plan, None)
+                span.note(full=full)
+            if self.metrics is not None:
+                self.metrics.add_sample(
+                    "plan.evaluate",
+                    (time.monotonic() - start) * 1000.0,
+                    exemplar=plan.eval_id or None,
+                )
+            self._fence_direct(plan, stop)
+            try:
+                self._commit(plan, result, full, cause=cause)
+            except Exception:
+                with self._lock:
+                    self._epoch += 1
+                raise
+            return result
+        finally:
+            with self._lock:
+                self._direct = False
+                self._released.notify_all()
+
+    def _fence_direct(self, plan: Plan, stop: threading.Event) -> None:
+        # the pipeline's two fences (before the verification, between
+        # it and the commit), and its threads' stop event with them
+        if stop.is_set() or self._not_leader():
+            self._note_not_leader(plan)
+            raise NotLeaderError(None)
+
+    def _incr(self, name: str) -> None:
+        if self.metrics is not None:
+            self.metrics.incr(name)
+
+    # ------------------------------------------------------------------
     # stage 1: verification (overlapped with stage-2 commits)
     # ------------------------------------------------------------------
 
     def _not_leader(self) -> bool:
         return self._leader_check is not None and not self._leader_check()
 
+    def _note_not_leader(self, plan: Plan) -> None:
+        self._incr("leadership.plan_rejected")
+        if plan.eval_id:
+            TRACE.event(plan.eval_id, "plan.not_leader")
+
     def _reject_not_leader(self, pending) -> None:
-        if self.metrics is not None:
-            self.metrics.incr("leadership.plan_rejected")
-        if pending.plan.eval_id:
-            TRACE.event(pending.plan.eval_id, "plan.not_leader")
+        self._note_not_leader(pending.plan)
         pending.respond(None, NotLeaderError(None))
 
     def _verify_loop(self, stop: threading.Event,
@@ -404,79 +543,99 @@ class PlanApplier:
             pending = self.plan_queue.dequeue(timeout=0.1)
             if pending is None:
                 continue
-            if self._not_leader():
-                # leadership revoked with this plan in flight: reject
-                # before any verification work — the worker nacks the
-                # eval for redelivery under the next leadership
-                self._reject_not_leader(pending)
-                continue
-            import time as _time
-
-            start = _time.monotonic()
-            cpu0 = TRACE.cpu_clock(pending.plan.eval_id)
+            # counted where the pipeline takes it in hand, so a plan
+            # enqueued past `apply` (the fan-out leader's and the
+            # cluster's RPC handlers) counts too
+            self._incr("plan.queued")
             with self._lock:
-                overlay = list(self._inflight)
-                epoch = self._epoch
-            state = (
-                OptimisticState(self.store, overlay)
-                if overlay
-                else self.store
-            )
+                # a direct apply holds the applier: no verification
+                # begins until it has let go (its commit is in the
+                # store by then, so this one sees it)
+                while self._direct:
+                    self._released.wait()
+                self._verifying = stop
             try:
-                with profiler_annotation("plan.evaluate"):
-                    result, full = evaluate_plan(
-                        state, pending.plan, self.pool
-                    )
-            except Exception as exc:  # noqa: BLE001
-                pending.respond(None, exc)
-                continue
-            if overlay:
-                self.overlap_verifies += 1
-            end = pending.t_evaluated = _time.monotonic()
-            verify_dt = end - start
-            if self.metrics is not None:
-                # (reference plan_apply.go:401 plan.evaluate timing)
-                self.metrics.add_sample(
-                    "plan.evaluate", verify_dt * 1000.0,
-                    exemplar=pending.plan.eval_id or None,
-                )
-            # flight recorder: the plan's wait in the queue and the
-            # verification interval on the submitting eval's trace,
-            # both under the submitter's span (the cause the plan
-            # carries), recorded by this thread
-            eval_id = pending.plan.eval_id
-            if eval_id:
-                TRACE.add_span(
-                    eval_id, "plan.queue_wait", pending.t_enqueued,
-                    start - pending.t_enqueued, cause=pending.cause,
-                )
-                TRACE.add_span(
-                    eval_id, "plan.evaluate", start, verify_dt,
-                    cause=pending.cause,
-                    overlay=bool(overlay), full=full,
-                    cpu_ms=cpu_ms_since(cpu0),
-                )
-            with self._lock:
-                self._inflight.append(result)
-            # blocks while the committer still holds an earlier plan:
-            # that wait IS the pipeline bubble the overlap hides
-            staged = False
-            while not stop.is_set():
-                try:
-                    staged_q.put(
-                        (pending, result, full, epoch), timeout=0.1
-                    )
-                    staged = True
-                    break
-                except _queue.Full:
-                    continue
-            if not staged:
-                # shutdown raced the hand-off: fail fast like every
-                # other flush path instead of leaving the submitter
-                # to hit its wait timeout
+                self._verify(pending, stop, staged_q)
+            finally:
+                # staged, its result is in _inflight and holds the
+                # applier from here; answered, nothing does
                 with self._lock:
-                    self._remove_inflight_locked(result)
-                pending.respond(None, NotLeaderError(None))
+                    if self._verifying is stop:
+                        self._verifying = None
+
+    def _verify(self, pending, stop: threading.Event,
+                staged_q: _queue.Queue) -> None:
+        if self._not_leader():
+            # leadership revoked with this plan in flight: reject
+            # before any verification work — the worker nacks the
+            # eval for redelivery under the next leadership
+            self._reject_not_leader(pending)
+            return
+        start = time.monotonic()
+        cpu0 = TRACE.cpu_clock(pending.plan.eval_id)
+        with self._lock:
+            overlay = list(self._inflight)
+            epoch = self._epoch
+        state = (
+            OptimisticState(self.store, overlay)
+            if overlay
+            else self.store
+        )
+        try:
+            with profiler_annotation("plan.evaluate"):
+                result, full = evaluate_plan(
+                    state, pending.plan, self.pool
+                )
+        except Exception as exc:  # noqa: BLE001
+            pending.respond(None, exc)
+            return
+        if overlay:
+            self.overlap_verifies += 1
+        end = pending.t_evaluated = time.monotonic()
+        verify_dt = end - start
+        if self.metrics is not None:
+            # (reference plan_apply.go:401 plan.evaluate timing)
+            self.metrics.add_sample(
+                "plan.evaluate", verify_dt * 1000.0,
+                exemplar=pending.plan.eval_id or None,
+            )
+        # flight recorder: the plan's wait in the queue and the
+        # verification interval on the submitting eval's trace,
+        # both under the submitter's span (the cause the plan
+        # carries), recorded by this thread
+        eval_id = pending.plan.eval_id
+        if eval_id:
+            TRACE.add_span(
+                eval_id, "plan.queue_wait", pending.t_enqueued,
+                start - pending.t_enqueued, cause=pending.cause,
+            )
+            TRACE.add_span(
+                eval_id, "plan.evaluate", start, verify_dt,
+                cause=pending.cause,
+                overlay=bool(overlay), full=full,
+                cpu_ms=cpu_ms_since(cpu0),
+            )
+        with self._lock:
+            self._inflight.append(result)
+        # blocks while the committer still holds an earlier plan:
+        # that wait IS the pipeline bubble the overlap hides
+        staged = False
+        while not stop.is_set():
+            try:
+                staged_q.put(
+                    (pending, result, full, epoch), timeout=0.1
+                )
+                staged = True
+                break
+            except _queue.Full:
+                continue
+        if not staged:
+            # shutdown raced the hand-off: fail fast like every
+            # other flush path instead of leaving the submitter
+            # to hit its wait timeout
+            with self._lock:
+                self._remove_inflight_locked(result)
+            pending.respond(None, NotLeaderError(None))
 
     # ------------------------------------------------------------------
     # stage 2: ordered commit
@@ -553,9 +712,7 @@ class PlanApplier:
         self, plan: Plan, result: PlanResult, full: bool,
         cause: Optional[int] = None,
     ) -> None:
-        import time as _time
-
-        start = _time.monotonic()
+        start = time.monotonic()
         if (
             result.node_update
             or result.node_allocation
@@ -593,18 +750,11 @@ class PlanApplier:
         if self.metrics is not None:
             # (reference plan_apply.go:185 plan.evaluate/apply timings)
             self.metrics.add_sample(
-                "plan.apply", (_time.monotonic() - start) * 1000.0,
+                "plan.apply", (time.monotonic() - start) * 1000.0,
                 exemplar=plan.eval_id or None,
             )
             if not full:
                 self.metrics.incr("plan.partial_commit")
-
-    def apply(self, plan: Plan) -> PlanResult:
-        """Synchronous verify+commit (test/tooling path; production
-        traffic flows through the two pipeline threads)."""
-        result, full = evaluate_plan(self.store, plan, self.pool)
-        self._commit(plan, result, full)
-        return result
 
     def _notify_capacity_change(self, result: PlanResult, index: int) -> None:
         """Stopped/preempted allocs free capacity: unblock their node

@@ -15,7 +15,9 @@ from ..structs import Plan, PlanResult
 
 
 class PendingPlan:
-    """A plan on its way through the applier.  It crosses three
+    """A plan on its way through the applier's pipeline (one that found
+    the applier idle never becomes one: ``PlanApplier.apply`` runs it
+    on the submitter's thread).  It crosses three
     threads and back (submitter -> verifier -> committer -> submitter),
     so it carries what the flight recorder needs to keep the eval's
     trace one tree: ``cause``, the id of the submitter's open span
@@ -62,6 +64,13 @@ class PlanQueue:
             if not enabled:
                 self.flush()
             self._lock.notify_all()
+
+    def idle(self) -> bool:
+        """Enabled and holding no plan: half of what a submitter has
+        to observe (the other half is the applier's own) to apply its
+        plan on its own thread."""
+        with self._lock:
+            return self._enabled and not self._heap
 
     def flush(self) -> None:
         # the queue only runs on a leader: a flush IS a leadership

@@ -3,10 +3,11 @@
 Each worker loops: dequeue an eval from the broker, fence the state at
 the eval's modify index (snapshot_min_index, worker.go:228), run the
 registered scheduler for the eval type, and ack/nack.  The worker is the
-scheduler's `Planner`: plans go to the plan queue and the worker blocks
-for the applier's verdict; a partial commit hands back a refreshed
-snapshot so the scheduler retries against fresh state (worker.go:277-339
-SubmitPlan / RefreshIndex).
+scheduler's `Planner`: plans go to the plan applier, which verifies and
+commits on this worker's thread when it is idle and takes the plan into
+its queue and pipeline when it is not; a partial commit hands back a
+refreshed snapshot so the scheduler retries against fresh state
+(worker.go:277-339 SubmitPlan / RefreshIndex).
 """
 from __future__ import annotations
 
@@ -203,23 +204,13 @@ class Worker:
         plan.snapshot_index = self.store.latest_index()
         t0 = _time.monotonic()
         try:
-            # the plan crosses to the applier's threads: the span this
-            # thread holds open for the eval travels with it as the
-            # cause of every plan.* span
-            pending = self.server.plan_queue.enqueue(
-                plan, cause=TRACE.current(plan.eval_id)
+            # the span this thread holds open for the eval is the
+            # cause of every plan.* span, on whichever thread the
+            # applier runs the plan: this one when it is idle, its
+            # pipeline's when other submitters keep it busy
+            result = self.server.applier.apply(
+                plan, cause=TRACE.current(plan.eval_id), timeout=10.0
             )
-            try:
-                result = pending.wait(timeout=10.0)
-            finally:
-                if pending.t_responded is not None:
-                    # the wake-up: the committer responded, this
-                    # thread resumes (it has to win the GIL first)
-                    TRACE.add_span(
-                        plan.eval_id, "plan.respond_wait",
-                        pending.t_responded,
-                        _time.monotonic() - pending.t_responded,
-                    )
             if result is None:
                 raise RuntimeError("plan rejected")
             if result.refresh_index:

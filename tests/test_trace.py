@@ -11,6 +11,8 @@ import random
 import time
 import urllib.request
 
+import pytest
+
 from nomad_tpu import mock
 from nomad_tpu.server import Server
 from nomad_tpu.structs import compute_node_class
@@ -729,17 +731,27 @@ def test_redelivered_eval_starts_its_own_broker_wait():
         TRACE.clear()
 
 
-def test_served_evals_fold_into_one_causal_tree():
+@pytest.mark.parametrize("path", ["direct", "pipeline"])
+def test_served_evals_fold_into_one_causal_tree(path, monkeypatch):
     """20 registrations over HTTP on a small batch-pipeline server:
     every acked eval is folded, its trace begins at the handler, the
     plan's spans hang under the submitter's span (not beside it), and
-    `store.commit` is a child of `plan.apply`."""
+    `store.commit` is a child of `plan.apply`.  The one submitter of
+    such a server finds the applier idle, so its plans are verified
+    and committed on its own thread with no hand-off to wait for
+    (``direct``); where the applier is busy the plan crosses to the
+    pipeline's threads and back, and its three waits are spans too
+    (``pipeline``, held busy here by refusing every claim)."""
     from nomad_tpu.api import start_http_server
     from nomad_tpu.api.codec import job_to_dict
     from nomad_tpu.trace import FOLD_SAMPLE, LAYERS
 
     TRACE.clear()
     server = Server(num_schedulers=1, seed=27, batch_pipeline=True)
+    if path == "pipeline":
+        monkeypatch.setattr(
+            server.applier, "_claim_direct", lambda: None
+        )
     server.start()
     http = start_http_server(server, port=0)
     base = f"http://127.0.0.1:{http.port}"
@@ -773,6 +785,22 @@ def test_served_evals_fold_into_one_causal_tree():
         assert samples["trace.self.broker"]["sum"] > 0.0
         assert samples["trace.self.ingress"]["sum"] > 0.0
         assert samples["trace.cpu_wall"]["count"] <= folded
+        # one sample a folded trace for every layer, the hand-off's
+        # too where there was none to wait for: it then reads 0
+        handoff = samples["trace.self.plan_handoff"]
+        assert handoff["count"] == folded
+        took = "plan.direct" if path == "direct" else "plan.queued"
+        assert dump["counters"][took] >= 20.0
+        assert (
+            dump["counters"]["plan.direct"]
+            + dump["counters"]["plan.queued"]
+            == dump["counters"][took]
+        )
+        waits = ("plan.queue_wait", "plan.stage_wait", "plan.respond_wait")
+        if path == "direct":
+            assert handoff["sum"] == 0.0
+        else:
+            assert handoff["sum"] > 0.0
 
         submitters = {
             "replay.commit", "batch_worker.replay",
@@ -799,10 +827,10 @@ def test_served_evals_fold_into_one_causal_tree():
             )
             assert wait["off_ms"] + wait["dur_ms"] <= first_stage + 1e-3
             # the plan's life under the span that submitted it
-            for name in (
-                "plan.queue_wait", "plan.evaluate", "plan.stage_wait",
-                "plan.apply", "plan.respond_wait",
-            ):
+            plan_spans = ("plan.evaluate", "plan.apply") + (
+                waits if path == "pipeline" else ()
+            )
+            for name in plan_spans:
                 span = next(s for s in spans if s["name"] == name)
                 assert span["parent"] is not None, (name, names)
                 assert by_id[span["parent"]]["name"] in submitters, (
@@ -811,8 +839,24 @@ def test_served_evals_fold_into_one_causal_tree():
             evaluate = next(
                 s for s in spans if s["name"] == "plan.evaluate"
             )
-            assert evaluate["thread"] == "plan-verifier"
-            assert by_id[evaluate["parent"]]["thread"] != "plan-verifier"
+            apply = next(s for s in spans if s["name"] == "plan.apply")
+            submitter = by_id[evaluate["parent"]]
+            assert apply["parent"] == evaluate["parent"]
+            if path == "direct":
+                # no thread crossed, so no wait was recorded and the
+                # hand-off layer holds none of this eval's life
+                assert not set(waits) & set(names), names
+                assert evaluate["thread"] == submitter["thread"]
+                assert apply["thread"] == submitter["thread"]
+                assert evaluate["attrs"]["overlay"] is False
+                assert "full" in evaluate["attrs"]
+                assert doc["layers_ms"]["plan_handoff"] == 0.0
+            else:
+                assert evaluate["thread"] == "plan-verifier"
+                assert apply["thread"] == "plan-applier"
+                assert submitter["thread"] != "plan-verifier"
+                assert doc["layers_ms"]["plan_handoff"] > 0.0
+            assert doc["layers_ms"]["plan_applier"] > 0.0
             commit = next(
                 s for s in spans if s["name"] == "store.commit"
             )
