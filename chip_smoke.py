@@ -19,7 +19,17 @@ The same seeded stream then runs through the sequential oracle
 ``Server(batch_pipeline=False)`` — plain host code, no JAX — and every
 job's (alloc name -> node id) set must be identical: bit-identical
 placement is the system's guarantee, here checked at the dtype the chip
-runs (f32).
+runs (f32; the run fails if ``jax_enable_x64`` is on).
+
+What it proves, since PR 31: a smoke, not the guarantee.  One seed, 50
+jobs, at float32 — where the kernels now score with the float64
+definition's own ``10^(1 - used/cap)`` (ops/twofloat.py) and no longer
+with the TPU's float32 ``pow``, which used to move 0-5 placements a
+benchmark window.  The guarantee is held by the benchmark's cells
+(``BENCHMARK.json``: ``binpack-10k-f64.deploy`` is this fleet under a
+closed loop of 128 jobs, every placement of about 5,000 jobs a run
+judged against a plain reference, at float64; the float32 cells follow
+as data, PERF.md section 7) and by tests/test_float32_scoring.py.
 
 The run FAILS (non-zero exit, no ``"ok": true`` line) unless JAX
 resolves a TPU, every job placed, placements match the oracle, the

@@ -31,7 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .score import MAX_SKIP, NO_NODE, SKIP_THRESHOLD, _pow10 as _pow10_f32
+from .score import MAX_SKIP, NO_NODE, SKIP_THRESHOLD, _fit_exponentials
 
 
 def pow2_bucket(n: int, floor: int = 1) -> int:
@@ -558,10 +558,10 @@ def _run_picks(
                     axis=0,
                 )
 
-            free_cpu = 1.0 - cpu_after / safe_cpu
-            free_mem = 1.0 - mem_after / safe_mem
-            # canonical f32-rounded exponential (structs/funcs.py _pow10)
-            base = _pow10_f32(free_cpu, dtype) + _pow10_f32(free_mem, dtype)
+            # canonical f32-rounded exponentials (structs/funcs.py _pow10)
+            base = _fit_exponentials(
+                cpu_after, safe_cpu, mem_after, safe_mem, dtype
+            )
             if spread_fit:
                 fitness = jnp.clip(base - 2.0, 0.0, 18.0)
             else:

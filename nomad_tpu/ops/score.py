@@ -28,6 +28,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import twofloat
+
 MAX_SKIP = 3  # (reference stack.go:17)
 SKIP_THRESHOLD = 0.0  # (reference stack.go:13)
 NO_NODE = -1
@@ -67,11 +69,39 @@ class PolicyTerms(NamedTuple):
 
 
 def _pow10(x, dtype):
-    """Canonical 10^x for fitness scoring: f64 pow rounded through
-    float32 so host and accelerator implementations agree bit-for-bit
-    (see structs/funcs.py _pow10)."""
+    """Canonical 10^x for fitness scoring: the working dtype's pow
+    rounded through float32 (see structs/funcs.py _pow10).  At float64
+    that IS the definition: XLA's pow and the host's libm differ by one
+    f64 ulp on ~5% of inputs and the rounding collapses them to the
+    same float32, bit for bit.  It is not so at float32, where the
+    TPU's pow is off the definition by up to 5e-6: float32 traces go
+    through `_fit_exponentials` below and never call this."""
     raw = jnp.power(jnp.asarray(10.0, dtype), x)
     return raw.astype(jnp.float32).astype(dtype)
+
+
+def _fit_exponentials(cpu_after, cpu_cap, mem_after, mem_cap, dtype):
+    """``10^freeCpu + 10^freeMem`` with each free share ``1 - after /
+    cap`` and each exponential the float64 definition's, rounded once
+    to float32 (structs/funcs.py _pow10).  The float64 trace computes
+    them as it always has; the float32 trace (``jax_enable_x64`` off,
+    the dtype a chip deploys) carries share and exponential as two
+    float32s (ops/twofloat.py), so the float32 it scores with is the
+    same number, bit for bit, on the CPU and on the TPU — which
+    float32 division and pow are not."""
+    if dtype == jnp.float32:
+        # both resources through one trace of the arithmetic: a launch
+        # shape's compile time is in its count of operations
+        cpu_after, cpu_cap, mem_after, mem_cap = jnp.broadcast_arrays(
+            cpu_after, cpu_cap, mem_after, mem_cap
+        )
+        p = twofloat.pow10_free(
+            jnp.stack([cpu_after, mem_after]), jnp.stack([cpu_cap, mem_cap])
+        )
+        return p[0] + p[1]
+    free_cpu = 1.0 - cpu_after / cpu_cap
+    free_mem = 1.0 - mem_after / mem_cap
+    return _pow10(free_cpu, dtype) + _pow10(free_mem, dtype)
 
 
 class ScoreInputs(NamedTuple):
@@ -123,13 +153,11 @@ def _score_vectors(inp: ScoreInputs, spread_fit: bool):
 
     safe_cpu_total = jnp.where(inp.cpu_total > 0, inp.cpu_total, 1.0)
     safe_mem_total = jnp.where(inp.mem_total > 0, inp.mem_total, 1.0)
-    free_cpu = 1.0 - cpu_after / safe_cpu_total
-    free_mem = 1.0 - mem_after / safe_mem_total
-    # the fitness exponential is DEFINED at float32 precision (see
-    # structs/funcs.py _pow10): host libm and XLA pow disagree by 1 f64
-    # ulp on ~5% of inputs, so both sides round the pow through f32 and
-    # continue in the working dtype
-    base = _pow10(free_cpu, dtype) + _pow10(free_mem, dtype)
+    # the fitness exponential is DEFINED at float32 precision
+    # (structs/funcs.py _pow10); the sum continues in the working dtype
+    base = _fit_exponentials(
+        cpu_after, safe_cpu_total, mem_after, safe_mem_total, dtype
+    )
     if spread_fit:
         fitness = jnp.clip(base - 2.0, 0.0, 18.0)
     else:

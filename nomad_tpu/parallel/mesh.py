@@ -33,7 +33,12 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.batch import BatchInputs, plan_picks
-from ..ops.score import ScoreInputs, _limited_walk_argmax, _score_vectors
+from ..ops.score import (
+    ScoreInputs,
+    _fit_exponentials,
+    _limited_walk_argmax,
+    _score_vectors,
+)
 
 
 def shard_map(f=None, **kwargs):
@@ -647,13 +652,8 @@ def sharded_chained_plan(mesh: Mesh, n_picks: int,
                 # distinct_hosts via the collision carry, as in the
                 # unsharded kernel
                 feas = feas_l & fit & ~(dh & (coll_c > 0))
-                free_cpu = 1.0 - cpu_after / safe_cpu
-                free_mem = 1.0 - mem_after / safe_mem
-                base = (
-                    jnp.power(jnp.asarray(10.0, dtype), free_cpu)
-                    .astype(jnp.float32).astype(dtype)
-                    + jnp.power(jnp.asarray(10.0, dtype), free_mem)
-                    .astype(jnp.float32).astype(dtype)
+                base = _fit_exponentials(
+                    cpu_after, safe_cpu, mem_after, safe_mem, dtype
                 )
                 if spread_fit:
                     fitness = jnp.clip(base - 2.0, 0.0, 18.0)

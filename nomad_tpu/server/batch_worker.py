@@ -123,6 +123,15 @@ SHIELD_COUNTERS = (
     "batch_worker.cold_shape_fallbacks",
     "batch_worker.sibling_compiles",
 )
+# the limit walk's counters, zero-registered at Server construction:
+# ring positions the kernel's walk drew (infeasible nodes and the
+# diverted ones included: what the sequential iterator chain would
+# have pulled from its source) and the picks they were drawn for,
+# summed once a fetched chunk from the pulls the kernel hands back
+WALK_COUNTERS = (
+    "batch_worker.walk_pulls",
+    "batch_worker.walk_picks",
+)
 # widest chunk bucket, kept under its historical name: the assembly
 # arena, warm_shapes and the mesh path still use it as the default
 # eval-axis alignment
@@ -2331,6 +2340,7 @@ class BatchWorker(Worker):
                         c1 - c0, (launch_dt + dt) * 1000.0,
                         mesh=casm.use_mesh,
                     )
+                    walk_pulls = walk_picks = 0
                     for e in range(c0, min(c1, casm.E_real)):
                         if rescore:
                             break
@@ -2348,6 +2358,8 @@ class BatchWorker(Worker):
                                 e - c0, : sim.placements
                             ]
                         ]
+                        walk_pulls += sum(pulls)
+                        walk_picks += len(pulls)
                         if wave is not None:
                             wave.append((
                                 ev, token, job, sim, rows, pulls,
@@ -2366,6 +2378,15 @@ class BatchWorker(Worker):
                         k += 1
                         if not ok:
                             rescore = True
+                    if walk_picks:
+                        metrics = getattr(self.server, "metrics", None)
+                        if metrics is not None:
+                            metrics.incr(
+                                "batch_worker.walk_pulls", walk_pulls
+                            )
+                            metrics.incr(
+                                "batch_worker.walk_picks", walk_picks
+                            )
                     if wave is not None and wave and not rescore:
                         # continuous commit: drain the READY prefix of
                         # the wave in order, so these evals ack now —

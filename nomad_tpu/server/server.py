@@ -382,11 +382,17 @@ class Server:
             # counter family (absence-of-series must mean "admission
             # never engaged", not "not exported") and expose the mode
             # flag (NOMAD_TPU_ADMIT=0 restores flush-boundary gulps)
-            from .batch_worker import ADMISSION_COUNTERS, SHIELD_COUNTERS
+            from .batch_worker import (
+                ADMISSION_COUNTERS,
+                SHIELD_COUNTERS,
+                WALK_COUNTERS,
+            )
 
             self.metrics.preregister(counters=ADMISSION_COUNTERS)
             # cold-compile shield: 0 must read "no cold shape met"
             self.metrics.preregister(counters=SHIELD_COUNTERS)
+            # the limit walk: 0 must read "no prescored pick fetched"
+            self.metrics.preregister(counters=WALK_COUNTERS)
             # sharded hot path: zero-register the mesh.* family the
             # same way (absence-of-series must mean "mesh never
             # engaged" — NOMAD_TPU_MESH off or a single-device host —
