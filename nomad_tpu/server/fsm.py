@@ -260,15 +260,17 @@ def install_payload(store: StateStore, acls, payload: dict) -> int:
                 store._allocs_by_eval[alloc.eval_id].add(alloc.id)
         # recompute usage for every node (not just those with allocs in
         # the snapshot — a node whose allocs all stopped must read zero)
-        # The port/device occupancy indexes are derived state too: clear
-        # the pre-restore entries (phantom static-port occupancy would
-        # skew the batch kernel's port_used0 columns) and rebuild them —
-        # _refresh_port_index also repopulates node_table.device_used
-        # from the restored live allocs.
+        # The port/device occupancy indexes and the per-node live
+        # aggregate are derived state too: clear the pre-restore entries
+        # (phantom static-port occupancy would skew the batch kernel's
+        # port_used0 columns) and rebuild them — _recount_node also
+        # repopulates node_table.device_used from the restored live
+        # allocs.
         store._ports_live.clear()
         store._ports_by_node.clear()
+        store._node_live.clear()
         # re-row the seeded allocation ballast BEFORE the usage
-        # recompute below — _live_usage_for_node reads it per node
+        # recompute below — _recount_node reads it per node
         seed_usage = payload.get("seed_usage")
         if seed_usage:
             import numpy as np
@@ -289,11 +291,10 @@ def install_payload(store: StateStore, acls, payload: dict) -> int:
         else:
             store._seed_usage = None
             store._seed_alloc_count = 0
-        for node_id in store.nodes:
-            store.node_table.update_node_usage(
-                node_id, store._live_usage_for_node(node_id)
-            )
-            store._refresh_port_index(node_id)
+        # an alloc whose node the snapshot lacks is counted too: the
+        # aggregate has to be right when that node registers again
+        for node_id in store.nodes.keys() | store._allocs_by_node.keys():
+            store._recount_node(node_id)
         for ev in payload["evals"]:
             store.evals[ev.id] = ev
             store._evals_by_job[(ev.namespace, ev.job_id)].add(ev.id)

@@ -101,6 +101,18 @@ class OptimisticState:
                 by_id[alloc.id] = alloc
         return list(by_id.values())
 
+    def node_fit_usage(self, node_id: str):
+        # the store's live sum knows nothing of the overlay: a node an
+        # in-flight result touches is walked
+        for result in self._results:
+            if (
+                node_id in result.node_update
+                or node_id in result.node_preemptions
+                or node_id in result.node_allocation
+            ):
+                return None
+        return self._store.node_fit_usage(node_id)
+
     def csi_volume_by_id(self, namespace: str, volume_id: str):
         vol = self._store.csi_volume_by_id(namespace, volume_id)
         if vol is None or not self._results:
@@ -154,19 +166,30 @@ class EvaluatePool:
         self, store, plan: Plan, node_ids: List[str]
     ) -> Dict[str, Tuple[bool, str]]:
         if len(node_ids) < self.MIN_FANOUT:
-            return {
-                nid: evaluate_node_plan(store, plan, nid)
+            verdicts = [
+                _node_verdict(store, plan, nid) for nid in node_ids
+            ]
+        else:
+            futures = [
+                self._pool.submit(_node_verdict, store, plan, nid)
                 for nid in node_ids
-            }
-        futures = {
-            nid: self._pool.submit(evaluate_node_plan, store, plan, nid)
-            for nid in node_ids
+            ]
+            verdicts = [fut.result() for fut in futures]
+        _count_fits(store, verdicts)
+        return {
+            nid: (fit, reason)
+            for nid, (fit, reason, _how) in zip(node_ids, verdicts)
         }
-        return {nid: fut.result() for nid, fut in futures.items()}
 
     def shutdown(self) -> None:
         self.closed = True
         self._pool.shutdown(wait=False)
+
+
+# one increment a node whose fit was computed, by how: from the
+# store's live aggregate, or by the walk over the node's allocs
+FIT_COUNTERS = ("plan.fit_fast", "plan.fit_full")
+_FIT_FAST, _FIT_FULL = FIT_COUNTERS
 
 
 def evaluate_node_plan(
@@ -174,20 +197,45 @@ def evaluate_node_plan(
 ) -> Tuple[bool, str]:
     """Whether the plan's changes to one node fit
     (reference plan_apply.go:629 evaluateNodePlan)."""
+    verdict = _node_verdict(store, plan, node_id)
+    _count_fits(store, (verdict,))
+    return verdict[0], verdict[1]
+
+
+def _count_fits(store, verdicts) -> None:
+    metrics = getattr(store, "metrics", None)
+    if metrics is None:
+        return
+    for name in FIT_COUNTERS:
+        n = sum(1 for v in verdicts if v[2] == name)
+        if n:
+            metrics.incr(name, n)
+
+
+def _node_verdict(
+    store: StateStore, plan: Plan, node_id: str
+) -> Tuple[bool, str, str]:
+    """(fit, reason, how): ``how`` names the counter of the side the
+    fit took, or is empty where none was computed."""
     # evict-only plans always fit: they only remove things
     # (reference plan_apply.go:631)
-    if not plan.node_allocation.get(node_id):
-        return True, ""
+    placed = plan.node_allocation.get(node_id)
+    if not placed:
+        return True, "", ""
 
     node = store.node_by_id(node_id)
     if node is None:
-        return False, "node does not exist"
+        return False, "node does not exist", ""
     if node.status != "ready":
-        return False, "node is not ready for placements"
+        return False, "node is not ready for placements", ""
     if node.scheduling_eligibility != "eligible":
-        return False, "node is not eligible"
+        return False, "node is not eligible", ""
     if node.drain:
-        return False, "node is draining"
+        return False, "node is draining", ""
+
+    fast = _fit_from_aggregate(store, plan, node, placed)
+    if fast is not None:
+        return fast[0], fast[1], _FIT_FAST
 
     proposed = [
         a
@@ -198,10 +246,68 @@ def evaluate_node_plan(
     remove_ids |= {a.id for a in plan.node_preemptions.get(node_id, ())}
     proposed = [a for a in proposed if a.id not in remove_ids]
     by_id = {a.id: a for a in proposed}
-    for alloc in plan.node_allocation.get(node_id, ()):
+    for alloc in placed:
         by_id[alloc.id] = alloc
     fit, dim, _util = allocs_fit(node, list(by_id.values()))
-    return fit, dim
+    return fit, dim, _FIT_FULL
+
+
+def _fit_from_aggregate(
+    store, plan: Plan, node: Node, placed: List[Allocation]
+) -> Optional[Tuple[bool, str]]:
+    """``allocs_fit`` of the walk below, from the store's live sum of
+    the node: minus the stored live versions the plan stops, preempts
+    or replaces, plus what it places.  None — take the walk — unless
+    the network half of ``allocs_fit`` is vacuous: no live alloc of the
+    node (the store's count) and none placed carries a port, a network
+    or a device.  What is left of that half is the collision among the
+    node's own reserved ports, a function of the node alone."""
+    node_id = node.id
+    live = store.node_fit_usage(node_id)
+    if live is None:
+        return None
+    cpu, mem, disk = live
+    adds: Dict[str, Optional[Tuple[int, int, int]]] = {}
+    for alloc in placed:
+        c, m, d, carries = alloc.fit_footprint()
+        if carries:
+            return None
+        # by id, the last one placed stands; a terminal one adds nothing
+        adds[alloc.id] = None if alloc.terminal_status() else (c, m, d)
+    gone = set(adds)
+    for alloc in plan.node_update.get(node_id, ()):
+        gone.add(alloc.id)
+    for alloc in plan.node_preemptions.get(node_id, ()):
+        gone.add(alloc.id)
+    for alloc_id in gone:
+        stored = store.alloc_by_id(alloc_id)
+        if (
+            stored is not None
+            and stored.node_id == node_id
+            and not stored.terminal_status()
+        ):
+            c, m, d, _ = stored.fit_footprint()
+            cpu -= c
+            mem -= m
+            disk -= d
+    for add in adds.values():
+        if add is not None:
+            cpu += add[0]
+            mem += add[1]
+            disk += add[2]
+    total, reserved = node.node_resources, node.reserved_resources
+    if total.cpu - reserved.cpu < cpu:
+        return False, "cpu"
+    if total.memory_mb - reserved.memory_mb < mem:
+        return False, "memory"
+    if total.disk_mb - reserved.disk_mb < disk:
+        return False, "disk"
+    if (
+        reserved.reserved_ports
+        or any(net.reserved_ports for net in total.networks)
+    ) and NetworkIndex().set_node(node):
+        return False, "reserved port collision"
+    return True, ""
 
 
 def evaluate_plan(
@@ -226,13 +332,14 @@ def evaluate_plan(
     verdicts: Optional[Dict[str, Tuple[bool, str]]] = None
     if pool is not None and not plan.all_at_once:
         verdicts = pool.evaluate_nodes(store, plan, sorted(node_ids))
+    walked: List[Tuple[bool, str, str]] = []
     partial = False
     for node_id in sorted(node_ids):
-        fit, _reason = (
-            verdicts[node_id]
-            if verdicts is not None
-            else evaluate_node_plan(store, plan, node_id)
-        )
+        if verdicts is not None:
+            fit = verdicts[node_id][0]
+        else:
+            walked.append(_node_verdict(store, plan, node_id))
+            fit = walked[-1][0]
         if fit:
             if plan.node_update.get(node_id):
                 result.node_update[node_id] = plan.node_update[node_id]
@@ -254,6 +361,7 @@ def evaluate_plan(
                 result.deployment = None
                 result.deployment_updates = []
                 break
+    _count_fits(store, walked)
     if not _verify_csi_claims(store, result):
         partial = True
     if partial:
@@ -369,7 +477,7 @@ class PlanApplier:
         self.applied = 0
         self.overlap_verifies = 0  # verifications that ran on an overlay
         if metrics is not None:
-            metrics.preregister(counters=PLAN_COUNTERS)
+            metrics.preregister(counters=PLAN_COUNTERS + FIT_COUNTERS)
 
     def start(self) -> None:
         # re-entrant after stop() (leadership can be re-established,

@@ -193,6 +193,9 @@ class Server:
             enabled=acl_enabled
         )
         self.metrics = Metrics()
+        # the store counts which side each alloc write's usage took,
+        # and carries the registry to the plan's per-node fit
+        self.store.attach_metrics(self.metrics)
         # placement explainability: zero-register the placement.*
         # counter/gauge families so dashboards see the whole reason
         # vocabulary from process start (absence-of-series must mean

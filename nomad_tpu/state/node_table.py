@@ -119,6 +119,9 @@ class NodeTable:
         self._device_sig_meta: Dict[int, tuple] = {}
         # (node_row, (vendor,type,name)) -> instances used by live allocs
         self.device_used: Dict[Tuple[int, Tuple[str, str, str]], int] = {}
+        # row -> the (vendor,type,name) keys it holds in device_used,
+        # so replacing a row's entries never scans the whole dict
+        self._device_used_keys: Dict[int, List[Tuple[str, str, str]]] = {}
 
         self.generation = 0  # bumped on any mutation; device cache key
         # bumped only on node join/leave/attribute/eligibility changes —
@@ -353,13 +356,24 @@ class NodeTable:
         self.device_groups.pop(row, None)
         self._row_fingerprints.pop(row, None)
         # a reused row must not inherit phantom device reservations
-        for key in [k for k in self.device_used if k[0] == row]:
-            del self.device_used[key]
+        self.set_row_device_used(row, {})
         if hasattr(self, "_nodes_cache"):
             self._nodes_cache.pop(node_id, None)
         self._free_rows.append(row)
         self.generation += 1
         self.topo_generation += 1
+
+    def set_row_device_used(
+        self, row: int, used: Dict[Tuple[str, str, str], int]
+    ) -> None:
+        """Replace one row's entries of ``device_used`` (the store's
+        per-node recount of its live allocs' device instances)."""
+        for key in self._device_used_keys.pop(row, ()):
+            del self.device_used[(row, key)]
+        if used:
+            for key, count in used.items():
+                self.device_used[(row, key)] = count
+            self._device_used_keys[row] = list(used)
 
     def update_node_usage(
         self, node_id: str, usage: Tuple[int, int, int]

@@ -53,7 +53,33 @@ from ..structs import (
     SchedulerConfiguration,
     compute_node_class,
 )
+from ..structs.network import MIN_DYNAMIC_PORT
 from .node_table import NodeTable
+
+# a node with no live alloc: (cpu, memory_mb, disk_mb, carriers)
+_NO_LIVE = (0, 0, 0, 0)
+
+# one increment an alloc written, by the side its usage took: added and
+# subtracted as integers, or the node recounted from nothing
+USAGE_COUNTERS = ("store.usage_delta", "store.usage_recount")
+
+
+def _holds_indexed(alloc: Allocation) -> bool:
+    """Whether a live alloc shows in the port / device index: a static
+    port below the dynamic range (group-level offer or task-level
+    reserved port) or a device."""
+    ar = alloc.allocated_resources
+    for p in ar.shared.ports:
+        if 0 < p.value < MIN_DYNAMIC_PORT:
+            return True
+    for tr in ar.tasks.values():
+        if tr.devices:
+            return True
+        for net in tr.networks:
+            for p in net.reserved_ports:
+                if 0 < p.value < MIN_DYNAMIC_PORT:
+                    return True
+    return False
 
 
 class StateStore:
@@ -121,6 +147,17 @@ class StateStore:
         # whole port dict
         self._ports_live: Dict[int, Dict[str, int]] = {}
         self._ports_by_node: Dict[str, set] = {}
+        # each node's live aggregate, kept by delta as allocs are
+        # written: node_id -> (cpu, memory_mb, disk_mb, carriers) over
+        # the live allocs of _allocs_by_node[node_id] — the sum
+        # _live_usage_for_node recounts (without the ballast) and how
+        # many of them carry a port, a network or a device.  Entries
+        # are tuples replaced whole under the lock, so the plan's fit
+        # (node_fit_usage) reads one without it.
+        self._node_live: Dict[str, Tuple[int, int, int, int]] = {}
+        # the server's registry (attach_metrics): which side a write
+        # and a fit took
+        self.metrics = None
 
         # bigworld allocation ballast: per-row (cpu, mem, disk) usage
         # seeded by bulk_seed_usage WITHOUT materializing Allocation
@@ -234,6 +271,11 @@ class StateStore:
             was_ready = existing is not None and existing.ready()
             self.nodes[node.id] = node
             self.node_table.upsert_node(node)
+            if existing is None and self._allocs_by_node.get(node.id):
+                # a node that registers again over allocs that stayed:
+                # its fresh row holds their usage and devices from now,
+                # not from the next alloc written there
+                self._recount_node(node.id)
             index = self._bump("nodes")
             self._touch_node(node.id)
             if existing is None or was_ready != node.ready():
@@ -869,7 +911,9 @@ class StateStore:
                 cb(allocs)
 
     def _upsert_allocs_locked(self, allocs: List[Allocation]) -> None:
+        n_delta = n_recount = 0
         for alloc in allocs:
+            node_id = alloc.node_id
             existing = self.allocs.get(alloc.id)
             if existing is not None:
                 alloc.create_index = existing.create_index
@@ -884,31 +928,105 @@ class StateStore:
             self.allocs[alloc.id] = alloc
             # conflict ledger: any alloc write mutates its node's
             # schedulable state (usage, ports, devices, proposed set)
-            self._touch_node(alloc.node_id)
-            self._allocs_by_node[alloc.node_id].add(alloc.id)
+            self._touch_node(node_id)
+            self._allocs_by_node[node_id].add(alloc.id)
             self._allocs_by_job[(alloc.namespace, alloc.job_id)].add(alloc.id)
             if alloc.eval_id:
                 self._allocs_by_eval[alloc.eval_id].add(alloc.id)
+            if existing is alloc:
+                # an aliasing caller mutated the stored object in
+                # place: what the aggregate holds for it is unknowable,
+                # so recount the node rather than miss a live->terminal
+                self._recount_node(node_id)
+                n_recount += 1
+                continue
+            if existing is not None and existing.node_id != node_id:
+                # an id that changed nodes leaves the old node's set
+                self._allocs_by_node[existing.node_id].discard(alloc.id)
+                self._recount_node(existing.node_id)
+                self._recount_node(node_id)
+                n_recount += 1
+                continue
+            # the delta: the stored version leaves the node's sum if it
+            # was live, the new one joins it if it is
+            n_delta += 1
             is_live = not alloc.terminal_status()
-            # existing is alloc: an aliasing caller mutated the stored
-            # object in place, so was_live is unknowable — recompute
-            # usage unconditionally rather than miss a live->terminal
-            if was_live != is_live or existing is None or existing is alloc:
+            before = self._node_live.get(node_id, _NO_LIVE)
+            cpu, mem, disk, carriers = before
+            indexed = False
+            if was_live:
+                c, m, d, carries = existing.fit_footprint()
+                cpu -= c
+                mem -= m
+                disk -= d
+                if carries:
+                    carriers -= 1
+                    indexed = _holds_indexed(existing)
+            if is_live:
+                c, m, d, carries = alloc.fit_footprint()
+                cpu += c
+                mem += m
+                disk += d
+                if carries:
+                    carriers += 1
+                    indexed = indexed or _holds_indexed(alloc)
+            after = (cpu, mem, disk, carriers)
+            if after != before:
+                self._node_live[node_id] = after
+            # the columns are written where they were recounted before
+            # (so the usage generations and dirty rows are the same),
+            # and where a live alloc's resources changed under it
+            if (
+                was_live != is_live
+                or existing is None
+                or after[:3] != before[:3]
+            ):
+                b = self._ballast(node_id)
                 self.node_table.update_node_usage(
-                    alloc.node_id, self._live_usage_for_node(alloc.node_id)
+                    node_id, (cpu + b[0], mem + b[1], disk + b[2])
                 )
-            # port occupancy follows the same lifecycle, but also
-            # shifts when an update re-offers ports on the same node
-            self._refresh_port_index(alloc.node_id)
+            # port and device occupancy follow the same lifecycle, and
+            # shift when an update re-offers ports on the same node:
+            # only a version that holds an indexed port or a device can
+            # have changed the node's entry
+            if indexed:
+                self._refresh_port_index(node_id)
+        if self.metrics is not None:
+            if n_delta:
+                self.metrics.incr("store.usage_delta", n_delta)
+            if n_recount:
+                self.metrics.incr("store.usage_recount", n_recount)
 
-    def _live_usage_for_node(self, node_id: str):
-        cpu = mem = disk = 0
+    def _recount_node(self, node_id: str) -> None:
+        """The full recompute of one node: usage columns, port / device
+        index and the live aggregate from the node's allocs — for a
+        write whose delta is unknowable, and for restore."""
+        usage = self._live_usage_for_node(node_id)
+        self.node_table.update_node_usage(node_id, usage)
+        ballast = self._ballast(node_id)
+        carriers = self._refresh_port_index(node_id)
+        self._node_live[node_id] = (
+            usage[0] - ballast[0],
+            usage[1] - ballast[1],
+            usage[2] - ballast[2],
+            carriers,
+        )
+
+    def _ballast(self, node_id: str) -> Tuple[int, int, int]:
         if self._seed_usage is not None:
             row = self.node_table.row_of.get(node_id)
             if row is not None and row < len(self._seed_usage[0]):
-                cpu = int(self._seed_usage[0][row])
-                mem = int(self._seed_usage[1][row])
-                disk = int(self._seed_usage[2][row])
+                return (
+                    int(self._seed_usage[0][row]),
+                    int(self._seed_usage[1][row]),
+                    int(self._seed_usage[2][row]),
+                )
+        return 0, 0, 0
+
+    def _live_usage_for_node(self, node_id: str):
+        """What the node's usage columns hold: the ballast plus every
+        live alloc, counted from nothing."""
+        cpu, mem, disk = self._ballast(node_id)
         for aid in self._allocs_by_node.get(node_id, ()):
             a = self.allocs[aid]
             if a.terminal_status():
@@ -919,7 +1037,23 @@ class StateStore:
             disk += c.disk_mb
         return cpu, mem, disk
 
-    def _refresh_port_index(self, node_id: str) -> None:
+    def node_fit_usage(
+        self, node_id: str
+    ) -> Optional[Tuple[int, int, int]]:
+        """(cpu, memory_mb, disk_mb) of the node's live allocs, for a
+        fit that adds integers instead of walking them — or None where
+        one of them carries a port, a network or a device, and the fit
+        has to walk.  Lock-free: one tuple, replaced whole."""
+        cpu, mem, disk, carriers = self._node_live.get(node_id, _NO_LIVE)
+        if carriers:
+            return None
+        return cpu, mem, disk
+
+    def attach_metrics(self, metrics) -> None:
+        metrics.preregister(counters=USAGE_COUNTERS)
+        self.metrics = metrics
+
+    def _refresh_port_index(self, node_id: str) -> int:
         """Per-node recount of live allocated static host ports, from
         both group-level offers (shared.ports) and task-level network
         offers (tasks[*].networks — rank.py assign_network stores them
@@ -929,31 +1063,24 @@ class StateStore:
         NetworkIndex per candidate node lazily — rank.go network
         path; the kernel needs all nodes up front).  Dynamic-range
         ports are skipped: static asks in that range are gated to the
-        sequential path, so the index is never queried for them."""
-        from ..structs.network import MIN_DYNAMIC_PORT
-
+        sequential path, so the index is never queried for them.
+        Returns how many of the node's live allocs carry a port, a
+        network or a device (the aggregate's fourth number)."""
         for port in self._ports_by_node.pop(node_id, ()):
             nodes = self._ports_live.get(port)
             if nodes is not None:
                 nodes.pop(node_id, None)
                 if not nodes:
                     del self._ports_live[port]
-        # device reservations live in ONE index — the node table's
-        # device_used, read by the per-select mask (MaskCompiler.
-        # device_feasibility / device_count_columns) and the batch
-        # kernel's free columns alike
         row = self.node_table.row_of.get(node_id)
-        if row is not None:
-            for key in [
-                k for k in self.node_table.device_used
-                if k[0] == row
-            ]:
-                del self.node_table.device_used[key]
         held: set = set()
+        devices: Dict[Tuple[str, str, str], int] = {}
+        carriers = 0
         for aid in self._allocs_by_node.get(node_id, ()):
             a = self.allocs[aid]
-            if a.terminal_status() or a.allocated_resources is None:
+            if a.terminal_status() or not a.fit_footprint()[3]:
                 continue
+            carriers += 1
             values = [
                 p.value
                 for p in a.allocated_resources.shared.ports
@@ -963,16 +1090,11 @@ class StateStore:
                     values.extend(
                         p.value for p in net.reserved_ports
                     )
-                if row is not None:
-                    for dv in tr.devices:
-                        key = (
-                            row,
-                            (dv.vendor, dv.type, dv.name),
-                        )
-                        self.node_table.device_used[key] = (
-                            self.node_table.device_used.get(key, 0)
-                            + len(dv.device_ids)
-                        )
+                for dv in tr.devices:
+                    key = (dv.vendor, dv.type, dv.name)
+                    devices[key] = devices.get(key, 0) + len(
+                        dv.device_ids
+                    )
             for value in values:
                 if not value or value >= MIN_DYNAMIC_PORT:
                     continue
@@ -981,6 +1103,13 @@ class StateStore:
                 held.add(value)
         if held:
             self._ports_by_node[node_id] = held
+        # device reservations live in ONE index — the node table's
+        # device_used, read by the per-select mask (MaskCompiler.
+        # device_feasibility / device_count_columns) and the batch
+        # kernel's free columns alike
+        if row is not None:
+            self.node_table.set_row_device_used(row, devices)
+        return carriers
 
     def live_port_nodes(self, port: int) -> Dict[str, int]:
         """node_id -> live alloc count holding `port` (empty when
@@ -1301,6 +1430,13 @@ class StateSnapshot:
 
     def live_port_nodes(self, port: int) -> Dict[str, int]:
         return self._store.live_port_nodes(port)
+
+    def node_fit_usage(self, node_id: str):
+        return self._store.node_fit_usage(node_id)
+
+    @property
+    def metrics(self):
+        return self._store.metrics
 
     def node_touch_count(self, node_id: str) -> int:
         return self._store.node_touch_count(node_id)

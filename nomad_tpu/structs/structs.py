@@ -996,6 +996,26 @@ class Allocation:
             return ComparableResources()
         return self.allocated_resources.comparable()
 
+    def fit_footprint(self) -> Tuple[int, int, int, bool]:
+        """(cpu, memory_mb, disk_mb, carries): the three integers of
+        ``comparable_resources`` a fit check compares, without the
+        object, and whether the allocation holds anything the network
+        or device half of a fit reads — a port, a network (bandwidth
+        rides on one) or a device.  The store keeps each node's live
+        sum of the first three and its count of the fourth."""
+        ar = self.allocated_resources
+        if ar is None:
+            return 0, 0, 0, False
+        cpu = mem = 0
+        shared = ar.shared
+        carries = bool(shared.ports or shared.networks)
+        for tr in ar.tasks.values():
+            cpu += tr.cpu
+            mem += tr.memory_mb
+            if tr.networks or tr.devices:
+                carries = True
+        return cpu, mem, shared.disk_mb, carries
+
     def index(self) -> int:
         """Parse the instance index out of the alloc name."""
         l = self.name.rfind("[")

@@ -64,14 +64,22 @@ class _RecordingStore:
     def __getattr__(self, name):
         return getattr(self._store, name)
 
-    def allocs_by_node(self, node_id):
+    def _read(self):
         with self._mu:
             self.reads.append(
                 (threading.current_thread().name, time.monotonic())
             )
         if self.read_latency:
             time.sleep(self.read_latency)
+
+    # the verification's read of a node, whichever side the fit takes
+    def allocs_by_node(self, node_id):
+        self._read()
         return self._store.allocs_by_node(node_id)
+
+    def node_fit_usage(self, node_id):
+        self._read()
+        return self._store.node_fit_usage(node_id)
 
     def upsert_plan_results(self, result, eval_id=""):
         start = time.monotonic()
