@@ -14,7 +14,6 @@ namespace capability the route requires (reference nomad/acl.go).
 from __future__ import annotations
 
 import json
-import os
 import re
 import threading
 from dataclasses import replace as dc_replace
@@ -65,19 +64,10 @@ class HTTPError(Exception):
         self.code = code
 
 
-def _fed_proxy_timeout_s() -> float:
-    """Deadline for a ?region= read proxied to another region's
-    advertised HTTP address — a wedged remote region must cost the
-    caller a bounded wait, never a pinned thread."""
-    try:
-        return max(
-            0.1,
-            float(
-                os.environ.get("NOMAD_TPU_FED_PROXY_TIMEOUT_S", "2")
-            ),
-        )
-    except ValueError:
-        return 2.0
+# deadline for a ?region= read proxied to another region's advertised
+# HTTP address: a wedged remote region must cost the caller a bounded
+# wait, never a pinned thread
+FED_PROXY_TIMEOUT_S = 2.0
 
 
 class APIHandler(BaseHTTPRequestHandler):
@@ -492,7 +482,7 @@ class APIHandler(BaseHTTPRequestHandler):
             req.add_header("X-Nomad-Token", token)
         try:
             with urllib.request.urlopen(
-                req, timeout=_fed_proxy_timeout_s()
+                req, timeout=FED_PROXY_TIMEOUT_S
             ) as resp:
                 code = resp.status
                 ctype = resp.headers.get(
@@ -1890,7 +1880,7 @@ class APIHandler(BaseHTTPRequestHandler):
             return True
 
         # metric time-series history: the retained snapshot windows
-        # (NOMAD_TPU_OBS_HISTORY_N x NOMAD_TPU_OBS_HISTORY_S), or one
+        # (NOMAD_TPU_OBS_HISTORY_N windows x 10 s), or one
         # metric's series with ?name=.  Unauthenticated and never
         # shed, like /v1/metrics — it shares the prefix on purpose.
         if path == "/v1/metrics/history" and method == "GET":

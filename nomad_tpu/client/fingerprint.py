@@ -10,7 +10,7 @@ import logging
 import os
 import platform
 import socket
-from typing import Dict, List, Optional
+from typing import Dict, List
 
 from ..structs import Node, NodeDeviceResource, NodeResources
 
@@ -78,7 +78,11 @@ def fingerprint_host(node: Node) -> None:
         node.name = socket.gethostname()
 
 
-def bounded_jax_devices(timeout_s: Optional[float] = None):
+# bounded TPU device-probe deadline during fingerprinting
+FINGERPRINT_TIMEOUT_S = 20.0
+
+
+def bounded_jax_devices():
     """`jax.devices()` with a deadline: callers (node fingerprint, TPU
     device plugin) must not wedge the client agent on backend init.
     Returns None on timeout/failure — a node that registers CPU-only
@@ -92,10 +96,6 @@ def bounded_jax_devices(timeout_s: Optional[float] = None):
     or ``JAX_PLATFORMS=cpu``."""
     import threading
 
-    if timeout_s is None:
-        timeout_s = float(
-            os.environ.get("NOMAD_TPU_FINGERPRINT_TIMEOUT_S", "20")
-        )
     box: Dict[str, List] = {}
 
     def enumerate_devices() -> None:
@@ -111,7 +111,7 @@ def bounded_jax_devices(timeout_s: Optional[float] = None):
 
     t = threading.Thread(target=enumerate_devices, daemon=True)
     t.start()
-    t.join(timeout_s)
+    t.join(FINGERPRINT_TIMEOUT_S)
     return box.get("devices")
 
 

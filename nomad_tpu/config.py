@@ -54,7 +54,6 @@ class DeviceConfig:
         device {
           probe_interval  = "30s"
           probe_timeout   = "10s"
-          watchdog_factor = 20
           watchdog_min    = "5s"
           watchdog_max    = "2m"
         }
@@ -62,7 +61,6 @@ class DeviceConfig:
 
     probe_interval_s: Optional[float] = None
     probe_timeout_s: Optional[float] = None
-    watchdog_factor: Optional[float] = None
     watchdog_min_s: Optional[float] = None
     watchdog_max_s: Optional[float] = None
     lost_probes: Optional[int] = None
@@ -179,11 +177,6 @@ def config_from_dict(raw: Dict) -> AgentConfig:
     cfg.device = DeviceConfig(
         probe_interval_s=_dur_or_none("probe_interval"),
         probe_timeout_s=_dur_or_none("probe_timeout"),
-        watchdog_factor=(
-            None
-            if device.get("watchdog_factor") is None
-            else float(device["watchdog_factor"])
-        ),
         watchdog_min_s=_dur_or_none("watchdog_min"),
         watchdog_max_s=_dur_or_none("watchdog_max"),
         lost_probes=(

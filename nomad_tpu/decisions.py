@@ -40,7 +40,6 @@ __all__ = [
     "DECISION_SITES",
     "DecisionLedger",
     "decisions_enabled",
-    "decisions_ring",
 ]
 
 # Registry of every adaptive decision site: slug -> the nomadlint
@@ -83,13 +82,8 @@ def decisions_enabled() -> bool:
     return os.environ.get("NOMAD_TPU_DECISIONS", "1") != "0"
 
 
-def decisions_ring() -> int:
-    try:
-        return max(
-            16, int(os.environ.get("NOMAD_TPU_DECISIONS_RING", "512"))
-        )
-    except ValueError:
-        return 512
+# ring depth: the newest-wins retention bound
+RING_DEPTH = 512
 
 
 class DecisionLedger:
@@ -102,10 +96,10 @@ class DecisionLedger:
     so callers can serialize without racing writers.
     """
 
-    def __init__(self, ring: Optional[int] = None) -> None:
+    def __init__(self, ring: int = RING_DEPTH) -> None:
         self.enabled = decisions_enabled()
         self._lock = threading.Lock()
-        self._ring: deque = deque(maxlen=ring or decisions_ring())
+        self._ring: deque = deque(maxlen=ring)
         self._seq = 0
         self._evicted = 0
         from .tsan import maybe_instrument

@@ -18,17 +18,15 @@ answered, and a caller that needs the chip checks them
 claims the chip like any JAX process — never run it beside a live
 scheduler.
 
-Env knobs: ``NOMAD_TPU_PREFLIGHT_S`` (total budget, default 600; the
-legacy ``BENCH_PREFLIGHT_S`` is honored as a fallback), plus the
-supervisor's ``NOMAD_TPU_PROBE_TIMEOUT_S`` per-attempt deadline.
+``--budget-s`` sets the total retry budget (default 600); the
+supervisor's ``NOMAD_TPU_PROBE_TIMEOUT_S`` is the per-attempt deadline.
 """
 from __future__ import annotations
 
 import json
-import os
 import sys
 import time
-from typing import Callable, Dict, Optional
+from typing import Callable, Dict
 
 from .supervisor import HEALTHY, DeviceSupervisor
 
@@ -40,6 +38,7 @@ UNREACHABLE = "UNREACHABLE"  # deadline passed without a canary pass
 HEALTHY_STATES = (HEALTHY, SKIPPED)
 
 _RETRY_SLEEP_S = 10.0
+PREFLIGHT_BUDGET_S = 600.0
 
 
 def _stderr(msg: str) -> None:
@@ -47,19 +46,12 @@ def _stderr(msg: str) -> None:
 
 
 def run_preflight(
-    total_s: Optional[float] = None,
+    total_s: float = PREFLIGHT_BUDGET_S,
     log: Callable[[str], None] = _stderr,
 ) -> Dict:
     """Probe the accelerator until it answers or ``total_s`` passes.
     Returns the machine-readable result dict (the state line payload);
     never raises."""
-    if total_s is None:
-        total_s = float(
-            os.environ.get(
-                "NOMAD_TPU_PREFLIGHT_S",
-                os.environ.get("BENCH_PREFLIGHT_S", 600),
-            )
-        )
     if total_s <= 0:
         return {"state": SKIPPED, "attempts": 0}
     # a throwaway supervisor: its canary + bounded-call machinery IS
@@ -133,8 +125,8 @@ def main(argv=None) -> int:
         description="bounded accelerator canary probe",
     )
     parser.add_argument(
-        "--budget-s", type=float, default=None,
-        help="total retry budget (default NOMAD_TPU_PREFLIGHT_S/600)",
+        "--budget-s", type=float, default=PREFLIGHT_BUDGET_S,
+        help="total retry budget in seconds",
     )
     args = parser.parse_args(argv)
     result = run_preflight(total_s=args.budget_s)

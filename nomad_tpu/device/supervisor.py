@@ -49,8 +49,8 @@ Env knobs (config-file equivalents in ``config.DeviceConfig``):
                                — real PJRT backend init takes tens of
                                seconds, and a cold start must not read
                                as a wedge
-  NOMAD_TPU_WATCHDOG_FACTOR    budget = factor * stage EWMA (default 20)
-  NOMAD_TPU_WATCHDOG_MIN_S     budget floor (default 5)
+  NOMAD_TPU_WATCHDOG_MIN_S     floor (default 5) of the launch budget,
+                               which is 20 x the stage's EWMA
   NOMAD_TPU_WATCHDOG_MAX_S     budget ceiling (default 120)
   NOMAD_TPU_LOST_PROBES        consecutive canary failures past
                                DEGRADED before LOST (default 2)
@@ -163,7 +163,6 @@ class DeviceSupervisor:
         backend: Optional[Backend] = None,
         probe_interval_s: Optional[float] = None,
         probe_timeout_s: Optional[float] = None,
-        watchdog_factor: Optional[float] = None,
         watchdog_min_s: Optional[float] = None,
         watchdog_max_s: Optional[float] = None,
         lost_probes: Optional[int] = None,
@@ -206,10 +205,6 @@ class DeviceSupervisor:
         )
         self._device_ready = False
         self.budgets = BudgetTracker(
-            factor=float(
-                opt(watchdog_factor, "watchdog_factor",
-                    "NOMAD_TPU_WATCHDOG_FACTOR", 20.0)
-            ),
             min_s=float(
                 opt(watchdog_min_s, "watchdog_min_s",
                     "NOMAD_TPU_WATCHDOG_MIN_S", 5.0)

@@ -5,9 +5,12 @@ one-line description.  This registry — together with the knob table
 in docs/ARCHITECTURE.md — is enforced by the ``config-drift`` rule of
 ``python -m tools.nomadlint``: a knob read anywhere in ``nomad_tpu/``,
 ``bench.py`` or ``tests/`` must appear here AND in the docs table, a
-registered knob must still be read somewhere, and a documented knob
-must still be registered.  New knobs therefore cannot ship
-undocumented, and removed ones cannot haunt the docs.
+registered knob must still be read somewhere, a documented knob
+must still be registered, and a registered knob must be set by
+something in the tree other than its read site (a test, a smoke,
+``bench.py``, ``tools/ci_check.sh``) or be a deployment setting.  New
+knobs therefore cannot ship undocumented, removed ones cannot haunt
+the docs, and an option nothing sets becomes a constant.
 
 The registry is data, not plumbing: call sites keep reading
 ``os.environ`` directly (many are hot-path or import-time reads with
@@ -25,6 +28,23 @@ class EnvKnob(NamedTuple):
     doc: str  # one-line description
 
 
+# Deployment settings (addresses, ports, paths, pod identity): the only
+# knobs that may stay registered with no setter in the tree
+# (``config-drift``, direction 5).
+DEPLOYMENT_KNOBS = frozenset({
+    "NOMAD_TPU_EXECUTOR_STATE",
+    # Not deployment settings: listed until each has its verdict
+    # (ROADMAP C3).  Whether a server warms at all is ROADMAP A7 / D4.
+    "NOMAD_TPU_WARM_ON_START",
+    # Set from a shell only: the recorder's and the explainer's opt-outs
+    # (tests reach the off side through ``set_enabled``), the chaos
+    # smoke's fault plan and the soak tests' opt-in.
+    "NOMAD_TPU_TRACE",
+    "NOMAD_TPU_EXPLAIN",
+    "NOMAD_TPU_CLUSTER_FAULT",
+    "NOMAD_TPU_SOAK",
+})
+
 ENV_KNOBS: Dict[str, EnvKnob] = {
     # -- batch pipeline (server/batch_worker.py) ----------------------
     "NOMAD_TPU_BATCH_MAX": EnvKnob(
@@ -40,10 +60,6 @@ ENV_KNOBS: Dict[str, EnvKnob] = {
         "1 serializes every wave-contended eval (full score-metric "
         "bit-identity)",
     ),
-    "NOMAD_TPU_REPLAY_WORKERS": EnvKnob(
-        "0", "nomad_tpu/server/batch_worker.py",
-        "replay pool size (0 = auto)",
-    ),
     "NOMAD_TPU_LATENCY_BUDGET_MS": EnvKnob(
         "250", "nomad_tpu/server/batch_worker.py",
         "adaptive gulp cap: keep last-eval latency within this "
@@ -53,18 +69,9 @@ ENV_KNOBS: Dict[str, EnvKnob] = {
         "1", "nomad_tpu/server/batch_worker.py",
         "0 restores flush-boundary gulps (no mid-chain admission)",
     ),
-    "NOMAD_TPU_PIPELINE_DEPTH": EnvKnob(
-        "2", "nomad_tpu/server/batch_worker.py",
-        "chunk launches in flight before the host blocks on a fetch",
-    ),
     "NOMAD_TPU_MESH": EnvKnob(
         "0", "nomad_tpu/server/batch_worker.py",
         "1 shards prescore launches over the node-axis device mesh",
-    ),
-    "NOMAD_TPU_MESH_DEVICES": EnvKnob(
-        "0", "nomad_tpu/server/batch_worker.py",
-        "cap on the node-axis mesh device count (0 = all devices; "
-        "bench sweeps and deployments reserving chips set this)",
     ),
     "NOMAD_TPU_STORM": EnvKnob(
         "0", "nomad_tpu/server/batch_worker.py",
@@ -82,11 +89,6 @@ ENV_KNOBS: Dict[str, EnvKnob] = {
         "max evals drained into one storm solve (clamped to "
         "[STORM_MIN, 1024])",
     ),
-    "NOMAD_TPU_STORM_ROUNDS": EnvKnob(
-        "0", "nomad_tpu/server/batch_worker.py",
-        "cap on storm auction rounds (0 = auto: the padded row "
-        "bucket, the solver's convergence bound)",
-    ),
     # -- policy-weighted scoring (sched/policy.py) --------------------
     "NOMAD_TPU_POLICY": EnvKnob(
         "1", "nomad_tpu/sched/policy.py",
@@ -102,11 +104,6 @@ ENV_KNOBS: Dict[str, EnvKnob] = {
         "", "nomad_tpu/sched/policy.py",
         "operator override for every job's migration stickiness "
         "coefficient (unset = per-job spec value)",
-    ),
-    "NOMAD_TPU_POLICY_CACHE": EnvKnob(
-        "64", "nomad_tpu/sched/policy.py",
-        "LRU capacity of the assembled throughput-tensor cache "
-        "(keyed by table epoch / job version / topo generation)",
     ),
     # -- multi-host mesh (nomad_tpu/parallel/mesh.py) -----------------
     "NOMAD_TPU_DIST": EnvKnob(
@@ -129,13 +126,6 @@ ENV_KNOBS: Dict[str, EnvKnob] = {
         "0", "nomad_tpu/parallel/mesh.py",
         "this process's id in [0, NOMAD_TPU_DIST_PROCS)",
     ),
-    "NOMAD_TPU_DIST_NS": EnvKnob(
-        "", "nomad_tpu/parallel/mesh.py",
-        "world namespace suffix: with NS set, "
-        "NOMAD_TPU_DIST_<KNOB>_<NS> overrides the bare knob, so N "
-        "follower-headed worlds can coexist in one env block "
-        "(composed fan-out topologies)",
-    ),
     "NOMAD_TPU_POD_PORT": EnvKnob(
         "", "nomad_tpu/server/batch_worker.py",
         "pod-head stream port: process 0 of a multi-host world "
@@ -146,18 +136,6 @@ ENV_KNOBS: Dict[str, EnvKnob] = {
         "0", "nomad_tpu/parallel/pod.py",
         "1 makes every pod chain/storm launch round-trip a result "
         "digest from every peer — the head/peer bit-parity gate",
-    ),
-    "NOMAD_TPU_SMOKE_NODES": EnvKnob(
-        "12", "nomad_tpu/parallel/dist_smoke.py",
-        "dist_smoke world size: registered nodes",
-    ),
-    "NOMAD_TPU_SMOKE_JOBS": EnvKnob(
-        "12", "nomad_tpu/parallel/dist_smoke.py",
-        "dist_smoke chain-phase eval count",
-    ),
-    "NOMAD_TPU_SMOKE_FAMILY": EnvKnob(
-        "16", "nomad_tpu/parallel/dist_smoke.py",
-        "dist_smoke storm-phase family size",
     ),
     "NOMAD_TPU_TSAN": EnvKnob(
         "0", "nomad_tpu/tsan.py",
@@ -177,11 +155,6 @@ ENV_KNOBS: Dict[str, EnvKnob] = {
         "leader-forward retry budget after the first attempt; each "
         "retry rediscovers the leader (command ids keep retries "
         "idempotent)",
-    ),
-    "NOMAD_TPU_FORWARD_BACKOFF_S": EnvKnob(
-        "0.05", "nomad_tpu/server/cluster.py",
-        "initial leader-forward retry backoff, doubling per attempt "
-        "(capped at 1s)",
     ),
     "NOMAD_TPU_CLUSTER_FAULT": EnvKnob(
         "", "nomad_tpu/raft/chaos.py",
@@ -213,37 +186,12 @@ ENV_KNOBS: Dict[str, EnvKnob] = {
         "follower fan-out worker — main workers in the same process "
         "stay meshless instead of racing it for the world",
     ),
-    "NOMAD_TPU_FANOUT_REFRESH_WAIT_S": EnvKnob(
-        "5", "nomad_tpu/server/fanout.py",
-        "budget a follower waits for its local FSM apply to catch "
-        "up (eval modify-index fence at the gulp boundary, "
-        "refresh-index after a partial commit, own-commit "
-        "alloc-index catch-up); past it the leases nack for "
-        "redelivery",
-    ),
     # -- multi-region federation (server/federation.py) ---------------
-    "NOMAD_TPU_FED_RETRIES": EnvKnob(
-        "4", "nomad_tpu/server/federation.py",
-        "cross-region forward retry budget after the first attempt; "
-        "each retry re-resolves the target region's membership from "
-        "gossip (fan-out command ids keep retries idempotent)",
-    ),
-    "NOMAD_TPU_FED_BACKOFF_S": EnvKnob(
-        "0.05", "nomad_tpu/server/federation.py",
-        "initial cross-region retry backoff, doubling per attempt "
-        "(capped at 1s)",
-    ),
     "NOMAD_TPU_REGION_PROBE_S": EnvKnob(
         "0.5", "nomad_tpu/server/federation.py",
         "federation router cadence: how often the gossip-derived "
         "region health/routing snapshot (and the federation.* "
         "gauges) refresh",
-    ),
-    "NOMAD_TPU_FED_PROXY_TIMEOUT_S": EnvKnob(
-        "2", "nomad_tpu/api/http.py",
-        "deadline for a ?region= HTTP read proxied to another "
-        "region's advertised HTTP address (the explicit WAN-read "
-        "escape hatch)",
     ),
     # -- overload control plane (server/overload.py, server.py) -------
     "NOMAD_TPU_OVERLOAD": EnvKnob(
@@ -260,17 +208,6 @@ ENV_KNOBS: Dict[str, EnvKnob] = {
         "30", "nomad_tpu/server/overload.py",
         "oldest-ready-eval age threshold for SHEDDING (EMERGENCY "
         "at 4x) — the measured commit-wave lag signal",
-    ),
-    "NOMAD_TPU_OVERLOAD_P99_MS": EnvKnob(
-        "0", "nomad_tpu/server/overload.py",
-        "flight-recorder eval-latency p99 threshold for SHEDDING "
-        "(EMERGENCY at 4x); 0 disables the latency signal",
-    ),
-    "NOMAD_TPU_OVERLOAD_SHED_FLOOR": EnvKnob(
-        "2", "nomad_tpu/server/overload.py",
-        "lowest priority class SHEDDING may shed (2 = job "
-        "submissions only; 1 also sheds queries; heartbeats are "
-        "never shed)",
     ),
     "NOMAD_TPU_OVERLOAD_WAVE_MIN": EnvKnob(
         "8", "nomad_tpu/server/server.py",
@@ -314,11 +251,6 @@ ENV_KNOBS: Dict[str, EnvKnob] = {
         "metric history depth: how many snapshot windows the ring "
         "retains (min 2)",
     ),
-    "NOMAD_TPU_OBS_HISTORY_S": EnvKnob(
-        "10", "nomad_tpu/telemetry.py",
-        "metric history cadence: seconds between snapshot windows "
-        "(default N*S = a 10-minute rolling view)",
-    ),
     "NOMAD_TPU_SLO": EnvKnob(
         "1", "nomad_tpu/slo.py",
         "0 disables SLO burn-rate grading (/v1/slo reports every "
@@ -334,33 +266,10 @@ ENV_KNOBS: Dict[str, EnvKnob] = {
         "slow burn window: newest history snapshots graded for "
         "'is it material' (min 2)",
     ),
-    "NOMAD_TPU_SLO_WARN": EnvKnob(
-        "1.0", "nomad_tpu/slo.py",
-        "WARN threshold: either window burning at >= this rate",
-    ),
-    "NOMAD_TPU_SLO_BURN": EnvKnob(
-        "2.0", "nomad_tpu/slo.py",
-        "BURNING threshold: BOTH windows burning at >= this rate",
-    ),
-    "NOMAD_TPU_SLO_P99_MS": EnvKnob(
-        "250", "nomad_tpu/slo.py",
-        "interactive_placement_p99 objective target: windowed "
-        "eval-latency p99 budget",
-    ),
-    "NOMAD_TPU_SLO_FAILOVER_MS": EnvKnob(
-        "60000", "nomad_tpu/slo.py",
-        "failover_detect_to_resume objective target: device "
-        "failover-to-restored p99 budget",
-    ),
     "NOMAD_TPU_DECISIONS": EnvKnob(
         "1", "nomad_tpu/decisions.py",
         "0 turns the adaptive-decision ledger into no-ops (sites "
         "skip record assembly entirely)",
-    ),
-    "NOMAD_TPU_DECISIONS_RING": EnvKnob(
-        "512", "nomad_tpu/decisions.py",
-        "decision-ledger ring depth: newest-wins retention bound "
-        "(min 16)",
     ),
     "NOMAD_TPU_OBS_FANIN_TIMEOUT_S": EnvKnob(
         "2.0", "nomad_tpu/server/cluster.py",
@@ -396,10 +305,6 @@ ENV_KNOBS: Dict[str, EnvKnob] = {
         "deadline floor until the device answers once (cold PJRT "
         "init must not read as a wedge)",
     ),
-    "NOMAD_TPU_WATCHDOG_FACTOR": EnvKnob(
-        "20", "nomad_tpu/device/supervisor.py",
-        "launch-watchdog budget = factor x stage EWMA",
-    ),
     "NOMAD_TPU_WATCHDOG_MIN_S": EnvKnob(
         "5", "nomad_tpu/device/supervisor.py",
         "launch-watchdog budget floor",
@@ -413,20 +318,11 @@ ENV_KNOBS: Dict[str, EnvKnob] = {
         "deterministic CPU fault plan "
         "(wedge_launch|slow_fetch|init_block|flaky[:N])",
     ),
-    "NOMAD_TPU_PREFLIGHT_S": EnvKnob(
-        "600", "nomad_tpu/device/preflight.py",
-        "total preflight retry budget for "
-        "`python -m nomad_tpu.device.preflight`",
-    ),
     # -- client -------------------------------------------------------
     "NOMAD_TPU_EXEC_ISOLATION": EnvKnob(
         "1", "nomad_tpu/client/drivers/exec.py",
         "0 forces the in-process restricted-env spawn instead of "
         "the isolated executor process",
-    ),
-    "NOMAD_TPU_FINGERPRINT_TIMEOUT_S": EnvKnob(
-        "20", "nomad_tpu/client/fingerprint.py",
-        "bounded TPU device-probe deadline during fingerprinting",
     ),
     "NOMAD_TPU_EXECUTOR_STATE": EnvKnob(
         "auto", "nomad_tpu/client/executor.py",

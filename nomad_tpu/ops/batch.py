@@ -1276,75 +1276,6 @@ def hostlocal_staging(
 @functools.partial(
     jax.jit, static_argnames=("n_picks", "spread_fit")
 )
-def chained_plan_picks_shared(
-    cpu_total,
-    mem_total,
-    disk_total,
-    feasible,  # bool[C] shared static mask
-    base_cpu_used,  # f[C] shared snapshot usage
-    base_mem_used,
-    base_disk_used,
-    perms,  # i32[E, C]
-    ask_cpu,  # f[E]
-    ask_mem,
-    ask_disk,
-    desired_count,  # i32[E]
-    limit,  # i32[E]
-    n_candidates,
-    n_picks: int,
-    spread_fit: bool = False,
-):
-    """Serially-equivalent chained planner with shared node columns:
-    the production dispatch shape — only E x C walk orders and per-eval
-    scalars ship per launch, usage chains across evals in-kernel."""
-    C = cpu_total.shape[0]
-    zeros_i = jnp.zeros(C, jnp.int32)
-    zeros_b = jnp.zeros(C, dtype=bool)
-    zeros_f = jnp.zeros(C, cpu_total.dtype)
-
-    def eval_step(used, xs):
-        perm, a_cpu, a_mem, a_disk, desired, lim = xs
-        inp = BatchInputs(
-            feasible=feasible,
-            base_cpu_used=used[0],
-            base_mem_used=used[1],
-            base_disk_used=used[2],
-            base_collisions=zeros_i,
-            penalty=zeros_b,
-            affinity_score=zeros_f,
-            perm=perm,
-            ask_cpu=a_cpu,
-            ask_mem=a_mem,
-            ask_disk=a_disk,
-            desired_count=desired,
-            limit=lim,
-            distinct_hosts=jnp.asarray(False),
-        )
-        rows, used_next, _pulls = _run_picks(
-            cpu_total,
-            mem_total,
-            disk_total,
-            used,
-            inp,
-            jnp.asarray(n_candidates, jnp.int32),
-            n_picks,
-            spread_fit,
-            wanted=desired,
-        )
-        return used_next, rows
-
-    used0 = (base_cpu_used, base_mem_used, base_disk_used)
-    _final, rows = jax.lax.scan(
-        eval_step,
-        used0,
-        (perms, ask_cpu, ask_mem, ask_disk, desired_count, limit),
-    )
-    return rows
-
-
-@functools.partial(
-    jax.jit, static_argnames=("n_picks", "spread_fit")
-)
 def batch_plan_picks_shared(
     cpu_total,
     mem_total,

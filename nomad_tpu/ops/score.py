@@ -305,13 +305,6 @@ def score_and_select_packed(inp: ScoreInputs, spread_fit: bool = False):
     )
 
 
-@functools.partial(jax.jit, static_argnames=("spread_fit",))
-def score_all(inp: ScoreInputs, spread_fit: bool = False):
-    """Scores + feasibility only (system stack / diagnostics)."""
-    feasible, final = _score_vectors(inp, spread_fit)
-    return feasible, final
-
-
 def make_perm(rng, rows, capacity: int) -> np.ndarray:
     """Walk order matching the oracle's seeded Fisher-Yates shuffle
     (sched/feasible.py shuffle_nodes) applied to the same candidate list:

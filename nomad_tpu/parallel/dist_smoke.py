@@ -53,37 +53,14 @@ import sys
 import time
 from typing import List, Optional
 
-# default world shape: small enough that compiles dominate nothing,
-# big enough that every device owns multiple node rows and every
-# phase crosses the process boundary.  The NOMAD_TPU_SMOKE_* knobs
-# scale the SAME worker (one code path) from this tier-1 tiny world
-# up to the bigworld reduced-scale CI drive (loadgen/bigworld_smoke)
+# world shape: small enough that compiles dominate nothing, big
+# enough that every device owns multiple node rows and every phase
+# crosses the process boundary
 DEVICES_PER_PROC = 2
 CHAIN_NODES = 12  # -> capacity 16: tiles over 4 devices
 CHAIN_JOBS = 12
 FAMILY_JOBS = 16
 KERNEL_E, KERNEL_A, KERNEL_C = 16, 64, 256
-
-
-def _world_knob(name: str, default: int) -> int:
-    try:
-        return max(1, int(os.environ.get(name, default)))
-    except ValueError:
-        return default
-
-
-def smoke_world() -> dict:
-    """The world-size knobs, defaulted to the tier-1 tiny world:
-    NOMAD_TPU_SMOKE_NODES (cluster size), NOMAD_TPU_SMOKE_JOBS
-    (chain-phase evals), NOMAD_TPU_SMOKE_FAMILY (storm family
-    size)."""
-    return {
-        "nodes": _world_knob("NOMAD_TPU_SMOKE_NODES", CHAIN_NODES),
-        "jobs": _world_knob("NOMAD_TPU_SMOKE_JOBS", CHAIN_JOBS),
-        "family": _world_knob(
-            "NOMAD_TPU_SMOKE_FAMILY", FAMILY_JOBS
-        ),
-    }
 
 
 def _free_port() -> int:
@@ -339,15 +316,10 @@ def run_worker() -> int:
 
     rank = jax.process_index()
     procs = jax.process_count()
-    world = smoke_world()
-    n_nodes, n_jobs, n_family = (
-        world["nodes"], world["jobs"], world["family"]
-    )
     result = {
         "procs": procs,
         "devices_per_host": jax.local_device_count(),
         "global_devices": jax.device_count(),
-        "world": world,
     }
 
     from nomad_tpu.server import Server
@@ -366,9 +338,9 @@ def run_worker() -> int:
     # starts, so the gulp composition — and with it the collective
     # launch sequence — is identical on every process
     worker.start = lambda: None  # type: ignore[method-assign]
-    for node in _make_nodes(n_nodes, seed=5):
+    for node in _make_nodes(CHAIN_NODES, seed=5):
         server.register_node(node)
-    chain_jobs = _make_jobs(n_jobs, seed=7)
+    chain_jobs = _make_jobs(CHAIN_JOBS, seed=7)
     for job in chain_jobs:
         server.register_job(job)
     server.start()
@@ -385,7 +357,7 @@ def run_worker() -> int:
         )
 
         # -- phase: chain (assemble/launch/fetch/replay) --------------
-        members = _drain_broker(server, worker, n_jobs)
+        members = _drain_broker(server, worker, CHAIN_JOBS)
         t0 = time.monotonic()
         leftover = worker._process_batch(members)
         for _ in range(8):
@@ -401,7 +373,7 @@ def run_worker() -> int:
         assert placed, "chain placed nothing"
         _assert_same_everywhere("chain placements", placed)
         result["chain"] = {
-            "evals": n_jobs,
+            "evals": CHAIN_JOBS,
             "placements": len(placed),
             "placements_per_sec": round(len(placed) / chain_dt, 1),
             "mesh_launches": worker.mesh_used,
@@ -449,7 +421,7 @@ def run_worker() -> int:
         }
 
         # -- phase: storm (sharded auction over the pod mesh) ---------
-        fam_jobs = _family_jobs(n_family)
+        fam_jobs = _family_jobs(FAMILY_JOBS)
         for job in fam_jobs:
             server.register_job(job)
         # wait for the whole wave to land, then dequeue ONE member
@@ -458,7 +430,7 @@ def run_worker() -> int:
         deadline = time.monotonic() + 30.0
         while (
             server.broker.ready_count(worker.schedulers)
-            < n_family
+            < FAMILY_JOBS
             and time.monotonic() < deadline
         ):
             time.sleep(0.02)
@@ -470,7 +442,7 @@ def run_worker() -> int:
             f"stray eval {ev0.job_id} raced the storm phase"
         )
         storm = worker._maybe_drain_storm(ev0, token0)
-        assert storm is not None and len(storm) == n_family, (
+        assert storm is not None and len(storm) == FAMILY_JOBS, (
             "storm detector missed the family backlog"
         )
         leftover = worker._process_storm(storm)
@@ -485,7 +457,7 @@ def run_worker() -> int:
         storm_placed = _placements(server, fam_jobs)
         _assert_same_everywhere("storm placements", storm_placed)
         result["storm"] = {
-            "members": n_family,
+            "members": FAMILY_JOBS,
             "solves": worker.storm_solves,
             "fallbacks": worker.storm_fallbacks,
             "placements": len(storm_placed),
@@ -574,7 +546,6 @@ def launch(
     procs: int = 2,
     devices_per_proc: int = DEVICES_PER_PROC,
     timeout: float = 420.0,
-    extra_env: Optional[dict] = None,
 ) -> dict:
     """Spawn the distributed smoke and return rank 0's result row.
     Raises RuntimeError (with the children's log tails) on failure or
@@ -618,8 +589,6 @@ def launch(
                 "NOMAD_TPU_BROKER_WATCHDOG": "1",
             }
         )
-        if extra_env:
-            env.update(extra_env)
         out = open(
             os.path.join(log_dir, f"p{rank}.log"), "w+"
         )

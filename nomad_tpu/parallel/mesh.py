@@ -66,22 +66,6 @@ class DistConfig(NamedTuple):
     process_id: int
 
 
-def _dist_knob(name: str, default: str) -> str:
-    """One NOMAD_TPU_DIST_* knob, namespaced.  With NOMAD_TPU_DIST_NS
-    set (say ``f1``), ``NOMAD_TPU_DIST_COORD_F1`` wins over
-    ``NOMAD_TPU_DIST_COORD`` — so a composed-topology harness can
-    hand every process ONE shared env block describing all N follower
-    worlds, varying only the namespace selector (plus the member id)
-    per process, and no follower can accidentally join another
-    follower's world by inheriting an un-namespaced coordinator."""
-    ns = os.environ.get("NOMAD_TPU_DIST_NS", "")
-    if ns:
-        val = os.environ.get(f"{name}_{ns.upper()}")
-        if val is not None:
-            return val
-    return os.environ.get(name, default)
-
-
 def dist_config() -> Optional[DistConfig]:
     """The NOMAD_TPU_DIST_* knobs, or None when multi-host is not
     opted into (`NOMAD_TPU_DIST` != 1).  With the opt-in set, a
@@ -90,12 +74,10 @@ def dist_config() -> Optional[DistConfig]:
     peer-deadlock the loud-failure contract exists to prevent."""
     if os.environ.get("NOMAD_TPU_DIST") != "1":
         return None
-    coord = _dist_knob(
-        "NOMAD_TPU_DIST_COORD", "127.0.0.1:8476"
-    )
+    coord = os.environ.get("NOMAD_TPU_DIST_COORD", "127.0.0.1:8476")
     try:
-        procs = int(_dist_knob("NOMAD_TPU_DIST_PROCS", "1"))
-        pid = int(_dist_knob("NOMAD_TPU_DIST_ID", "0"))
+        procs = int(os.environ.get("NOMAD_TPU_DIST_PROCS", "1"))
+        pid = int(os.environ.get("NOMAD_TPU_DIST_ID", "0"))
     except ValueError as exc:
         raise ValueError(
             "NOMAD_TPU_DIST=1 but NOMAD_TPU_DIST_PROCS/"

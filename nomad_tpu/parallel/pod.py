@@ -192,21 +192,15 @@ class PodService:
 def build_worker_mesh():
     """The worker's mesh bring-up, shared verbatim by head (via
     BatchWorker._make_mesh) and peer: join the NOMAD_TPU_DIST* world,
-    then lay every visible device (capped by NOMAD_TPU_MESH_DEVICES)
-    along the node axis.  Identical env -> identical mesh on every
-    member, which the collective programs require."""
+    then lay every visible device along the node axis.  Identical env
+    -> identical mesh on every member, which the collective programs
+    require."""
     import jax
 
     from .mesh import distributed_init, make_mesh
 
     distributed_init()
     n = len(jax.devices())
-    try:
-        cap = int(os.environ.get("NOMAD_TPU_MESH_DEVICES", "0"))
-    except ValueError:
-        cap = 0
-    if cap > 0:
-        n = min(n, cap)
     if n <= 1:
         return None
     return make_mesh(n_devices=n, eval_axis=1)

@@ -132,11 +132,8 @@ def resolve(job) -> Optional[ResolvedPolicy]:
 # ---------------------------------------------------------------------------
 
 
-def _cache_capacity() -> int:
-    try:
-        return max(1, int(os.environ.get("NOMAD_TPU_POLICY_CACHE", "64")))
-    except ValueError:
-        return 64
+# LRU capacity of the assembled throughput-tensor cache
+TPUT_CACHE_CAPACITY = 64
 
 
 class _TputCache:
@@ -162,8 +159,7 @@ class _TputCache:
         with self._lock:
             self._entries[key] = tensor
             self._entries.move_to_end(key)
-            cap = _cache_capacity()
-            while len(self._entries) > cap:
+            while len(self._entries) > TPUT_CACHE_CAPACITY:
                 self._entries.popitem(last=False)
 
     def __len__(self) -> int:

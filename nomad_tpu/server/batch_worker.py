@@ -136,6 +136,10 @@ WALK_COUNTERS = (
 # arena, warm_shapes and the mesh path still use it as the default
 # eval-axis alignment
 PIPELINE_CHUNK = CHUNK_BUCKETS[-1]
+# pipelined prescore: chunk launches in flight before the host blocks
+# on the oldest one's fetch (1 would be launch->fetch->replay per
+# chunk, no overlap)
+PIPELINE_DEPTH = 2
 # continuous micro-batching counters, zero-registered at Server
 # construction (tools/check_stage_accounting.py check 10): every
 # `admission.*` name the worker emits must appear here, so dashboards
@@ -779,13 +783,6 @@ class BatchWorker(Worker):
         # expected-touch ledger so serial fallbacks don't poison the
         # relaxed conflict check for later wave members
         self._last_replay_touches: Optional[Dict[str, int]] = None
-        try:
-            self.replay_workers: Optional[int] = (
-                int(_os.environ.get("NOMAD_TPU_REPLAY_WORKERS", "0"))
-                or None
-            )
-        except ValueError:
-            self.replay_workers = None
         self._replay_pool = None  # lazy EvaluatePool
         self.replay_speculative = 0  # speculations committed
         self.replay_conflicts = 0  # speculations discarded on conflict
@@ -857,15 +854,6 @@ class BatchWorker(Worker):
         except ValueError:
             self.storm_max = 256
         self.storm_max = max(self.storm_min, min(self.storm_max, 1024))
-        try:
-            # 0 = auto: the solve's padded row bucket (the auction
-            # assigns at least one row per round, so the bucket is
-            # the convergence bound)
-            self.storm_rounds = int(
-                _os.environ.get("NOMAD_TPU_STORM_ROUNDS", "0")
-            )
-        except ValueError:
-            self.storm_rounds = 0
         self.storm_solves = 0
         self.storm_evals = 0
         self.storm_rows = 0
@@ -925,19 +913,6 @@ class BatchWorker(Worker):
         self._usage_cache_lock = threading.Lock()
         self._input_cache_hits = 0
         self._input_cache_misses = 0
-        # pipelined prescore: how many chunk launches may be in flight
-        # before the host blocks on the oldest one's fetch.  1 degrades
-        # to launch->fetch->replay per chunk (no overlap); 0/negative
-        # clamps to 1
-        try:
-            self.pipeline_depth = max(
-                1,
-                int(
-                    _os.environ.get("NOMAD_TPU_PIPELINE_DEPTH", 2)
-                ),
-            )
-        except ValueError:
-            self.pipeline_depth = 2
         self._donate_carries: Optional[bool] = None
         # cold-compile shield: launch signatures known to be compiled.
         # A first-seen shape is compiled on a background thread while
@@ -1035,8 +1010,7 @@ class BatchWorker(Worker):
         None otherwise.  The operator asked for it (NOMAD_TPU_MESH=1),
         so a mesh that does not form is logged at WARNING with the
         exception — the exact single-device launches then cover every
-        eval.  NOMAD_TPU_MESH_DEVICES caps the node axis (bench sweeps
-        and deployments that reserve chips for other work).
+        eval.  The node axis spans every visible device.
 
         With the NOMAD_TPU_DIST_* knobs set, the multi-host world is
         joined FIRST (`distributed_init`, idempotent) so
@@ -1045,8 +1019,6 @@ class BatchWorker(Worker):
         deliberately — the peer processes would deadlock inside their
         first collective waiting for a member that silently fell back
         to single-host."""
-        import os as _os
-
         from ..parallel.mesh import distributed_init
 
         distributed_init()
@@ -1055,14 +1027,6 @@ class BatchWorker(Worker):
             import jax as _jax
 
             n = len(_jax.devices())
-            try:
-                cap = int(
-                    _os.environ.get("NOMAD_TPU_MESH_DEVICES", "0")
-                )
-            except ValueError:
-                cap = 0
-            if cap > 0:
-                n = min(n, cap)
             if n > 1:
                 from ..parallel.mesh import host_count, make_mesh
 
@@ -1429,8 +1393,7 @@ class BatchWorker(Worker):
 
     def _replay_pool_instance(self):
         """Lazy speculative-replay pool (the plan applier's
-        EvaluatePool shape, sized cores/2 unless
-        NOMAD_TPU_REPLAY_WORKERS overrides); its width is the
+        EvaluatePool shape, sized cores/2); its width is the
         `batch_worker.replay_parallelism` gauge.
 
         Re-created when the previous pool was shut down: leadership
@@ -1443,8 +1406,7 @@ class BatchWorker(Worker):
             from .plan_apply import EvaluatePool
 
             self._replay_pool = EvaluatePool(
-                self.replay_workers,
-                thread_name_prefix="replay-spec",
+                thread_name_prefix="replay-spec"
             )
             metrics = getattr(self.server, "metrics", None)
             if metrics is not None:
@@ -2207,7 +2169,7 @@ class BatchWorker(Worker):
                     while (
                         not stalled
                         and ci < len(chunks)
-                        and len(pending) < self.pipeline_depth
+                        and len(pending) < PIPELINE_DEPTH
                     ):
                         casm, c0, c1, base = chunks[ci]
                         # mesh chunks time/trace/guard under their own
@@ -3003,8 +2965,6 @@ class BatchWorker(Worker):
 
         table = snap.node_table
         max_rounds = problem.max_rounds
-        if self.storm_rounds > 0:
-            max_rounds = min(max_rounds, self.storm_rounds)
         mesh = self._mesh
         if (
             mesh is not None

@@ -47,16 +47,9 @@ def _forward_retries() -> int:
         return 4
 
 
-def _forward_backoff_s() -> float:
-    """Initial retry backoff; doubles per attempt (capped at 1s) so a
-    leaderless interregnum is waited out, not hammered."""
-    try:
-        return max(
-            0.0,
-            float(os.environ.get("NOMAD_TPU_FORWARD_BACKOFF_S", "0.05")),
-        )
-    except ValueError:
-        return 0.05
+# initial leader-forward retry backoff; doubles per attempt (capped at
+# 1s) so a leaderless interregnum is waited out, not hammered
+FORWARD_BACKOFF_S = 0.05
 
 
 def obs_fanin_timeout_s() -> float:
@@ -342,7 +335,7 @@ class ClusterServer(Server):
         cmd_id so even a WHOLE retried call dedups, not just one
         forward attempt."""
         data = encode_command(kind, args, cmd_id=cmd_id or new_id())
-        backoff = _forward_backoff_s()
+        backoff = FORWARD_BACKOFF_S
         retries = _forward_retries()
         last_exc: Exception = NotLeaderError(None)
         for attempt in range(retries + 1):

@@ -111,16 +111,11 @@ def fanout_lease_n() -> int:
         return 8
 
 
-def fanout_refresh_wait_s() -> float:
-    try:
-        return max(
-            0.1,
-            float(
-                os.environ.get("NOMAD_TPU_FANOUT_REFRESH_WAIT_S", "5")
-            ),
-        )
-    except ValueError:
-        return 5.0
+# budget a follower waits for its local FSM apply to catch up (the
+# eval fence at the gulp boundary, the refresh index after a partial
+# commit, its own commit's alloc index); past it the leases nack for
+# redelivery
+REFRESH_WAIT_S = 5.0
 
 
 class RemoteBrokerClient:
@@ -651,10 +646,6 @@ def _make_fanout_worker(view: FollowerView, seed=None):
         # see BatchWorker._mesh_allowed
         _is_fanout_worker = True
 
-        def __init__(self, server, **kwargs) -> None:
-            super().__init__(server, **kwargs)
-            self._refresh_wait_s = fanout_refresh_wait_s()
-
         def _count_fanout(self, kind: str) -> None:
             metrics = getattr(self.server, "metrics", None)
             if metrics is not None:
@@ -680,7 +671,7 @@ def _make_fanout_worker(view: FollowerView, seed=None):
                 return True
             try:
                 self.store.snapshot_min_index(
-                    target, timeout=self._refresh_wait_s
+                    target, timeout=REFRESH_WAIT_S
                 )
                 return True
             except TimeoutError:
@@ -692,7 +683,7 @@ def _make_fanout_worker(view: FollowerView, seed=None):
                         "held": len(held),
                         "target_index": target,
                         "local_index": self.store.latest_index(),
-                        "wait_s": self._refresh_wait_s,
+                        "wait_s": REFRESH_WAIT_S,
                         "leader_gen": self._leader_gen(),
                     },
                     alternatives=["keep_waiting"],
@@ -743,7 +734,7 @@ def _make_fanout_worker(view: FollowerView, seed=None):
                         inputs={
                             "refresh_index": result.refresh_index,
                             "local_index": self.store.latest_index(),
-                            "wait_s": self._refresh_wait_s,
+                            "wait_s": REFRESH_WAIT_S,
                             "leader_gen": self._leader_gen(),
                         },
                         alternatives=["plan_on_stale_snapshot"],
@@ -755,7 +746,7 @@ def _make_fanout_worker(view: FollowerView, seed=None):
                     )
                     snap = self.store.snapshot_min_index(
                         result.refresh_index,
-                        timeout=self._refresh_wait_s,
+                        timeout=REFRESH_WAIT_S,
                     )
                     return result, snap
                 if result.alloc_index:
@@ -765,7 +756,7 @@ def _make_fanout_worker(view: FollowerView, seed=None):
                     # evaluate_plan is the serialization point)
                     self.store.wait_for_index(
                         result.alloc_index,
-                        timeout=self._refresh_wait_s,
+                        timeout=REFRESH_WAIT_S,
                     )
                 return result, None
             finally:

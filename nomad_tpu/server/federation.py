@@ -75,26 +75,13 @@ FEDERATION_GAUGES = (
 )
 
 
-def fed_retries() -> int:
-    """Bounded cross-region forward retry budget (attempts AFTER the
-    first); each retry re-resolves the target region's membership, so
-    a forward survives the remote leadership moving mid-call."""
-    try:
-        return max(0, int(os.environ.get("NOMAD_TPU_FED_RETRIES", "4")))
-    except ValueError:
-        return 4
-
-
-def fed_backoff_s() -> float:
-    """Initial cross-region retry backoff; doubles per attempt (capped
-    at 1s) so a remote interregnum is waited out, not hammered."""
-    try:
-        return max(
-            0.0,
-            float(os.environ.get("NOMAD_TPU_FED_BACKOFF_S", "0.05")),
-        )
-    except ValueError:
-        return 0.05
+# bounded cross-region forward retry budget (attempts AFTER the first):
+# each retry re-resolves the target region's membership, so a forward
+# survives the remote leadership moving mid-call
+FED_RETRIES = 4
+# initial cross-region retry backoff; doubles per attempt (capped at
+# 1s) so a remote interregnum is waited out, not hammered
+FED_BACKOFF_S = 0.05
 
 
 def region_probe_s() -> float:
@@ -142,8 +129,8 @@ class FederationRouter:
 
     def __init__(self, server) -> None:
         self.server = server
-        self.retries = fed_retries()
-        self.backoff_s = fed_backoff_s()
+        self.retries = FED_RETRIES
+        self.backoff_s = FED_BACKOFF_S
         self._probe_s = region_probe_s()
         self._lock = threading.Lock()
         # region -> {"members": int, "http": [addr, ...]}

@@ -38,10 +38,7 @@ EMERGENCY threshold:
   per-job pending heaps — the work already accepted but not started;
 * **oldest pending age** (``EvalBroker.oldest_pending_age()``): the
   commit-wave lag the next accepted eval will experience before its
-  wave even starts — queueing delay measured, not modeled;
-* **flight-recorder p99** (``batch_worker.eval_latency_ms`` p99, off
-  by default — ``NOMAD_TPU_OVERLOAD_P99_MS``): the end-to-end latency
-  tail with trace exemplars attached.
+  wave even starts — queueing delay measured, not modeled.
 
 Escalation is immediate; de-escalation drops one rung at a time after
 the signals have stayed below the lower rung's thresholds for a
@@ -102,9 +99,6 @@ _EMERGENCY_FACTOR = 4.0
 # Retry-After advice per mode (seconds); SHEDDING backs clients off
 # briefly, EMERGENCY tells them the backlog needs real draining
 _RETRY_AFTER_S = {MODE_SHEDDING: 1.0, MODE_EMERGENCY: 5.0}
-# flight-recorder p99 input needs this many samples before it counts
-# (a 3-sample "p99" is just the max of a cold start)
-_P99_MIN_COUNT = 16
 
 # observability/liveness endpoints that must answer DURING overload —
 # shedding the endpoints an operator needs to see the overload would
@@ -179,19 +173,6 @@ class OverloadController:
         self.age_threshold_s = max(
             0.1, _env_float("NOMAD_TPU_OVERLOAD_AGE_S", 30.0)
         )
-        # flight-recorder p99 input (ms); 0 disables the signal
-        self.p99_threshold_ms = max(
-            0.0, _env_float("NOMAD_TPU_OVERLOAD_P99_MS", 0.0)
-        )
-        # lowest (numerically) priority class SHEDDING may shed;
-        # EMERGENCY always sheds every class above heartbeats
-        try:
-            self.shed_floor = int(
-                os.environ.get("NOMAD_TPU_OVERLOAD_SHED_FLOOR", "2")
-            )
-        except ValueError:
-            self.shed_floor = PRI_SUBMIT
-        self.shed_floor = max(PRI_QUERY, self.shed_floor)
         self._lock = threading.Lock()
         self._mode = MODE_NORMAL
         self._last_eval = 0.0
@@ -202,33 +183,21 @@ class OverloadController:
         self._incident_id: Optional[str] = None
         self._incident_shed_at_start = 0.0
         # last computed signals, for /v1/overload
-        self._signals: Dict[str, float] = {
-            "depth": 0.0, "age_s": 0.0, "p99_ms": 0.0,
-        }
+        self._signals: Dict[str, float] = {"depth": 0.0, "age_s": 0.0}
 
     # -- signals -------------------------------------------------------
 
-    def _read_signals(self) -> Tuple[float, float, float]:
+    def _read_signals(self) -> Tuple[float, float]:
         broker = getattr(self.server, "broker", None)
         depth = float(broker.pending_depth()) if broker else 0.0
         age = float(broker.oldest_pending_age()) if broker else 0.0
-        p99 = 0.0
-        if self.p99_threshold_ms > 0:
-            metrics = getattr(self.server, "metrics", None)
-            snap = (
-                metrics.get_sample("batch_worker.eval_latency_ms")
-                if metrics is not None
-                else None
-            )
-            if snap is not None and snap["count"] >= _P99_MIN_COUNT:
-                p99 = float(snap["p99"])
-        return depth, age, p99
+        return depth, age
 
-    def _severity(self, depth: float, age: float, p99: float) -> int:
+    def _severity(self, depth: float, age: float) -> int:
         """Worst rung any single signal supports."""
 
         def rung(value: float, threshold: float) -> int:
-            if threshold <= 0 or value < threshold:
+            if value < threshold:
                 return MODE_NORMAL
             if value < threshold * _EMERGENCY_FACTOR:
                 return MODE_SHEDDING
@@ -237,7 +206,6 @@ class OverloadController:
         return max(
             rung(depth, self.depth_threshold),
             rung(age, self.age_threshold_s),
-            rung(p99, self.p99_threshold_ms),
         )
 
     # -- mode ladder ---------------------------------------------------
@@ -256,16 +224,16 @@ class OverloadController:
         # locks across modules here would add an edge to the static
         # lock graph for no benefit (a stale signal read costs one
         # _EVAL_INTERVAL_S of mode lag)
-        depth, age, p99 = self._read_signals()
-        target = self._severity(depth, age, p99)
+        depth, age = self._read_signals()
+        target = self._severity(depth, age)
         with self._lock:
-            self._signals = {"depth": depth, "age_s": age, "p99_ms": p99}
+            self._signals = {"depth": depth, "age_s": age}
             mode = self._mode
             if target >= mode:
                 # the signals support (or exceed) the current rung
                 self._last_supported = now
             if target > mode:
-                self._transition_locked(target, depth, age, p99)
+                self._transition_locked(target, depth, age)
             elif (
                 target < mode
                 and now - self._last_supported >= _COOLDOWN_S
@@ -273,12 +241,12 @@ class OverloadController:
                 # one rung at a time, re-anchoring the cooldown, so a
                 # deep EMERGENCY walks down through SHEDDING instead
                 # of snapping open the floodgates
-                self._transition_locked(mode - 1, depth, age, p99)
+                self._transition_locked(mode - 1, depth, age)
                 self._last_supported = now
             return self._mode
 
     def _transition_locked(
-        self, new_mode: int, depth: float, age: float, p99: float
+        self, new_mode: int, depth: float, age: float
     ) -> None:
         from ..decisions import DECISIONS
         from ..trace import TRACE
@@ -317,7 +285,6 @@ class OverloadController:
                 mode=MODE_NAMES[new_mode],
                 broker_depth=depth,
                 oldest_age_s=round(age, 3),
-                p99_ms=round(p99, 1),
             )
         elif self._incident_id is not None:
             TRACE.annotate(
@@ -342,7 +309,6 @@ class OverloadController:
             inputs={
                 "broker_depth": depth,
                 "oldest_age_s": round(age, 3),
-                "p99_ms": round(p99, 1),
                 "leader_gen": getattr(
                     self.server, "_leadership_gen", 0
                 ),
@@ -398,7 +364,7 @@ class OverloadController:
         metrics = getattr(self.server, "metrics", None)
         shed = False
         if mode == MODE_SHEDDING:
-            shed = pclass >= self.shed_floor
+            shed = pclass >= PRI_SUBMIT
         elif mode == MODE_EMERGENCY:
             # heartbeats are the one class an overloaded leader must
             # keep answering: shedding them converts ingress overload
@@ -441,9 +407,7 @@ class OverloadController:
             "thresholds": {
                 "depth": self.depth_threshold,
                 "age_s": self.age_threshold_s,
-                "p99_ms": self.p99_threshold_ms,
                 "emergency_factor": _EMERGENCY_FACTOR,
             },
-            "shed_floor": self.shed_floor,
             "incident": incident,
         }

@@ -10,9 +10,9 @@ now?") and a **slow** window (``NOMAD_TPU_SLO_SLOW_N`` — "is it
 material?").  Each objective's burn rate is its observed
 badness divided by its error budget; status is
 
-* ``BURNING`` when BOTH windows burn at >= ``NOMAD_TPU_SLO_BURN``
+* ``BURNING`` when BOTH windows burn at >= ``BURN_AT``
   (fast alone is noise, slow alone is history),
-* ``WARN`` when EITHER window reaches ``NOMAD_TPU_SLO_WARN``,
+* ``WARN`` when EITHER window reaches ``WARN_AT``,
 * ``OK`` otherwise (including "not enough history yet": the engine
   never pages on an empty ring).
 
@@ -46,16 +46,18 @@ _ZERO_TOLERANCE_BURN = 1000.0
 
 _STATUS_RANK = {"OK": 0, "WARN": 1, "BURNING": 2}
 
+# burn-rate thresholds: WARN when either window reaches WARN_AT,
+# BURNING when both reach BURN_AT
+WARN_AT = 1.0
+BURN_AT = 2.0
+# objective targets: the interactive placement budget (windowed
+# eval-latency p99) and device failover-to-restored p99
+P99_TARGET_MS = 250.0
+FAILOVER_TARGET_MS = 60000.0
+
 
 def slo_enabled() -> bool:
     return os.environ.get("NOMAD_TPU_SLO", "1") != "0"
-
-
-def _knob_float(name: str, default: float) -> float:
-    try:
-        return float(os.environ.get(name, str(default)))
-    except ValueError:
-        return default
 
 
 def _knob_int(name: str, default: int, lo: int) -> int:
@@ -74,12 +76,6 @@ class SLOEngine:
         self.enabled = slo_enabled()
         self.fast_n = _knob_int("NOMAD_TPU_SLO_FAST_N", 6, 2)
         self.slow_n = _knob_int("NOMAD_TPU_SLO_SLOW_N", 30, 2)
-        self.warn_at = _knob_float("NOMAD_TPU_SLO_WARN", 1.0)
-        self.burn_at = _knob_float("NOMAD_TPU_SLO_BURN", 2.0)
-        p99_ms = _knob_float("NOMAD_TPU_SLO_P99_MS", 250.0)
-        failover_ms = _knob_float(
-            "NOMAD_TPU_SLO_FAILOVER_MS", 60000.0
-        )
         # The declared objectives.  "budget" is the error budget the
         # burn rate is normalized against: for latency objectives the
         # tolerated fraction of windows over target, for ratio
@@ -90,7 +86,7 @@ class SLOEngine:
                 "name": "interactive_placement_p99",
                 "kind": "latency_p99",
                 "sample": "batch_worker.eval_latency_ms",
-                "target_ms": p99_ms,
+                "target_ms": P99_TARGET_MS,
                 "budget": 0.05,
                 "doc": "windowed eval-latency p99 stays within the "
                        "interactive placement budget",
@@ -124,7 +120,7 @@ class SLOEngine:
                 "name": "failover_detect_to_resume",
                 "kind": "latency_p99",
                 "sample": "device.failover_resume_ms",
-                "target_ms": failover_ms,
+                "target_ms": FAILOVER_TARGET_MS,
                 "budget": 0.05,
                 "doc": "device failover detect-to-resume stays "
                        "within budget",
@@ -167,9 +163,9 @@ class SLOEngine:
         raise ValueError(f"unknown objective kind {kind!r}")
 
     def _grade(self, burn_fast: float, burn_slow: float) -> str:
-        if burn_fast >= self.burn_at and burn_slow >= self.burn_at:
+        if burn_fast >= BURN_AT and burn_slow >= BURN_AT:
             return "BURNING"
-        if burn_fast >= self.warn_at or burn_slow >= self.warn_at:
+        if burn_fast >= WARN_AT or burn_slow >= WARN_AT:
             return "WARN"
         return "OK"
 
@@ -214,8 +210,8 @@ class SLOEngine:
                 "interval_s": hist.get("interval_s", 0),
             },
             "thresholds": {
-                "warn": self.warn_at,
-                "burning": self.burn_at,
+                "warn": WARN_AT,
+                "burning": BURN_AT,
             },
             "objectives": out,
             "worst": worst,

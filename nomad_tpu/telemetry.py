@@ -43,14 +43,9 @@ def obs_history_windows() -> int:
         return 60
 
 
-def obs_history_interval_s() -> float:
-    try:
-        return max(
-            0.05,
-            float(os.environ.get("NOMAD_TPU_OBS_HISTORY_S", "10")),
-        )
-    except ValueError:
-        return 10.0
+# seconds between history snapshot windows (60 windows by default: a
+# 10-minute rolling view)
+OBS_HISTORY_INTERVAL_S = 10.0
 
 
 def percentile(ordered: List[float], q: float) -> float:
@@ -315,7 +310,7 @@ class MetricsHistory:
     see "p99 over the last N minutes" without an external scraper, and
     the training-data surface the future self-tuning controller reads.
 
-    Every ``NOMAD_TPU_OBS_HISTORY_S`` seconds a snapshot thread
+    Every ``interval_s`` seconds (10 by default) a snapshot thread
     (`obs-history`) captures all registered counters (cumulative),
     gauges (point-in-time) and sample summaries (count + p50/p99 over
     the summary's sliding window, read at the window boundary) into a
@@ -331,18 +326,14 @@ class MetricsHistory:
         self,
         metrics: Metrics,
         windows: Optional[int] = None,
-        interval_s: Optional[float] = None,
+        interval_s: float = OBS_HISTORY_INTERVAL_S,
     ) -> None:
         self.metrics = metrics
         self.enabled = obs_history_enabled()
         self.windows = (
             windows if windows is not None else obs_history_windows()
         )
-        self.interval_s = (
-            interval_s
-            if interval_s is not None
-            else obs_history_interval_s()
-        )
+        self.interval_s = interval_s
         self._ring: deque = deque(maxlen=self.windows)
         self._lock = threading.Lock()
         self._stop = threading.Event()

@@ -203,6 +203,9 @@ def test_gc_make_room_for_destroys_oldest(tmp_path):
         max_allocs=3,
         destroy_fn=destroyed.append,
     )
+    # make_room_for goes on to _gc_for_disk: a roomy disk, whatever
+    # the machine's own is
+    gc._disk_stats = lambda: (10.0, 1e6)
     gc.set_live_count(1)
     gc.mark_terminal("old1")
     gc.mark_terminal("old2")

@@ -235,7 +235,7 @@ def test_overload_close_incident_finishes_shed_trace():
     TRACE.clear()
     ctl = OverloadController(SimpleNamespace(metrics=Metrics()))
     with ctl._lock:
-        ctl._transition_locked(MODE_SHEDDING, 999.0, 45.0, 0.0)
+        ctl._transition_locked(MODE_SHEDDING, 999.0, 45.0)
     incident = ctl._incident_id
     assert incident is not None
     assert TRACE.get(incident)["complete"] is False

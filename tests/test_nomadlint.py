@@ -138,6 +138,46 @@ def test_wrong_rule_suppression_does_not_hide(tmp_path):
     )
 
 
+def test_a_knob_nothing_sets_is_a_finding(tmp_path):
+    """config-drift direction 5: a folded knob registered again (row,
+    docs line and all) with no setter anywhere in the tree is a
+    finding; listing it as a deployment setting is the one way out."""
+    ctx = _ctx()
+    # in two parts: a whole name here would be a use of the knob
+    knob = "NOMAD_TPU_" + "PIPELINE_DEPTH"
+    row = f'    "{knob}": EnvKnob("2", "nomad_tpu/x.py", "folded"),\n'
+    registry = ctx.source(ctx.path("envknobs")).replace(
+        "ENV_KNOBS: Dict[str, EnvKnob] = {\n",
+        "ENV_KNOBS: Dict[str, EnvKnob] = {\n" + row,
+    )
+    assert row in registry
+    docs = tmp_path / "docs.md"
+    docs.write_text(
+        ctx.source(ctx.path("arch_doc")) + f"\n| `{knob}` | `2` |\n"
+    )
+
+    def unset(registry_src):
+        path = tmp_path / "envknobs.py"
+        path.write_text(registry_src)
+        result = run(
+            ctx.with_overrides(
+                envknobs=str(path), arch_doc=str(docs)
+            ),
+            ["config-drift"],
+        )
+        return [
+            f.message for f in result.findings
+            if "never set" in f.message
+        ]
+
+    found = unset(registry)
+    assert len(found) == 1 and knob in found[0], found
+    assert unset(registry.replace(
+        "DEPLOYMENT_KNOBS = frozenset({",
+        f'DEPLOYMENT_KNOBS = frozenset({{"{knob}",',
+    )) == []
+
+
 def _run_cli(*args):
     return subprocess.run(
         [sys.executable, "-m", "tools.nomadlint", *args],

@@ -13,26 +13,74 @@ keeps it complete.  Usage is collected by AST scan for full-match
 dicts handed to subprocesses all surface the name as exactly such a
 literal.
 
-Four directions checked:
+Five directions checked:
 
 1. every knob used in code is registered in ``ENV_KNOBS``;
 2. every registered knob appears in the docs table;
 3. every ``NOMAD_TPU_*`` name in the docs table is registered
    (no stale doc rows);
 4. every registered knob is actually read somewhere (no dead
-   registry rows).
+   registry rows);
+5. every registered knob is set by something other than its read
+   site — a ``setenv`` / ``setdefault`` call, an ``x["NAME"] = ...``
+   store, an env-dict key, or ``NAME=`` in ``tools/ci_check.sh`` —
+   or is listed in ``DEPLOYMENT_KNOBS`` (addresses, ports, paths,
+   pod identity).  An option nothing sets is a constant.
 """
 from __future__ import annotations
 
+import ast
 import os
 import re
 from typing import Dict, List, Set
 
-from ..astutil import dict_key_strings, string_constants
+from ..astutil import (
+    assigned_strings,
+    dict_key_strings,
+    string_constants,
+)
 from ..core import Context, Finding, Rule, register
 
 ENV_RE = re.compile(r"^NOMAD_TPU_[A-Z0-9_]+$")
 DOC_ENV_RE = re.compile(r"NOMAD_TPU_[A-Z0-9_]+")
+SHELL_SET_RE = re.compile(r"\b(NOMAD_TPU_[A-Z0-9_]+)=")
+# entry points outside the usage scan whose setters count (direction 5)
+SETTER_ONLY_FILES = ("chip_smoke.py", "__graft_entry__.py")
+SETTER_SHELL = "tools/ci_check.sh"
+
+
+def _knob(node: ast.AST) -> Set[str]:
+    if (
+        isinstance(node, ast.Constant)
+        and isinstance(node.value, str)
+        and ENV_RE.match(node.value)
+    ):
+        return {node.value}
+    return set()
+
+
+def setters(tree: ast.AST) -> Set[str]:
+    """Knob names a module sets: first argument of a ``setenv`` /
+    ``setdefault`` call, subscript of an assignment target, or key of
+    a dict literal."""
+    out: Set[str] = set()
+    for node in ast.walk(tree):
+        if (
+            isinstance(node, ast.Call)
+            and isinstance(node.func, ast.Attribute)
+            and node.func.attr in ("setenv", "setdefault")
+            and node.args
+        ):
+            out |= _knob(node.args[0])
+        elif isinstance(node, ast.Assign):
+            for t in node.targets:
+                if isinstance(t, ast.Subscript):
+                    out |= _knob(t.slice)
+        elif isinstance(node, ast.Dict):
+            for k in node.keys:
+                if k is not None:
+                    out |= _knob(k)
+    return out
 
 
 @register
@@ -48,8 +96,8 @@ class ConfigDriftRule(Rule):
     # rule the full file set
     cross_file = True
 
-    def _usage(self, ctx: Context) -> Dict[str, List]:
-        """knob -> [(path, line), ...] across the scan scope."""
+    def _files(self, ctx: Context) -> List[str]:
+        """The scan scope, the registry itself left out."""
         override = ctx.overrides.get("scan_files")
         if override is not None:
             files = list(override)
@@ -63,18 +111,35 @@ class ConfigDriftRule(Rule):
                     for fn in sorted(os.listdir(tests_dir))
                     if fn.endswith(".py")
                 )
-        envknobs = ctx.path("envknobs")
+        registry = (ctx.path("envknobs"), ctx.default_path("envknobs"))
+        return [p for p in files if p not in registry]
+
+    def _usage(self, ctx: Context) -> Dict[str, List]:
+        """knob -> [(path, line), ...] across the scan scope."""
         out: Dict[str, List] = {}
-        for path in files:
-            if path == envknobs or path == ctx.default_path(
-                "envknobs"
-            ):
-                continue  # the registry itself
+        for path in self._files(ctx):
             for value, line in string_constants(ctx.tree(path)):
                 if ENV_RE.match(value):
                     out.setdefault(value, []).append(
                         (path, line)
                     )
+        return out
+
+    def _set(self, ctx: Context) -> Set[str]:
+        """Knobs with a setter in the scan scope (plus, on the live
+        tree, the root entry points and ``tools/ci_check.sh``)."""
+        files = self._files(ctx)
+        out: Set[str] = set()
+        if "scan_files" not in ctx.overrides:
+            files += [
+                os.path.join(ctx.repo, fn)
+                for fn in SETTER_ONLY_FILES
+            ]
+            out |= set(SHELL_SET_RE.findall(
+                ctx.source(os.path.join(ctx.repo, SETTER_SHELL))
+            ))
+        for path in files:
+            out |= setters(ctx.tree(path))
         return out
 
     def check(self, ctx: Context) -> List[Finding]:
@@ -159,6 +224,19 @@ class ConfigDriftRule(Rule):
                         "read anywhere — dead registry row",
                     )
                 )
+        deployment = assigned_strings(
+            ctx.tree(envknobs_path), "DEPLOYMENT_KNOBS"
+        )
+        for knob in sorted(registered - deployment - self._set(ctx)):
+            findings.append(
+                Finding(
+                    self.name, envknobs_path, 0,
+                    f"env knob {knob} is never set by a test, smoke, "
+                    "bench.py or tools/ci_check.sh and is not in "
+                    "DEPLOYMENT_KNOBS — an option nothing sets is a "
+                    "constant: fold it",
+                )
+            )
         return findings
 
     @classmethod
