@@ -122,6 +122,11 @@ class EvalContext:
         # passthrough walks EVERY candidate) must deviate to the
         # serial path instead of answering from possibly-stale state
         self.speculative = speculative
+        # options whose fit BinPackIterator computed in this eval, by
+        # side (rank.FIT_COUNTERS); plain integers, flushed to the
+        # telemetry once an eval
+        self.fit_fast = 0
+        self.fit_full = 0
 
     def reset(self) -> None:
         """Called between placements (reference context.go:116 Reset)."""

@@ -33,6 +33,7 @@ from ..structs import (
     TaskGroup,
 )
 from .context import EvalContext
+from .rank import flush_fit_counts
 from .reconcile import (
     AllocReconciler,
     BLOCKED_EVAL_FAILED_PLACEMENTS,
@@ -204,7 +205,10 @@ class GenericScheduler:
         if self.job is not None and not self.job.stopped():
             self.stack.set_job(self.job)
 
-        self._compute_job_allocs()
+        try:
+            self._compute_job_allocs()
+        finally:
+            flush_fit_counts(self.ctx)
 
         delay_instead = (
             bool(self.followup_evals) and self.eval.wait_until == 0.0

@@ -21,12 +21,15 @@ from ..structs import (
     AllocatedSharedResources,
     AllocatedTaskResources,
     Allocation,
+    ComparableResources,
     Job,
     NetworkIndex,
     NetworkResource,
     Node,
     TaskGroup,
     allocs_fit,
+    exhausted_dimension,
+    node_usage_after_plan,
     remove_allocs,
     score_fit_binpack,
     score_fit_spread,
@@ -111,9 +114,37 @@ class StaticRankIterator:
         self.seen = 0
 
 
+# one count an option whose fit BinPackIterator computed, by how: from
+# the state's live sum of the node, or by the walk over the node's
+# proposed allocations.  Plain integers on the EvalContext a pick;
+# flush_fit_counts adds them to the telemetry once an eval
+FIT_COUNTERS = ("rank.fit_fast", "rank.fit_full")
+
+
+def flush_fit_counts(ctx: EvalContext) -> None:
+    """Add the context's fit counts to its state's telemetry (at most
+    one increment a side) and zero them."""
+    fast, full = ctx.fit_fast, ctx.fit_full
+    ctx.fit_fast = ctx.fit_full = 0
+    metrics = getattr(ctx.state, "metrics", None)
+    if metrics is None:
+        return
+    if fast:
+        metrics.incr(FIT_COUNTERS[0], fast)
+    if full:
+        metrics.incr(FIT_COUNTERS[1], full)
+
+
 class BinPackIterator:
     """Resource fitting + fitness scoring, with optional preemption
-    (reference rank.go:149)."""
+    (reference rank.go:149).
+
+    Where the network and device half of the check is vacuous — no
+    eviction pass, an ask of cpu, memory and disk alone, no port,
+    network or device on the node's live allocs or on what the plan
+    places there — an option's fit and score come from the state's live
+    sum of the node (``_fit_from_aggregate``); every other option takes
+    the walk over the node's proposed allocations."""
 
     def __init__(
         self,
@@ -129,6 +160,7 @@ class BinPackIterator:
         self.priority = priority
         self.job_ns_id: Tuple[str, str] = ("", "")
         self.task_group: Optional[TaskGroup] = None
+        self._plain_ask: Optional[Tuple[int, int, int]] = None
         self.score_fit = (
             score_fit_spread
             if algorithm == SCHEDULER_ALGORITHM_SPREAD
@@ -141,12 +173,60 @@ class BinPackIterator:
 
     def set_task_group(self, tg: TaskGroup) -> None:
         self.task_group = tg
+        self._plain_ask = _plain_ask(tg)
+
+    def _fit_from_aggregate(self, option: RankedNode) -> Optional[bool]:
+        """The walk's verdict, ``util``, score and task resources for an
+        ask of cpu, memory and disk alone, from the state's live sum of
+        the node (``node_usage_after_plan``).  None — take the walk —
+        where the state or the plan offers no such sum for the node."""
+        ctx, node = self.ctx, option.node
+        used = node_usage_after_plan(ctx.state, ctx.plan, node.id)
+        if used is None:
+            return None
+        for task in self.task_group.tasks:
+            option.set_task_resources(
+                task,
+                AllocatedTaskResources(
+                    cpu=task.resources.cpu,
+                    memory_mb=task.resources.memory_mb,
+                ),
+            )
+        ask = self._plain_ask
+        util = ComparableResources(
+            cpu=used[0] + ask[0],
+            memory_mb=used[1] + ask[1],
+            disk_mb=used[2] + ask[2],
+        )
+        # allocs_fit's verdict, in its order: the three dimensions,
+        # then what a network index holding the node alone can say
+        dim = exhausted_dimension(
+            node, util.cpu, util.memory_mb, util.disk_mb
+        )
+        if not dim and _node_overcommitted(node):
+            dim = "bandwidth exceeded"
+        if dim:
+            ctx.metrics.exhausted_node(node, dim)
+            return False
+        normalized = self.score_fit(node, util) / BINPACK_MAX_FIT_SCORE
+        option.scores.append(normalized)
+        ctx.metrics.score_node(node, "binpack", normalized)
+        return True
 
     def next(self) -> Optional[RankedNode]:
         while True:
             option = self.source.next()
             if option is None:
                 return None
+
+            if self._plain_ask is not None and not self.evict:
+                fit = self._fit_from_aggregate(option)
+                if fit is not None:
+                    self.ctx.fit_fast += 1
+                    if fit:
+                        return option
+                    continue
+            self.ctx.fit_full += 1
 
             tg = self.task_group
             proposed = option.proposed_allocs(self.ctx)
@@ -328,6 +408,29 @@ class BinPackIterator:
 
     def reset(self) -> None:
         self.source.reset()
+
+
+def _plain_ask(tg: TaskGroup) -> Optional[Tuple[int, int, int]]:
+    """The group's (cpu, memory_mb, disk_mb) where that is all it asks;
+    None where the group or a task asks a network or a device."""
+    if tg.networks:
+        return None
+    cpu = mem = 0
+    for task in tg.tasks:
+        resources = task.resources
+        if resources.networks or resources.devices:
+            return None
+        cpu += resources.cpu
+        mem += resources.memory_mb
+    return cpu, mem, tg.ephemeral_disk.size_mb
+
+
+def _node_overcommitted(node: Node) -> bool:
+    """``NetworkIndex.overcommitted`` of an index that holds the node
+    and no allocation's network: a function of the node alone."""
+    net_idx = NetworkIndex()
+    net_idx.set_node(node)
+    return net_idx.overcommitted()
 
 
 class JobAntiAffinityIterator:

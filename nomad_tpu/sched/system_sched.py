@@ -23,6 +23,7 @@ from ..structs import (
     PlanResult,
 )
 from .context import EvalContext
+from .rank import flush_fit_counts
 from .reconcile import (
     ALLOC_LOST,
     ALLOC_NODE_TAINTED,
@@ -139,7 +140,10 @@ class SystemScheduler:
         if self.job is not None and not self.job.stopped():
             self.stack.set_job(self.job)
 
-        self._compute_job_allocs()
+        try:
+            self._compute_job_allocs()
+        finally:
+            flush_fit_counts(self.ctx)
 
         if self.plan.is_no_op() and not self.eval.annotate_plan:
             return True

@@ -47,6 +47,8 @@ from ..structs import (
     Plan,
     PlanResult,
     allocs_fit,
+    exhausted_dimension,
+    node_usage_after_plan,
 )
 
 
@@ -233,7 +235,7 @@ def _node_verdict(
     if node.drain:
         return False, "node is draining", ""
 
-    fast = _fit_from_aggregate(store, plan, node, placed)
+    fast = _fit_from_aggregate(store, plan, node)
     if fast is not None:
         return fast[0], fast[1], _FIT_FAST
 
@@ -253,7 +255,7 @@ def _node_verdict(
 
 
 def _fit_from_aggregate(
-    store, plan: Plan, node: Node, placed: List[Allocation]
+    store, plan: Plan, node: Node
 ) -> Optional[Tuple[bool, str]]:
     """``allocs_fit`` of the walk below, from the store's live sum of
     the node: minus the stored live versions the plan stops, preempts
@@ -262,46 +264,13 @@ def _fit_from_aggregate(
     node (the store's count) and none placed carries a port, a network
     or a device.  What is left of that half is the collision among the
     node's own reserved ports, a function of the node alone."""
-    node_id = node.id
-    live = store.node_fit_usage(node_id)
-    if live is None:
+    used = node_usage_after_plan(store, plan, node.id)
+    if used is None:
         return None
-    cpu, mem, disk = live
-    adds: Dict[str, Optional[Tuple[int, int, int]]] = {}
-    for alloc in placed:
-        c, m, d, carries = alloc.fit_footprint()
-        if carries:
-            return None
-        # by id, the last one placed stands; a terminal one adds nothing
-        adds[alloc.id] = None if alloc.terminal_status() else (c, m, d)
-    gone = set(adds)
-    for alloc in plan.node_update.get(node_id, ()):
-        gone.add(alloc.id)
-    for alloc in plan.node_preemptions.get(node_id, ()):
-        gone.add(alloc.id)
-    for alloc_id in gone:
-        stored = store.alloc_by_id(alloc_id)
-        if (
-            stored is not None
-            and stored.node_id == node_id
-            and not stored.terminal_status()
-        ):
-            c, m, d, _ = stored.fit_footprint()
-            cpu -= c
-            mem -= m
-            disk -= d
-    for add in adds.values():
-        if add is not None:
-            cpu += add[0]
-            mem += add[1]
-            disk += add[2]
+    dim = exhausted_dimension(node, *used)
+    if dim:
+        return False, dim
     total, reserved = node.node_resources, node.reserved_resources
-    if total.cpu - reserved.cpu < cpu:
-        return False, "cpu"
-    if total.memory_mb - reserved.memory_mb < mem:
-        return False, "memory"
-    if total.disk_mb - reserved.disk_mb < disk:
-        return False, "disk"
     if (
         reserved.reserved_ports
         or any(net.reserved_ports for net in total.networks)

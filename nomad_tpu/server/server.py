@@ -196,6 +196,11 @@ class Server:
         # the store counts which side each alloc write's usage took,
         # and carries the registry to the plan's per-node fit
         self.store.attach_metrics(self.metrics)
+        # the scheduler's own fit check counts its sides there too:
+        # 0 must read "no option ranked", not "not exported"
+        from ..sched.rank import FIT_COUNTERS as RANK_FIT_COUNTERS
+
+        self.metrics.preregister(counters=RANK_FIT_COUNTERS)
         # placement explainability: zero-register the placement.*
         # counter/gauge families so dashboards see the whole reason
         # vocabulary from process start (absence-of-series must mean

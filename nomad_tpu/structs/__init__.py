@@ -4,6 +4,8 @@ from .funcs import (  # noqa: F401
     score_fit_spread,
     compute_free_percentage,
     allocs_fit,
+    node_usage_after_plan,
+    exhausted_dimension,
     filter_terminal_allocs,
     remove_allocs,
 )

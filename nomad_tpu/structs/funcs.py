@@ -91,6 +91,67 @@ def allocs_fit(
     return True, "", used
 
 
+def node_usage_after_plan(
+    state, plan, node_id: str
+) -> Optional[Tuple[int, int, int]]:
+    """(cpu, memory_mb, disk_mb) that ``allocs_fit`` would add up over
+    the node's proposed allocations — its live ones, less what the plan
+    stops, preempts or replaces, plus what it places there (by id, the
+    last one stands; a terminal one adds nothing) — from the state's
+    live sum of the node instead of a walk over them.  None — take the
+    walk — unless the network and device half of a fit is vacuous: the
+    state offers a sum for the node (``node_fit_usage``: no live alloc
+    there carries a port, a network or a device) and nothing placed
+    carries one."""
+    usage = getattr(state, "node_fit_usage", None)
+    live = usage(node_id) if usage is not None else None
+    if live is None:
+        return None
+    cpu, mem, disk = live
+    adds: Dict[str, Optional[Tuple[int, int, int]]] = {}
+    for alloc in plan.node_allocation.get(node_id, ()):
+        c, m, d, carries = alloc.fit_footprint()
+        if carries:
+            return None
+        adds[alloc.id] = None if alloc.terminal_status() else (c, m, d)
+    gone = set(adds)
+    for alloc in plan.node_update.get(node_id, ()):
+        gone.add(alloc.id)
+    for alloc in plan.node_preemptions.get(node_id, ()):
+        gone.add(alloc.id)
+    for alloc_id in gone:
+        stored = state.alloc_by_id(alloc_id)
+        if (
+            stored is not None
+            and stored.node_id == node_id
+            and not stored.terminal_status()
+        ):
+            c, m, d, _ = stored.fit_footprint()
+            cpu -= c
+            mem -= m
+            disk -= d
+    for add in adds.values():
+        if add is not None:
+            cpu += add[0]
+            mem += add[1]
+            disk += add[2]
+    return cpu, mem, disk
+
+
+def exhausted_dimension(node: Node, cpu: int, mem: int, disk: int) -> str:
+    """``allocs_fit``'s verdict on the three sums, in its order: the
+    first of "cpu", "memory", "disk" that node − reserved cannot hold,
+    or "" where all three fit."""
+    total, reserved = node.node_resources, node.reserved_resources
+    if total.cpu - reserved.cpu < cpu:
+        return "cpu"
+    if total.memory_mb - reserved.memory_mb < mem:
+        return "memory"
+    if total.disk_mb - reserved.disk_mb < disk:
+        return "disk"
+    return ""
+
+
 def compute_free_percentage(
     node: Node, util: ComparableResources
 ) -> Tuple[float, float]:

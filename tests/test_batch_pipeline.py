@@ -2433,6 +2433,87 @@ def test_adaptive_cap_inputs_exported_as_gauges():
         server.stop()
 
 
+def _served_wave(nodes, jobs):
+    """A wave of prescored evals through one served worker: what it
+    committed (ids aside), the rank.fit_* counters and how many
+    speculations committed."""
+    server = Server(num_schedulers=1, seed=34, batch_pipeline=True)
+    server.start()
+    try:
+        for node in nodes:
+            server.register_node(copy.deepcopy(node))
+        for job in jobs:
+            server.register_job(copy.deepcopy(job))
+        assert server.drain_to_idle(30)
+        committed = {
+            job.id: sorted(
+                (
+                    a.name, a.node_id, a.metrics.nodes_evaluated,
+                    tuple(sorted(a.metrics.scores.items())),
+                    tuple(
+                        (m.node_id, tuple(sorted(m.scores.items())),
+                         m.norm_score)
+                        for m in a.metrics.score_meta
+                    ),
+                    tuple(sorted(
+                        (name, tr.cpu, tr.memory_mb)
+                        for name, tr in a.allocated_resources.tasks.items()
+                    )),
+                )
+                for a in server.store.allocs_by_job("default", job.id)
+                if not a.terminal_status()
+            )
+            for job in jobs
+        }
+        worker = server.workers[0]
+        assert worker.prescored > 0 and worker.fallbacks == 0
+        counters = server.metrics.dump()["counters"]
+        return {
+            "committed": committed,
+            "fast": counters["rank.fit_fast"],
+            "full": counters["rank.fit_full"],
+            "speculated": worker.replay_speculative,
+        }
+    finally:
+        server.stop()
+
+
+def test_prescored_wave_commits_the_walks_answers_from_the_aggregate(
+    monkeypatch,
+):
+    """The winner's exact check reads the store's live aggregate on the
+    served path (`rank.fit_fast`, no `rank.fit_full`), and commits what
+    the same wave commits over snapshots that hide the aggregate and so
+    walk the node's allocations: the same nodes, scores, nodes
+    evaluated and task resources.  One job a datacenter: the wave's
+    speculations read no node another member writes, so what each
+    records does not depend on the order they ran in."""
+    from nomad_tpu.state.store import StateSnapshot
+
+    nodes, jobs = [], []
+    for dc in range(6):
+        for node in make_nodes(3, seed=34 + dc):
+            node.datacenter = f"dc{dc}"
+            node.computed_class = compute_node_class(node)
+            nodes.append(node)
+        job = make_jobs(1, seed=40 + dc)[0]
+        job.id = f"fit-wave-{dc}"
+        job.datacenters = [f"dc{dc}"]
+        job.task_groups[0].count = 2 + dc % 3
+        jobs.append(job)
+    placed = sum(j.task_groups[0].count for j in jobs)
+    fast = _served_wave(nodes, jobs)
+    assert sum(len(v) for v in fast["committed"].values()) == placed
+    assert fast["fast"] >= placed and fast["full"] == 0
+    assert fast["speculated"] > 0
+    # the schedulers' view only: the plan applier reads the store
+    monkeypatch.delattr(StateSnapshot, "node_fit_usage")
+    walked = _served_wave(nodes, jobs)
+    assert walked["fast"] == 0 and walked["full"] >= placed
+    assert walked["speculated"] > 0
+    assert fast["committed"] == walked["committed"]
+
+
 def test_deq_ts_is_bounded_and_popped_on_nack():
     """The dequeue-timestamp map must not leak: nacked evals pop their
     stamp, and the map sheds oldest-first past DEQ_TS_MAX even when

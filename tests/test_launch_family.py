@@ -164,9 +164,16 @@ def test_first_sighting_compiles_every_member(donate, monkeypatch):
         compiled = set(worker._compiled)
 
         # a burst wide enough for the 4- and 8-wide chunks and for
-        # chains past their first chunk: nothing is left to meet
+        # chains past their first chunk: nothing is left to meet.
+        # Staged behind the worker's pause (honored between gulps; a
+        # dequeue in flight times out in 0.1 s), so that the backlog is
+        # there whatever the registering thread's pace against the
+        # worker's
+        worker.set_pause(True)
+        time.sleep(0.3)
         for i in range(1, 40):
             server.register_job(_job(i))
+        worker.set_pause(False)
         assert server.drain_to_idle(120)
         _settle(worker)
         widths = {a[7].shape[0] for a, _k, _f in sightings}
