@@ -31,7 +31,26 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .score import MAX_SKIP, NO_NODE, SKIP_THRESHOLD, _fit_exponentials
+from .score import (
+    MAX_SKIP,
+    NO_NODE,
+    ScoreList,
+    earliest_best,
+    under_threshold,
+)
+
+
+# A float32 trace compares scores as pairs (score.py ScoreList) and
+# flags, in this bit of a pick's pull count, that the pair's `lo` half
+# chose the winner: the pick one float32 a score would have given to an
+# earlier node.  The float64 trace never sets it.
+PAIR_DECIDED = 1 << 30
+
+
+def split_pulls(pulls):
+    """(pull counts, `lo`-decided flags) of the `pulls` a kernel of
+    this module hands back; numpy or jax arrays."""
+    return pulls & (PAIR_DECIDED - 1), pulls >= PAIR_DECIDED
 
 
 def pow2_bucket(n: int, floor: int = 1) -> int:
@@ -276,9 +295,12 @@ def _walk(s_p, f_p, offset, limit, n_candidates):
     entirely in permuted space (no per-step gathers — the rotation is
     closed-form prefix arithmetic; see ops/score.py for the walk
     semantics being emulated).  `s_p`/`f_p` are score/feasibility in
-    permuted order.  Returns (win_pos, any_emitted, pulls) where
-    win_pos indexes the permuted arrays."""
-    n = s_p.shape[0]
+    permuted order; `s_p` is one array on the float64 trace and a pair
+    `(hi, lo)` on the float32 trace, compared `hi` first.  Returns
+    (win_pos, any_emitted, pulls, decided) where win_pos indexes the
+    permuted arrays and `decided` says that `lo` chose the winner
+    (score.py earliest_best; None on the float64 trace)."""
+    n = f_p.shape[0]
     # int32 throughout: under x64 a default arange is int64, which
     # would promote `pulls` and break the int32 offset scan carry
     pos = jnp.arange(n, dtype=jnp.int32)
@@ -299,7 +321,7 @@ def _walk(s_p, f_p, offset, limit, n_candidates):
             _rotated_prefix(cs, c_off, total, in_wrap, is_tail), total
         )
 
-    bad = f_p & (s_p <= SKIP_THRESHOLD)
+    bad = f_p & under_threshold(s_p)
     bad_rank, _ = rot(bad)
     diverted = bad & (bad_rank <= MAX_SKIP)
     nd = f_p & ~diverted
@@ -318,14 +340,7 @@ def _walk(s_p, f_p, offset, limit, n_candidates):
     emit_order = jnp.where(nd, nd_incl - 1, nd_count + div_order)
     emitted = f_p & (emit_order < limit)
 
-    neg_inf = jnp.asarray(-jnp.inf, dtype=s_p.dtype)
-    masked = jnp.where(emitted, s_p, neg_inf)
-    best = jnp.max(masked)
-    candidates = emitted & (masked == best)
-    order_key = jnp.where(
-        candidates, emit_order, jnp.asarray(2**31 - 1, jnp.int32)
-    )
-    win = jnp.argmin(order_key)
+    win, _best, decided = earliest_best(s_p, emitted, emit_order)
     any_emitted = jnp.any(emitted)
 
     limit_reached = nd_count >= limit
@@ -334,7 +349,7 @@ def _walk(s_p, f_p, offset, limit, n_candidates):
         jnp.where(nd & (nd_incl == limit), wp, big)
     )
     pulls = jnp.where(limit_reached, lth_wp + 1, n_candidates)
-    return win, any_emitted, pulls
+    return win, any_emitted, pulls, decided
 
 
 def _run_picks(
@@ -365,7 +380,9 @@ def _run_picks(
                  # the picking group's own allocs (feasible.py
                  # _satisfies: job_collision AND task_collision)
 ):
-    """Inner pick scan; returns (rows i32[P], final used columns).
+    """Inner pick scan; returns (rows i32[P], final used columns,
+    pulls i32[P] — on a float32 trace with the PAIR_DECIDED flags,
+    see split_pulls).
 
     All per-pick state lives in PERMUTED space: every input column is
     gathered through `inp.perm` exactly once up front, and each scan
@@ -558,42 +575,24 @@ def _run_picks(
                     axis=0,
                 )
 
-            # canonical f32-rounded exponentials (structs/funcs.py _pow10)
-            base = _fit_exponentials(
-                cpu_after, safe_cpu, mem_after, safe_mem, dtype
+            # the score list from the canonical f32-rounded exponentials
+            # (structs/funcs.py _pow10) on: an array at float64, a
+            # pair of float32s on the float32 trace (score.py ScoreList)
+            scores = ScoreList(
+                cpu_after, safe_cpu, mem_after, safe_mem, coll_t,
+                tg.desired_count, spread_fit, dtype, pick=pick_idx,
             )
-            if spread_fit:
-                fitness = jnp.clip(base - 2.0, 0.0, 18.0)
-            else:
-                fitness = jnp.clip(20.0 - base, 0.0, 18.0)
-            score_sum = fitness / 18.0
-            count = jnp.ones_like(score_sum)
-
-            has_coll = coll_t > 0
-            anti = jnp.where(
-                has_coll,
-                -(coll_t.astype(dtype) + 1.0)
-                / tg.desired_count[pick_idx].astype(dtype),
-                0.0,
-            )
-            score_sum = score_sum + anti
-            count = count + has_coll.astype(dtype)
-            score_sum = score_sum - penalty_vec.astype(dtype)
-            count = count + penalty_vec.astype(dtype)
+            scores.penalty(penalty_vec)
             aff_k = aff_tp[t]
             has_aff = aff_k != 0.0
-            score_sum = score_sum + jnp.where(has_aff, aff_k, 0.0)
-            count = count + has_aff.astype(dtype)
+            scores.append(jnp.where(has_aff, aff_k, 0.0), has_aff)
             if dev_aff is not None:
                 # device-affinity match fraction (rank.go:460): appended
                 # for EVERY scored node when the ask carries affinities
                 # with non-zero total weight — even a 0.0 value enters
                 # the mean, unlike the node-affinity component
                 d_on = dev_aff_on[t]
-                score_sum = score_sum + jnp.where(
-                    d_on, dev_aff_p[t], 0.0
-                )
-                count = count + d_on.astype(dtype)
+                scores.append(jnp.where(d_on, dev_aff_p[t], 0.0), d_on)
         with jax.named_scope("spread"):
             if spread is not None:
                 # boost per stanza: ((desired - (used+1)) / desired) * w,
@@ -610,14 +609,12 @@ def _run_picks(
                     spread_existing, spread_prop, spread_clr,
                     spread.weight, slot_active, spread.even, dtype,
                 )
-                has_spread = spread_total != 0.0
-                score_sum = score_sum + spread_total
-                count = count + has_spread.astype(dtype)
+                scores.append(spread_total, spread_total != 0.0)
         with jax.named_scope("score"):
-            final = score_sum / count
+            final = scores.mean()
 
         with jax.named_scope("walk"):
-            win, any_emitted, step_pulls = _walk(
+            win, any_emitted, step_pulls, decided = _walk(
                 final, feasible, offset, tg.limit[pick_idx], n_candidates
             )
         with jax.named_scope("usage_update"):
@@ -668,6 +665,8 @@ def _run_picks(
                     slot_ok, onehot_p[:, safe_win, :], 0.0
                 )
                 out["spread_clr"] = spread_clr
+            if decided is not None:
+                pulls = pulls + jnp.where(ok & decided, PAIR_DECIDED, 0)
         return out, (row, app, pulls)
 
     carry0 = {
@@ -803,6 +802,8 @@ def plan_picks_full(
         n_picks,
         spread_fit,
     )
+    if cpu_total.dtype == jnp.float32:
+        pulls, _decided = split_pulls(pulls)
     return jnp.stack([rows.astype(jnp.int32), pulls.astype(jnp.int32)])
 
 
@@ -1062,7 +1063,8 @@ def chained_plan_picks_cols(
     # pulls[E, P]: source-iterator consumption per pick — the host
     # reconstructs the sequential walk offset at any pick from the
     # running sum (preemption-retry passthrough seeds the oracle's
-    # StaticIterator offset with it)
+    # StaticIterator offset with it).  On a float32 trace a pick's
+    # entry also carries the PAIR_DECIDED flag: split_pulls
     if return_carry:
         return rows, pulls, final
     return rows, pulls

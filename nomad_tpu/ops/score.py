@@ -12,9 +12,13 @@ selected node is bit-identical to what the pull-based iterator chain
 would have chosen while doing O(N) vector math instead of O(limit) pointer
 chasing.
 
-Score-append semantics are reproduced as a (sum, count) pair: each term
-contributes to the sum and increments the count only under the reference's
-append conditions; the final score is sum/count.
+Score-append semantics are reproduced as a running sum and a count
+(``ScoreList``): each term contributes to the sum and increments the
+count only under the reference's append conditions; the final score is
+sum/count.  On the float32 trace the sum is two float32s ``(hi, lo)``
+from the fitness exponentials to the mean, and the walk's maximum
+compares ``hi`` first and ``lo`` second (``earliest_best``), so the
+winner is the float64 definition's.
 
 Shapes are fixed to the node arena capacity so jit traces cache across
 cluster churn; vacant rows are masked.
@@ -84,11 +88,13 @@ def _fit_exponentials(cpu_after, cpu_cap, mem_after, mem_cap, dtype):
     """``10^freeCpu + 10^freeMem`` with each free share ``1 - after /
     cap`` and each exponential the float64 definition's, rounded once
     to float32 (structs/funcs.py _pow10).  The float64 trace computes
-    them as it always has; the float32 trace (``jax_enable_x64`` off,
-    the dtype a chip deploys) carries share and exponential as two
-    float32s (ops/twofloat.py), so the float32 it scores with is the
-    same number, bit for bit, on the CPU and on the TPU — which
-    float32 division and pow are not."""
+    them as it always has and returns the sum as an array; the float32
+    trace (``jax_enable_x64`` off, the dtype a chip deploys) carries
+    share and exponential as two float32s (ops/twofloat.py), so each
+    float32 exponential is the same number, bit for bit, on the CPU and
+    on the TPU — which float32 division and pow are not — and returns
+    their EXACT sum as a pair ``(hi, lo)``: float64 holds the sum of two
+    float32s exactly, one float32 does not (``hi`` is the float32 sum)."""
     if dtype == jnp.float32:
         # both resources through one trace of the arithmetic: a launch
         # shape's compile time is in its count of operations
@@ -98,10 +104,158 @@ def _fit_exponentials(cpu_after, cpu_cap, mem_after, mem_cap, dtype):
         p = twofloat.pow10_free(
             jnp.stack([cpu_after, mem_after]), jnp.stack([cpu_cap, mem_cap])
         )
-        return p[0] + p[1]
+        return twofloat.exact_sum(p[0], p[1])
     free_cpu = 1.0 - cpu_after / cpu_cap
     free_mem = 1.0 - mem_after / mem_cap
     return _pow10(free_cpu, dtype) + _pow10(free_mem, dtype)
+
+
+def pair_hi(x):
+    """The working-dtype array of a score the kernels carry: a float32
+    trace's pair gives its ``hi``, the float64 trace's array itself."""
+    return x[0] if isinstance(x, tuple) else x
+
+
+class ScoreList:
+    """The score list of a pick for every candidate at once: the
+    running sum of the appended terms and their count (rank.go's
+    ``Scores`` and its mean, rank.go:706).  It opens with the two terms
+    every job has, bin-packing or spread fitness (funcs.go:175, :202)
+    and job anti-affinity (rank.go:527: ``-(collisions + 1) /
+    desired_count`` where the node holds allocations of the job's group
+    already; ``desired_count`` is the group's count, or with ``pick``
+    the counts of a scan's picks and the index of this one).  The
+    reschedule penalty (rank.go:573) has its own method, every other
+    term goes through ``append``.
+
+    The float64 trace holds the sum as one array.  The float32 trace
+    holds it as a pair ``(hi, lo)`` (ops/twofloat.py) from the
+    exponentials to ``mean``: ``20 - sum`` exactly, each quotient and
+    sum to about 2^-46, so that two candidates whose float64 scores
+    differ order as float64 orders them where one float32 would tie
+    them.  A term that is not a pair yet (node affinity, spread boost,
+    device affinity, policy terms) enters ``append`` as ``(value, 0)``."""
+
+    def __init__(
+        self, cpu_after, cpu_cap, mem_after, mem_cap, collisions,
+        desired_count, spread_fit: bool, dtype, pick=None,
+    ):
+        self.dtype = dtype
+        self.paired = dtype == jnp.float32
+        base = _fit_exponentials(
+            cpu_after, cpu_cap, mem_after, mem_cap, dtype
+        )
+        if self.paired:
+            fitness = twofloat.clip(
+                twofloat.add_f(base, -2.0)
+                if spread_fit
+                else twofloat.add_f(twofloat.neg(base), 20.0),
+                0.0, 18.0,
+            )
+            has_collision = collisions > 0
+            if pick is not None:
+                desired_count = desired_count[pick]
+            # fitness / 18 and -(collisions + 1) / count through one
+            # trace of the quotient (a launch shape's compile time is
+            # in its count of operations)
+            fit_hi, fit_lo, above, by_18, by_count = jnp.broadcast_arrays(
+                fitness[0], fitness[1], -(collisions.astype(dtype) + 1.0),
+                jnp.asarray(18.0, dtype), desired_count.astype(dtype),
+            )
+            q_hi, q_lo = twofloat.quotient(
+                (
+                    jnp.stack([fit_hi, above]),
+                    jnp.stack([fit_lo, jnp.zeros_like(fit_lo)]),
+                ),
+                jnp.stack([by_18, by_count]),
+            )
+            self.sum = twofloat.add(
+                (q_hi[0], q_lo[0]),
+                (
+                    jnp.where(has_collision, q_hi[1], 0.0),
+                    jnp.where(has_collision, q_lo[1], 0.0),
+                ),
+            )
+            self.count = jnp.ones_like(fit_hi)
+        else:
+            # operation for operation what the kernels always traced:
+            # the float64 trace's lowered text is held to it
+            # (tests/test_float32_scoring.py)
+            if spread_fit:
+                fitness = jnp.clip(base - 2.0, 0.0, 18.0)
+            else:
+                fitness = jnp.clip(20.0 - base, 0.0, 18.0)
+            self.sum = fitness / 18.0
+            self.count = jnp.ones_like(self.sum)
+            has_collision = collisions > 0
+            above = -(collisions.astype(dtype) + 1.0)
+            if pick is not None:
+                desired_count = desired_count[pick]
+            self.sum = self.sum + jnp.where(
+                has_collision, above / desired_count.astype(dtype), 0.0
+            )
+        self.count = self.count + has_collision.astype(dtype)
+
+    def penalty(self, penalized) -> None:
+        """-1 on a node the allocation is being rescheduled away from."""
+        if self.paired:
+            self.sum = twofloat.add_f(
+                self.sum, -penalized.astype(self.dtype)
+            )
+        else:
+            self.sum = self.sum - penalized.astype(self.dtype)
+        self.count = self.count + penalized.astype(self.dtype)
+
+    def append(self, term, present=None) -> None:
+        """Add ``term`` (0 where it does not append) to the sum and
+        ``present`` (a mask, or a 0/1 flag in the working dtype; by
+        default where ``term`` is not 0) to the count."""
+        if self.paired:
+            self.sum = twofloat.add_f(self.sum, term)
+        else:
+            self.sum = self.sum + term
+        if present is None:
+            present = term != 0.0
+        if present.dtype == jnp.bool_:
+            present = present.astype(self.count.dtype)
+        self.count = self.count + present
+
+    def mean(self):
+        """sum / count: a pair at float32, an array at float64."""
+        if self.paired:
+            return twofloat.quotient(self.sum, self.count)
+        return self.sum / self.count
+
+
+def under_threshold(s):
+    """The walk's skip test (select.go:52), ``score <= 0``."""
+    if isinstance(s, tuple):
+        return twofloat.at_most(s, SKIP_THRESHOLD)
+    return s <= SKIP_THRESHOLD
+
+
+def earliest_best(s, emitted, emit_order):
+    """MaxScoreIterator over the emitted candidates (select.go:94-113):
+    the strict maximum, the earliest emitted of equal scores.  A pair
+    compares by ``hi``, then by ``lo``.  Returns (position of the
+    winner, its score as one float, whether ``lo`` decided: two or
+    more emitted candidates shared the winner's ``hi`` and differed in
+    ``lo`` — None on the float64 trace, which has no ``lo``)."""
+    hi = pair_hi(s)
+    neg_inf = jnp.asarray(-jnp.inf, dtype=hi.dtype)
+    masked = jnp.where(emitted, hi, neg_inf)
+    best = jnp.max(masked)
+    candidates = emitted & (masked == best)
+    decided = None
+    if isinstance(s, tuple):
+        at_hi = candidates
+        masked_lo = jnp.where(at_hi, s[1], neg_inf)
+        candidates = at_hi & (masked_lo == jnp.max(masked_lo))
+        decided = jnp.any(at_hi & ~candidates)
+    order_key = jnp.where(
+        candidates, emit_order, jnp.asarray(2**31 - 1, jnp.int32)
+    )
+    return jnp.argmin(order_key), best, decided
 
 
 class ScoreInputs(NamedTuple):
@@ -137,8 +291,9 @@ class ScoreInputs(NamedTuple):
     policy: Optional[PolicyTerms] = None
 
 
-def _score_vectors(inp: ScoreInputs, spread_fit: bool):
-    """Returns (feasible_after_fit bool[C], final_scores f[C])."""
+def _score_terms(inp: ScoreInputs, spread_fit: bool):
+    """Returns (feasible_after_fit bool[C], final scores: f[C] on the
+    float64 trace, a pair of f[C] on the float32 trace)."""
     dtype = inp.cpu_total.dtype
     cpu_after = inp.cpu_used + inp.ask_cpu
     mem_after = inp.mem_used + inp.ask_mem
@@ -154,39 +309,19 @@ def _score_vectors(inp: ScoreInputs, spread_fit: bool):
     safe_cpu_total = jnp.where(inp.cpu_total > 0, inp.cpu_total, 1.0)
     safe_mem_total = jnp.where(inp.mem_total > 0, inp.mem_total, 1.0)
     # the fitness exponential is DEFINED at float32 precision
-    # (structs/funcs.py _pow10); the sum continues in the working dtype
-    base = _fit_exponentials(
-        cpu_after, safe_cpu_total, mem_after, safe_mem_total, dtype
+    # (structs/funcs.py _pow10) and everything after it at float64: an
+    # array there, a pair on the float32 trace (ScoreList)
+    scores = ScoreList(
+        cpu_after, safe_cpu_total, mem_after, safe_mem_total,
+        inp.collisions, inp.desired_count, spread_fit, dtype,
     )
-    if spread_fit:
-        fitness = jnp.clip(base - 2.0, 0.0, 18.0)
-    else:
-        fitness = jnp.clip(20.0 - base, 0.0, 18.0)
-    binpack = fitness / 18.0
-
-    score_sum = binpack
-    count = jnp.ones_like(binpack)
-
-    has_collision = inp.collisions > 0
-    anti = jnp.where(
-        has_collision,
-        -(inp.collisions.astype(dtype) + 1.0)
-        / inp.desired_count.astype(dtype),
-        0.0,
-    )
-    score_sum = score_sum + anti
-    count = count + has_collision.astype(dtype)
-
-    score_sum = score_sum - inp.penalty.astype(dtype)
-    count = count + inp.penalty.astype(dtype)
+    scores.penalty(inp.penalty)
 
     has_aff = inp.affinity_score != 0.0
-    score_sum = score_sum + jnp.where(has_aff, inp.affinity_score, 0.0)
-    count = count + has_aff.astype(dtype)
+    scores.append(jnp.where(has_aff, inp.affinity_score, 0.0), has_aff)
 
     has_spread = inp.spread_boost != 0.0
-    score_sum = score_sum + jnp.where(has_spread, inp.spread_boost, 0.0)
-    count = count + has_spread.astype(dtype)
+    scores.append(jnp.where(has_spread, inp.spread_boost, 0.0), has_spread)
 
     # policy-weighted terms append LAST so the serial oracle's
     # left-to-right float-sum order is preserved (PolicyIterator sits
@@ -201,14 +336,19 @@ def _score_vectors(inp: ScoreInputs, spread_fit: bool):
         # score_sum is never -0.0, and np.zeros stages +0.0), so only
         # the count needs a flag/predicate
         if pol.tput_term is not None:
-            score_sum = score_sum + pol.tput_term
-            count = count + pol.has_tput
+            scores.append(pol.tput_term, pol.has_tput)
         if pol.mig_term is not None:
-            score_sum = score_sum + pol.mig_term
-            count = count + (pol.mig_term != 0.0).astype(dtype)
+            scores.append(pol.mig_term)
 
-    final = score_sum / count
-    return feasible, final
+    return feasible, scores.mean()
+
+
+def _score_vectors(inp: ScoreInputs, spread_fit: bool):
+    """Returns (feasible_after_fit bool[C], final_scores f[C]): the
+    storm solver and the sharded select read one float a candidate
+    (a float32 pair's ``hi``)."""
+    feasible, final = _score_terms(inp, spread_fit)
+    return feasible, pair_hi(final)
 
 
 def _limited_walk_argmax(
@@ -241,10 +381,13 @@ def _limited_walk_argmax(
     Infeasible nodes consume pulls (they are filtered mid-chain), which
     is exactly how the reference's rotation advances.
     """
-    s = scores[perm]
+    if isinstance(scores, tuple):
+        s = (scores[0][perm], scores[1][perm])
+    else:
+        s = scores[perm]
     f = feasible[perm]
 
-    bad = f & (s <= SKIP_THRESHOLD)
+    bad = f & under_threshold(s)
     bad_rank = jnp.cumsum(bad.astype(jnp.int32))
     diverted = bad & (bad_rank <= MAX_SKIP)
     nd = f & ~diverted
@@ -263,14 +406,7 @@ def _limited_walk_argmax(
     emit_order = jnp.where(nd, nd_rank, nd_count + div_order)
     emitted = f & (emit_order < limit)
 
-    neg_inf = jnp.asarray(-jnp.inf, dtype=s.dtype)
-    masked = jnp.where(emitted, s, neg_inf)
-    best = jnp.max(masked)
-    candidates = emitted & (masked == best)
-    order_key = jnp.where(
-        candidates, emit_order, jnp.asarray(2**31 - 1, jnp.int32)
-    )
-    win_pos = jnp.argmin(order_key)
+    win_pos, best, _decided = earliest_best(s, emitted, emit_order)
     chosen_row = perm[win_pos]
     any_emitted = jnp.any(emitted)
     chosen_row = jnp.where(any_emitted, chosen_row, NO_NODE)
@@ -285,7 +421,7 @@ def _limited_walk_argmax(
 def score_and_select(inp: ScoreInputs, spread_fit: bool = False):
     """Returns (chosen_row, chosen_score, feasible_count, pulls).
     chosen_row == -1 when no feasible node was emitted."""
-    feasible, final = _score_vectors(inp, spread_fit)
+    feasible, final = _score_terms(inp, spread_fit)
     chosen_row, best, feasible_count, pulls = _limited_walk_argmax(
         feasible, final, inp.perm, inp.limit, inp.n_candidates
     )

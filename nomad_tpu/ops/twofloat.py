@@ -7,7 +7,13 @@ call ``pow`` for that: the TPU's float32 ``pow`` is off the definition
 by up to 5e-6 (relative) and its division by 1e-7, enough to reorder
 near-tied candidates.  So the float32 trace carries the free share and
 the exponential as an unevaluated sum ``hi + lo`` of two float32 arrays
-(about 48 significant bits) and rounds once at the end.
+(about 48 significant bits) and rounds once at the end.  The rest of a
+score — the sum of the two exponentials, ``20 - sum``, ``/ 18``, the
+anti-affinity and penalty terms and the mean — is defined in float64
+too, so it stays a pair all the way to the walk's maximum
+(ops/score.py ``ScoreList``): the pair operations below the
+exponential (``exact_sum``, ``add``, ``add_f``, ``quotient``, ``clip``,
+``at_most``) are the ones that tail needs.
 
 Only float32 ``+``, ``-`` and ``*`` carry the result, and they are
 correctly rounded on the CPU and on the TPU alike.  Every product whose
@@ -68,12 +74,12 @@ def _two_prod(a, b) -> Pair:
     return _fast_two_sum(top, top_e + (mid_e + al * bl))
 
 
-def _add(x: Pair, y: Pair) -> Pair:
+def add(x: Pair, y: Pair) -> Pair:
     s, e = _two_sum(x[0], y[0])
     return _fast_two_sum(s, e + (x[1] + y[1]))
 
 
-def _add_f(x: Pair, b) -> Pair:
+def add_f(x: Pair, b) -> Pair:
     s, e = _two_sum(x[0], b)
     return _fast_two_sum(s, e + x[1])
 
@@ -83,19 +89,51 @@ def _mul(x: Pair, y: Pair) -> Pair:
     return _fast_two_sum(p, e + (x[0] * y[1] + x[1] * y[0]))
 
 
+def exact_sum(a, b) -> Pair:
+    """``a + b`` of two float32 arrays as a pair, exactly."""
+    return _two_sum(a, b)
+
+
+def neg(x: Pair) -> Pair:
+    return -x[0], -x[1]
+
+
+def quotient(x: Pair, d) -> Pair:
+    """``x / d`` for a float32 ``d`` that holds a whole number other
+    than 0, to about 2^-47: the backend's quotient, then two
+    corrections by the quotient of the exact remainder.  A quotient
+    that a pair holds exactly comes out exact, and ``d == 1`` hands
+    ``x`` back."""
+    q = x[0] / d
+    out = (q, jnp.zeros_like(q))
+    rem = x
+    for _ in range(2):
+        p, e = _two_prod(q, d)
+        rem = add(rem, (-p, -e))
+        q = rem[0] / d
+        out = add_f(out, q)
+    return out
+
+
+def clip(x: Pair, low: float, high: float) -> Pair:
+    """``x`` held to [low, high], two float32 constants."""
+    over = (x[0] > high) | ((x[0] == high) & (x[1] > 0.0))
+    under = (x[0] < low) | ((x[0] == low) & (x[1] < 0.0))
+    hi = jnp.where(over, F32(high), jnp.where(under, F32(low), x[0]))
+    return hi, jnp.where(over | under, F32(0.0), x[1])
+
+
+def at_most(x: Pair, bound: float):
+    """``x <= bound`` for a float32 constant: ``hi`` decides, and
+    ``lo``'s sign where ``hi`` sits on the bound."""
+    return (x[0] < bound) | ((x[0] == bound) & (x[1] <= 0.0))
+
+
 def free_share(after, cap) -> Pair:
     """``1 - after / cap`` for float32 columns of whole numbers
-    (``cap`` > 0), to about 2^-47: the backend's quotient, then two
-    corrections by the quotient of the exact remainder."""
-    q = after / cap
-    out = (q, jnp.zeros_like(q))
-    rem = (after, jnp.zeros_like(after))
-    for _ in range(2):
-        p, e = _two_prod(q, cap)
-        rem = _add(rem, (-p, -e))
-        q = rem[0] / cap
-        out = _add_f(out, q)
-    return _add_f((-out[0], -out[1]), F32(1.0))
+    (``cap`` > 0), to about 2^-47 (``quotient``)."""
+    q = quotient((after, jnp.zeros_like(after)), cap)
+    return add_f(neg(q), F32(1.0))
 
 
 # log10(2) in three parts, the first two of 12 bits each, so that
@@ -120,14 +158,14 @@ def pow10(x: Pair) -> Pair:
     of two float32 neighbours."""
     n = jnp.clip(jnp.round(x[0] * _LOG2_10), -126.0, 127.0)
     # r = x - n * log10(2): 10^x = 2^n * 10^r, |r| <= log10(2) / 2
-    r = _add_f(_add_f(_add_f(x, -n * _L1), -n * _L2), -n * _L3)
+    r = add_f(add_f(add_f(x, -n * _L1), -n * _L2), -n * _L3)
     t = _mul(r, _LN10)  # 10^r = e^t
     tail = jnp.full_like(t[0], F32(_INV_FACT[_LAST_TERM]))
     for k in range(_LAST_TERM - 1, _PAIR_TERMS - 1, -1):
         tail = tail * t[0] + F32(_INV_FACT[k])
     p = (tail, jnp.zeros_like(tail))
     for k in range(_PAIR_TERMS - 1, -1, -1):
-        p = _add(_mul(p, t), _const(_INV_FACT[k]))
+        p = add(_mul(p, t), _const(_INV_FACT[k]))
     # times 2^n, exact: the exponent field written directly
     scale = jax.lax.bitcast_convert_type(
         (n.astype(jnp.int32) + 127) << 23, jnp.float32

@@ -38,6 +38,7 @@ from ..ops.score import (
     _fit_exponentials,
     _limited_walk_argmax,
     _score_vectors,
+    pair_hi,
 )
 
 
@@ -634,9 +635,11 @@ def sharded_chained_plan(mesh: Mesh, n_picks: int,
                 # distinct_hosts via the collision carry, as in the
                 # unsharded kernel
                 feas = feas_l & fit & ~(dh & (coll_c > 0))
-                base = _fit_exponentials(
+                # one float a score here: a float32 trace's pair
+                # gives its hi, the float32 sum
+                base = pair_hi(_fit_exponentials(
                     cpu_after, safe_cpu, mem_after, safe_mem, dtype
-                )
+                ))
                 if spread_fit:
                     fitness = jnp.clip(base - 2.0, 0.0, 18.0)
                 else:

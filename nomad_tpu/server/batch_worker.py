@@ -70,6 +70,7 @@ from ..ops.batch import (
     chained_plan_picks_cols_donated,
     patch_rows,
     pow2_bucket as _pow2,
+    split_pulls,
 )
 from ..ops.constraints import MaskCompiler
 from ..sched.feasible import shuffle_permutation
@@ -132,6 +133,12 @@ WALK_COUNTERS = (
     "batch_worker.walk_pulls",
     "batch_worker.walk_picks",
 )
+# beside them: the picks of a float32 trace in which two or more
+# emitted candidates shared the winner's `hi` and differed in `lo`
+# (ops/score.py earliest_best) — the picks one float32 a score would
+# have given to the earlier node.  It rides in the same pulls (a flag
+# bit a pick, ops/batch.py split_pulls); a float64 trace reads 0
+PAIR_COUNTER = "batch_worker.pair_decided_picks"
 # widest chunk bucket, kept under its historical name: the assembly
 # arena, warm_shapes and the mesh path still use it as the default
 # eval-axis alignment
@@ -2302,7 +2309,9 @@ class BatchWorker(Worker):
                         c1 - c0, (launch_dt + dt) * 1000.0,
                         mesh=casm.use_mesh,
                     )
-                    walk_pulls = walk_picks = 0
+                    pulls_arr, lo_decided = split_pulls(pulls_arr)
+                    any_decided = lo_decided.any()
+                    walk_pulls = walk_picks = pair_decided = 0
                     for e in range(c0, min(c1, casm.E_real)):
                         if rescore:
                             break
@@ -2322,6 +2331,10 @@ class BatchWorker(Worker):
                         ]
                         walk_pulls += sum(pulls)
                         walk_picks += len(pulls)
+                        if any_decided:
+                            pair_decided += int(
+                                lo_decided[e - c0, : sim.placements].sum()
+                            )
                         if wave is not None:
                             wave.append((
                                 ev, token, job, sim, rows, pulls,
@@ -2349,6 +2362,8 @@ class BatchWorker(Worker):
                             metrics.incr(
                                 "batch_worker.walk_picks", walk_picks
                             )
+                            if pair_decided:
+                                metrics.incr(PAIR_COUNTER, pair_decided)
                     if wave is not None and wave and not rescore:
                         # continuous commit: drain the READY prefix of
                         # the wave in order, so these evals ack now —

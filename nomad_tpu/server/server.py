@@ -392,6 +392,7 @@ class Server:
             # flag (NOMAD_TPU_ADMIT=0 restores flush-boundary gulps)
             from .batch_worker import (
                 ADMISSION_COUNTERS,
+                PAIR_COUNTER,
                 SHIELD_COUNTERS,
                 WALK_COUNTERS,
             )
@@ -400,7 +401,9 @@ class Server:
             # cold-compile shield: 0 must read "no cold shape met"
             self.metrics.preregister(counters=SHIELD_COUNTERS)
             # the limit walk: 0 must read "no prescored pick fetched"
-            self.metrics.preregister(counters=WALK_COUNTERS)
+            self.metrics.preregister(
+                counters=WALK_COUNTERS + (PAIR_COUNTER,)
+            )
             # sharded hot path: zero-register the mesh.* family the
             # same way (absence-of-series must mean "mesh never
             # engaged" — NOMAD_TPU_MESH off or a single-device host —

@@ -6,7 +6,12 @@ one of the benchmark's job shapes.  Prints one JSON line: how the
 chained kernel's placements compare with the sequential scheduler's
 (no JAX in it) and with ``benchmark/reference.py`` at float64.
 
-    python tests/_float32_parity_child.py <config> <nodes> <jobs> <seed>
+    python tests/_float32_parity_child.py <config> <nodes> <jobs> <seed> [planted]
+
+With ``planted`` the fleet is one near-tie of the configuration's score
+lattice (tests/_near_ties.py), the seed's choice of them: half the
+nodes a candidate, half its neighbour whose float64 score is a hair
+better and which one float32 a score cannot tell from it.
 """
 import json
 import os
@@ -21,7 +26,9 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 import jax  # noqa: E402
+import numpy as np  # noqa: E402
 
+import _near_ties as near  # noqa: E402
 from benchmark import correct, system  # noqa: E402
 from benchmark.manifest import Manifest  # noqa: E402
 from benchmark.stream import JobStream  # noqa: E402
@@ -59,6 +66,15 @@ def main(argv):
     config["fleet"]["nodes"] = nodes
     config["fleet"]["resident_allocs"] = nodes * 10
     world = make_world(config, seed)
+    if argv[4:] == ["planted"]:
+        worse, better, _gap = near.near_ties(config["fleet"])
+        res = config["job"]["task_groups"][0]["tasks"][0]["resources"]
+        ask = (res["cpu"], res["memory_mb"])
+        roomy = np.flatnonzero(
+            np.all(worse[:, [0, 2]] >= ask, 1) & np.all(better[:, [0, 2]] >= ask, 1)
+        )
+        k = roomy[np.random.default_rng(seed).integers(len(roomy))]
+        world = near.plant(world, config["fleet"], worse[k], better[k], ask)
     stream = JobStream(config, {"loop": "closed", "in_flight": 1}, seed)
     payloads = [stream.payload(i) for i in range(jobs)]
     kernel, prescored, counters = serve(world, payloads, seed, True)
@@ -77,6 +93,7 @@ def main(argv):
         "jobs_compared": numbers["jobs_compared"],
         "walk_pulls": counters.get("batch_worker.walk_pulls"),
         "walk_picks": counters.get("batch_worker.walk_picks"),
+        "pair_decided_picks": counters.get("batch_worker.pair_decided_picks"),
     }), flush=True)
 
 

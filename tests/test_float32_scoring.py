@@ -5,10 +5,16 @@ float32 (structs/funcs.py ``_pow10``).  With ``jax_enable_x64`` off —
 the dtype a chip deploys — the kernels carry the free share and the
 exponential as two float32s (ops/twofloat.py) and must land on that
 same float32; with it on, the float64 trace is what it has always been.
+Everything after the exponentials is defined in float64 as well, so the
+float32 trace carries the whole score of a candidate as a pair
+(ops/score.py ``ScoreList``) and the walk picks on the pair: two
+candidates whose float64 scores differ order as float64 orders them,
+where one float32 a score ties them and gives the pick to the earlier.
 The tier-1 session runs x64 on, so every case here that needs it off
 switches it off for its own scope (``jax.enable_x64(False)``, or a child
 process where a served pipeline's threads need it).
 """
+import functools
 import json
 import os
 import subprocess
@@ -19,9 +25,23 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import _near_ties as near
 from benchmark.manifest import Manifest, repo_root
 from nomad_tpu.ops import twofloat
-from nomad_tpu.ops.score import _fit_exponentials, _pow10
+from nomad_tpu.ops.batch import (
+    ChainInputs,
+    _rotated_prefix,
+    _walk,
+    chained_plan_picks_cols,
+    split_pulls,
+)
+from nomad_tpu.ops.score import (
+    MAX_SKIP,
+    SKIP_THRESHOLD,
+    ScoreList,
+    _fit_exponentials,
+    _pow10,
+)
 from nomad_tpu.structs.funcs import pow10_np
 
 
@@ -112,12 +132,18 @@ def test_the_float32_trace_scores_with_the_two_floats():
     mem_after = np.asarray([384.0, 8000.0, 16128.0], np.float32)
     mem_cap = np.asarray([16128.0, 32512.0, 65280.0], np.float32)
     with jax.enable_x64(False):
-        base = np.asarray(jax.jit(_fit_exponentials, static_argnums=4)(
+        hi, lo = jax.jit(_fit_exponentials, static_argnums=4)(
             after, cap, mem_after, mem_cap, jnp.float32
-        ))
-        assert base.dtype == np.float32
-    want = _definition(after, cap) + _definition(mem_after, mem_cap)
-    assert np.array_equal(base, want)
+        )
+        hi, lo = np.asarray(hi), np.asarray(lo)
+        assert hi.dtype == lo.dtype == np.float32
+    e_cpu, e_mem = _definition(after, cap), _definition(mem_after, mem_cap)
+    # hi is the float32 sum the trace scored with before; with lo the
+    # pair is the sum as float64 holds it: exactly
+    assert np.array_equal(hi, e_cpu + e_mem)
+    assert np.array_equal(
+        hi.astype(np.float64) + lo, e_cpu.astype(np.float64) + e_mem
+    )
 
 
 def _lowered(fn, *args):
@@ -164,7 +190,382 @@ def test_at_x64_on_the_score_terms_lower_to_the_float64_trace_of_before():
     assert "bitcast" not in text and "f32" in text
 
 
+def _before_step(
+    cpu_after, cpu_cap, mem_after, mem_cap, coll, desired, pick, penalty,
+    aff, feasible, offset, limit, n_candidates,
+):
+    """The score block and the walk of a pick-step as ops/batch.py held
+    them before a float32 trace's score became a pair, line for line."""
+    dtype = cpu_after.dtype
+    base = _fit_exponentials(cpu_after, cpu_cap, mem_after, mem_cap, dtype)
+    fitness = jnp.clip(20.0 - base, 0.0, 18.0)
+    score_sum = fitness / 18.0
+    count = jnp.ones_like(score_sum)
+    has_coll = coll > 0
+    anti = jnp.where(
+        has_coll,
+        -(coll.astype(dtype) + 1.0) / desired[pick].astype(dtype),
+        0.0,
+    )
+    score_sum = score_sum + anti
+    count = count + has_coll.astype(dtype)
+    score_sum = score_sum - penalty.astype(dtype)
+    count = count + penalty.astype(dtype)
+    has_aff = aff != 0.0
+    score_sum = score_sum + jnp.where(has_aff, aff, 0.0)
+    count = count + has_aff.astype(dtype)
+    s_p = score_sum / count
+    f_p = feasible
+
+    n = s_p.shape[0]
+    pos = jnp.arange(n, dtype=jnp.int32)
+    is_tail = pos >= n_candidates
+    in_wrap = pos < offset
+    wp = jnp.where(
+        is_tail, pos, jnp.mod(pos - offset + n_candidates, n_candidates)
+    )
+
+    def rot(b):
+        cs = jnp.cumsum(b.astype(jnp.int32))
+        total = cs[-1]
+        c_off = jnp.where(offset > 0, cs[offset - 1], 0)
+        return (
+            _rotated_prefix(cs, c_off, total, in_wrap, is_tail), total
+        )
+
+    bad = f_p & (s_p <= SKIP_THRESHOLD)
+    bad_rank, _ = rot(bad)
+    diverted = bad & (bad_rank <= MAX_SKIP)
+    nd = f_p & ~diverted
+    nd_incl, nd_count = rot(nd)
+    div_incl, n_div = rot(diverted)
+    div_rank = div_incl - 1
+    div_order = jnp.where(
+        (n_div == 2) & (nd_count > 0), 1 - div_rank, div_rank
+    )
+    emit_order = jnp.where(nd, nd_incl - 1, nd_count + div_order)
+    emitted = f_p & (emit_order < limit)
+
+    neg_inf = jnp.asarray(-jnp.inf, dtype=s_p.dtype)
+    masked = jnp.where(emitted, s_p, neg_inf)
+    best = jnp.max(masked)
+    candidates = emitted & (masked == best)
+    order_key = jnp.where(
+        candidates, emit_order, jnp.asarray(2**31 - 1, jnp.int32)
+    )
+    win = jnp.argmin(order_key)
+    any_emitted = jnp.any(emitted)
+
+    limit_reached = nd_count >= limit
+    big = jnp.asarray(2**31 - 1, jnp.int32)
+    lth_wp = jnp.min(
+        jnp.where(nd & (nd_incl == limit), wp, big)
+    )
+    pulls = jnp.where(limit_reached, lth_wp + 1, n_candidates)
+    return win, any_emitted, pulls
+
+
+def _step(
+    cpu_after, cpu_cap, mem_after, mem_cap, coll, desired, pick, penalty,
+    aff, feasible, offset, limit, n_candidates,
+):
+    """The same two phases as ops/batch.py holds them now."""
+    dtype = cpu_after.dtype
+    scores = ScoreList(
+        cpu_after, cpu_cap, mem_after, mem_cap, coll, desired, False, dtype,
+        pick=pick,
+    )
+    scores.penalty(penalty)
+    has_aff = aff != 0.0
+    scores.append(jnp.where(has_aff, aff, 0.0), has_aff)
+    win, any_emitted, pulls, decided = _walk(
+        scores.mean(), feasible, offset, limit, n_candidates
+    )
+    return (win, any_emitted, pulls), decided
+
+
+def _step_args(dtype):
+    n = 16
+    col = jnp.arange(1.0, n + 1.0, dtype=dtype)
+    return (
+        col * 300, col * 500 + 4000, col * 128, col * 256 + 2048,
+        jnp.arange(n, dtype=jnp.int32) % 3, jnp.full(4, 10, jnp.int32),
+        jnp.int32(1), jnp.arange(n) % 5 == 0, (col % 4) * 0.25,
+        jnp.arange(n) % 7 != 0, jnp.int32(3), jnp.int32(4), jnp.int32(n - 2),
+    )
+
+
+def test_at_x64_on_the_score_block_and_the_walk_lower_to_the_float64_trace_of_before():
+    """Every line a float32 pair touched — the score list from the
+    exponentials to the mean, the skip test, the maximum and its tie —
+    is, at float64, the code the chained kernel held before, operation
+    for operation; no pair, no flag in the pulls."""
+    args = _step_args(jnp.float64)
+    def _now_step(*a):
+        return _step(*a)[0]
+
+    now = _lowered(_now_step, *args)
+    assert _step(*args)[1] is None
+    assert now.replace("jit__now_step", "jit__before_step") == _lowered(
+        _before_step, *args
+    )
+    assert "bitcast" not in now
+
+
+def test_at_x64_on_the_chained_kernel_holds_no_pair_and_flags_no_pull():
+    kernel = _near_tie_launch(*_some_near_ties(4), dtype=np.float64)
+    text = chained_plan_picks_cols.lower(*kernel[0], **kernel[1]).as_text()
+    assert "bitcast" not in text and "1073741824" not in text
+    with jax.enable_x64(False):
+        kernel = _near_tie_launch(*_some_near_ties(4), dtype=np.float32)
+        text = chained_plan_picks_cols.lower(*kernel[0], **kernel[1]).as_text()
+    assert "bitcast" in text and "1073741824" in text and "f64" not in text
+
+
+# ---- the whole score as a pair ------------------------------------------
+
+FLEETS = {
+    name: Manifest().config(name)["fleet"] for name in ("binpack-10k", "spread-5k")
+}
+
+
+def _pair_scores(rows, desired_count, penalty, spread_fit=False):
+    """(hi, lo) of ``ScoreList``'s mean at x64 off for candidates given
+    as rows (cpu after, cpu capacity, memory after, memory capacity,
+    collisions)."""
+    def mean(cpu_after, cpu_cap, mem_after, mem_cap, coll, pen):
+        scores = ScoreList(
+            cpu_after, cpu_cap, mem_after, mem_cap, coll,
+            jnp.int32(desired_count), spread_fit, jnp.float32,
+        )
+        scores.penalty(pen)
+        return scores.mean()
+
+    with jax.enable_x64(False):
+        cols = [jnp.asarray(rows[:, k], jnp.float32) for k in range(4)]
+        hi, lo = jax.jit(mean)(
+            *cols, jnp.asarray(rows[:, 4], jnp.int32), jnp.asarray(penalty)
+        )
+        assert hi.dtype == lo.dtype == jnp.float32
+        return np.asarray(hi), np.asarray(lo)
+
+
+def _ordered_as_float64(hi, lo, want):
+    """Sorted by the float64 score, the pairs ascend with it wherever it
+    rises by more than 2^-40 of itself.  (Nearer than that, float64's
+    own rounding orders two different sums of terms that tie in the
+    reals; only candidates of identical inputs are held to tie, and
+    those the kernel's test holds.)"""
+    order = np.argsort(want, kind="stable")
+    hi, lo, want = hi[order], lo[order], want[order]
+    rises = (hi[:-1] < hi[1:]) | ((hi[:-1] == hi[1:]) & (lo[:-1] < lo[1:]))
+    apart = np.diff(want) > np.abs(want[1:]) * 2.0**-40
+    assert np.all(rises[apart]), np.flatnonzero(apart & ~rises)[:5]
+    return int(apart.sum())
+
+
+@pytest.mark.parametrize("mixed_terms", [False, True], ids=["binpack", "mixed"])
+@pytest.mark.parametrize("config_name", sorted(FLEETS))
+def test_the_pair_score_orders_sampled_candidates_of_a_fleet_as_float64(
+    config_name, mixed_terms
+):
+    """(a): candidates drawn from every (used, capacity) the fleet can
+    hold; ``mixed``: a third with collisions, a tenth penalised, so the
+    means run over one, two and three terms."""
+    rng = np.random.default_rng(35)
+    n = 300000
+    cpu = np.stack(_every_pair(config_name, "cpu"), 1)
+    mem = np.stack(_every_pair(config_name, "mem"), 1)
+    rows = np.concatenate([
+        cpu[rng.integers(0, len(cpu), n)], mem[rng.integers(0, len(mem), n)],
+        np.zeros((n, 1), np.int64),
+    ], axis=1)
+    penalty = np.zeros(n, bool)
+    if mixed_terms:
+        rows[:, 4] = rng.integers(0, 5, n) * (rng.random(n) < 0.33)
+        penalty = rng.random(n) < 0.1
+    count = int(Manifest().config(config_name)["job"]["task_groups"][0]["count"])
+    hi, lo = _pair_scores(rows, count, penalty)
+    assert np.array_equal(hi, (hi.astype(np.float64) + lo).astype(np.float32))
+    want = near.float64_score(
+        *near.exponentials(*rows[:, :4].T), rows[:, 4], count, penalty
+    )
+    assert np.max(np.abs(hi.astype(np.float64) + lo - want)) < 2.0**-44
+    assert _ordered_as_float64(hi, lo, want) > n // 2
+    # one float32 a score does not: it ties candidates float64 tells apart
+    plain = near.plain_float32(
+        *near.exponentials(*rows[:, :4].T), rows[:, 4], count, penalty
+    )
+    order = np.argsort(want, kind="stable")
+    gap = np.diff(want[order])
+    tied = (plain[order][:-1] >= plain[order][1:]) & (
+        gap > want[order][1:] * 2.0**-40
+    )
+    assert tied.sum() > 100
+
+
+def test_the_lattice_of_binpack_10k_holds_the_near_ties_the_issue_counted():
+    sums, _where = near.lattice_sums(FLEETS["binpack-10k"])
+    worse, better, gap = _near_ties_of("binpack-10k", 0)
+    assert (len(sums), len(gap)) == (489448, 11420)
+    # of the score; 18 times that of the sum of the exponentials
+    assert 6.6e-9 < gap.min() and gap.max() < 1.2e-7
+    assert np.all(worse[:, 4] == 0) and np.all(better[:, 4] == 0)
+
+
+@pytest.mark.parametrize("collisions", [0, 1, 2], ids=["b", "c1", "c2"])
+@pytest.mark.parametrize("config_name", sorted(FLEETS))
+def test_the_pair_orders_every_near_tie_of_the_lattice_as_float64(
+    config_name, collisions
+):
+    """(b), (c) below the kernel: over EVERY near-tie of the lattice
+    the pair puts the better above the worse; the plain tail never."""
+    count = int(Manifest().config(config_name)["job"]["task_groups"][0]["count"])
+    worse, better, _gap = _near_ties_of(config_name, collisions, count)
+    assert len(worse) > 100
+    rows = np.concatenate([worse, better])
+    hi, lo = _pair_scores(rows, count, np.zeros(len(rows), bool))
+    k = len(worse)
+    above = (hi[k:] > hi[:k]) | ((hi[k:] == hi[:k]) & (lo[k:] > lo[:k]))
+    assert np.all(above), np.flatnonzero(~above)[:5]
+    plain = near.plain_float32(
+        *near.exponentials(*rows[:, :4].T), rows[:, 4], count
+    )
+    assert not np.any(plain[k:] > plain[:k])
+
+
+ASK = (500, 256)  # cpu, memory of the benchmark's job
+
+
+@functools.lru_cache(maxsize=None)
+def _near_ties_of(fleet, collisions, count=10):
+    return near.near_ties(FLEETS[fleet], collisions, count)
+
+
+def _some_near_ties(n, collisions=0, fleet="binpack-10k"):
+    """``n`` near-ties spread evenly over the lattice's, both nodes with
+    room for the ask below their lattice point."""
+    worse, better, _gap = _near_ties_of(fleet, collisions)
+    roomy = np.flatnonzero(
+        (worse[:, 0] >= ASK[0]) & (worse[:, 2] >= ASK[1])
+        & (better[:, 0] >= ASK[0]) & (better[:, 2] >= ASK[1])
+    )
+    take = roomy[np.linspace(0, len(roomy) - 1, n).astype(int)]
+    return worse[take], better[take]
+
+
+def _near_tie_launch(worse, better, dtype=np.float32, first="worse"):
+    """(args, kwargs) of one chained launch: an evaluation a near-tie,
+    one pick each, its two nodes the only feasible ones of the arena
+    and the walk long enough for both.  Node 2e is walked before node
+    2e + 1."""
+    k = len(worse)
+    pair = (worse, better) if first == "worse" else (better, worse)
+    nodes = np.stack(pair, 1).reshape(2 * k, 5)
+    c = 2 * k
+    feasible = np.zeros((k, 1, c), bool)
+    feasible[np.arange(k), 0, 2 * np.arange(k)] = True
+    feasible[np.arange(k), 0, 2 * np.arange(k) + 1] = True
+    coll0 = np.broadcast_to(nodes[:, 4].astype(np.int32), (k, 1, c)).copy()
+    stacked = ChainInputs(
+        feasible=feasible,
+        perm=np.tile(np.arange(c, dtype=np.int32), (k, 1)),
+        ask_cpu=np.full((k, 1), ASK[0], dtype),
+        ask_mem=np.full((k, 1), ASK[1], dtype),
+        ask_disk=np.zeros((k, 1), dtype),
+        desired_count=np.full((k, 1), 10, np.int32),
+        limit=np.full((k, 1), 2, np.int32),
+        distinct_hosts=np.zeros(k, bool),
+        tg_idx=np.zeros((k, 1), np.int32),
+    )
+    args = (
+        nodes[:, 1].astype(dtype), nodes[:, 3].astype(dtype),
+        np.full(c, 1e5, dtype),
+        (nodes[:, 0] - ASK[0]).astype(dtype),
+        (nodes[:, 2] - ASK[1]).astype(dtype), np.zeros(c, dtype),
+        stacked, np.full(k, c, np.int32), 1,
+    )
+    return args, {"coll0": coll0}
+
+
+def _kernel_picks(worse, better, first="worse"):
+    """(rows picked, whether ``lo`` decided) by the chained kernel at
+    x64 off."""
+    with jax.enable_x64(False):
+        args, kwargs = _near_tie_launch(worse, better, first=first)
+        rows, pulls = chained_plan_picks_cols(*args, **kwargs)
+        pulls, decided = split_pulls(np.asarray(pulls))
+    k = len(worse)
+    # the walk draws up to the second of the evaluation's two nodes
+    assert np.array_equal(pulls[:, 0], 2 * np.arange(k) + 2)
+    return np.asarray(rows)[:, 0], decided[:, 0]
+
+
+@pytest.mark.parametrize("collisions", [0, 1], ids=["b", "c"])
+def test_the_chained_kernel_at_x64_off_picks_float64s_winner_of_a_near_tie(
+    collisions,
+):
+    """(b), (c): the better node LATER in the walk.  One float32 a
+    score ties the two (or, with the mean over two terms on one side,
+    ranks them the other way), and the pick went to the earlier: this
+    fails on a tree whose float32 trace scores with one float32."""
+    worse, better = _some_near_ties(192, collisions)
+    both = np.concatenate([worse, better])
+    k = len(worse)
+    want = near.float64_score(*near.exponentials(*both[:, :4].T), both[:, 4])
+    plain = near.plain_float32(*near.exponentials(*both[:, :4].T), both[:, 4])
+    assert np.all(want[k:] > want[:k]) and np.all(plain[k:] <= plain[:k])
+    if collisions:
+        assert np.any(worse[:, 4] > 0) and np.any(better[:, 4] > 0)
+        assert np.any(plain[k:] < plain[:k])
+    rows, decided = _kernel_picks(worse, better)
+    assert np.array_equal(rows, 2 * np.arange(k) + 1)
+    # lo decided wherever hi ties the two, a good share of the plain
+    # tail's ties (a pair's hi is the score rounded ONCE to float32,
+    # the plain tail's a rounding a step, so the two tie in different
+    # places; the rest of these picks hi orders by itself)
+    hi, _lo = _pair_scores(both, 10, np.zeros(2 * k, bool))
+    assert np.array_equal(decided, hi[k:] == hi[:k])
+    assert np.all(hi[k:] >= hi[:k]) and decided.mean() > 0.25
+    # the better node first: it wins all the same, by the pair again
+    rows, _decided = _kernel_picks(worse, better, first="better")
+    assert np.array_equal(rows, 2 * np.arange(k))
+
+
+def test_equal_candidates_still_tie_and_the_earlier_wins():
+    worse, _better = _some_near_ties(64)
+    rows, decided = _kernel_picks(worse, worse)
+    assert np.array_equal(rows, 2 * np.arange(len(worse)))
+    assert not decided.any()
+
+
+def test_a_score_of_zero_or_less_is_skipped_on_the_pair_as_on_float64():
+    """A node the job's own allocations crowd scores under 0 and is put
+    aside (select.go's skip list): later in the order of emission, so
+    of two equal candidates the crowded one loses its place."""
+    cand = np.asarray([[7900, 7900, 16128, 16128, 0]], np.int64)
+    crowded = cand + [0, 0, 0, 0, 9]  # binpack 1.0 - 10/10 over two terms
+    want = near.float64_score(
+        *near.exponentials(*np.concatenate([crowded, cand])[:, :4].T),
+        np.asarray([9, 0]),
+    )
+    assert want[0] == 0.0 and want[1] == 1.0
+    rows, decided = _kernel_picks(crowded, cand)
+    assert rows.tolist() == [1] and not decided.any()
+
+
 CHILD = os.path.join(repo_root(), "tests", "_float32_parity_child.py")
+
+
+def _served_at_x64_off(config_name, nodes, jobs, seed, *more):
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    done = subprocess.run(
+        [sys.executable, CHILD, config_name, str(nodes), str(jobs), str(seed),
+         *more],
+        env=env, capture_output=True, text=True, timeout=300,
+    )
+    assert done.returncode == 0, done.stderr[-2000:]
+    return json.loads(done.stdout.strip().splitlines()[-1])
 
 
 @pytest.mark.parametrize("seed", [3, 1000000007, 2**31 + 11])
@@ -174,13 +575,7 @@ CHILD = os.path.join(repo_root(), "tests", "_float32_parity_child.py")
 def test_the_chained_kernel_at_x64_off_places_as_the_reference_and_the_sequential_scheduler(
     config_name, nodes, jobs, seed
 ):
-    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
-    done = subprocess.run(
-        [sys.executable, CHILD, config_name, str(nodes), str(jobs), str(seed)],
-        env=env, capture_output=True, text=True, timeout=300,
-    )
-    assert done.returncode == 0, done.stderr[-2000:]
-    line = json.loads(done.stdout.strip().splitlines()[-1])
+    line = _served_at_x64_off(config_name, nodes, jobs, seed)
     assert line["x64"] is False
     # every evaluation through the chained kernel, none down the host path
     assert line["prescored"] == jobs == line["jobs_compared"]
@@ -190,3 +585,23 @@ def test_the_chained_kernel_at_x64_off_places_as_the_reference_and_the_sequentia
     # the walk's counters: one pick a placement, a pull or more a pick
     assert line["walk_picks"] == line["placements"]
     assert line["walk_pulls"] >= line["walk_picks"]
+    assert line["pair_decided_picks"] is not None
+
+
+@pytest.mark.parametrize("seed", [5, 1000000009, 2**31 + 13])
+def test_the_served_pipeline_at_x64_off_places_a_planted_near_tie_world_as_float64(
+    seed,
+):
+    """(d): a fleet that is one near-tie of the lattice.  Every pick
+    over fresh nodes meets it; the reference and the sequential
+    scheduler (float64 both) give it to the better node wherever that
+    sits in the walk, and so does the served float32 pipeline — by the
+    ``lo`` half, as its counter says."""
+    line = _served_at_x64_off("binpack-10k", 400, 40, seed, "planted")
+    assert line["x64"] is False
+    assert line["prescored"] == 40 == line["jobs_compared"]
+    assert line["differ_from_sequential"] == 0
+    assert line["mismatched_placements"] == 0
+    assert line["lost_or_duplicate"] == 0
+    assert line["walk_picks"] == line["placements"] == 400
+    assert line["pair_decided_picks"] >= 10
