@@ -36,6 +36,7 @@ from ..structs import (
     EVAL_TRIGGER_JOB_DEREGISTER,
     EVAL_TRIGGER_JOB_REGISTER,
     EVAL_TRIGGER_NODE_UPDATE,
+    id_counts,
     Job,
     JOB_TYPE_CORE,
     JOB_TYPE_SERVICE,
@@ -201,6 +202,11 @@ class Server:
         from ..sched.rank import FIT_COUNTERS as RANK_FIT_COUNTERS
 
         self.metrics.preregister(counters=RANK_FIT_COUNTERS)
+        # the process's id pool keeps its two counts (ID_COUNTERS) as
+        # plain integers: structs.new_id takes no lock and makes no
+        # call a draw.  The registry reads them whenever it is read,
+        # from construction on
+        self.metrics.attach_live_counters(id_counts)
         # placement explainability: zero-register the placement.*
         # counter/gauge families so dashboards see the whole reason
         # vocabulary from process start (absence-of-series must mean

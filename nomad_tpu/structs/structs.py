@@ -9,8 +9,8 @@ structs.  IDs are strings (uuid4 hex by default).
 """
 from __future__ import annotations
 
+import os
 import time
-import uuid
 from dataclasses import dataclass, field, replace
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -99,8 +99,64 @@ DEPLOYMENT_STATUS_CANCELLED = "cancelled"
 PREEMPTION_PRIORITY_DELTA = 10
 
 
+# Ids are uuid4 text (32 lower-case hex characters, version nibble 4,
+# RFC 4122 variant), drawn from a pool that ONE read of the OS
+# generator fills: os.urandom releases the GIL, and on a sandboxed
+# host a thread that releases it once an id queues milliseconds for it
+# behind the replay pool (PERF.md section 6, PR 37).  No lock: pop and
+# extend are atomic under the GIL, and two threads that find the pool
+# empty at once both refill, which only makes it longer.
+ID_POOL_SIZE = 256
+# refills so far; ids drawn = refills * ID_POOL_SIZE - ids still pooled
+ID_COUNTERS = ("ids.refills", "ids.drawn")
+_VARIANT_DIGIT = {c: "89ab"[int(c, 16) & 3] for c in "0123456789abcdef"}
+_id_pool: List[str] = []
+_draw_id = _id_pool.pop
+_id_refills = 0
+
+
+def _fresh_ids() -> List[str]:
+    """ID_POOL_SIZE uuid4 hex ids from one read of the OS generator."""
+    global _id_refills
+    raw = os.urandom(16 * ID_POOL_SIZE).hex()
+    _id_refills += 1
+    return [
+        raw[i : i + 12]
+        + "4"
+        + raw[i + 13 : i + 16]
+        + _VARIANT_DIGIT[raw[i + 16]]
+        + raw[i + 17 : i + 32]
+        for i in range(0, len(raw), 32)
+    ]
+
+
 def new_id() -> str:
-    return uuid.uuid4().hex
+    try:
+        return _draw_id()
+    except IndexError:
+        fresh = _fresh_ids()
+        mine = fresh.pop()
+        _id_pool.extend(fresh)
+        return mine
+
+
+def id_counts() -> Dict[str, float]:
+    """ID_COUNTERS as they stand, for a metrics registry to read."""
+    refills = _id_refills
+    return {
+        ID_COUNTERS[0]: float(refills),
+        ID_COUNTERS[1]: float(refills * ID_POOL_SIZE - len(_id_pool)),
+    }
+
+
+def _forget_ids() -> None:
+    """A forked child starts with no pooled id and its own counts."""
+    global _id_refills
+    _id_pool.clear()
+    _id_refills = 0
+
+
+os.register_at_fork(after_in_child=_forget_ids)
 
 
 # ---------------------------------------------------------------------------

@@ -10,7 +10,7 @@ import threading
 import time
 from collections import defaultdict, deque
 from contextlib import contextmanager
-from typing import Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 # cluster-scope observability telemetry, zero-registered at Server
 # construction (the `cluster-obs-metrics` nomadlint rule enforces
@@ -144,6 +144,9 @@ class Metrics:
         self._counters: Dict[str, float] = defaultdict(float)
         self._gauges: Dict[str, float] = {}
         self._samples: Dict[str, _Summary] = defaultdict(_Summary)
+        # counters another module keeps as plain integers (no lock, no
+        # call a count) and this registry reads when it is read
+        self._live_counters: List[Callable[[], Dict[str, float]]] = []
         # happens-before sanitizer (NOMAD_TPU_TSAN=1)
         from .tsan import maybe_instrument
 
@@ -174,11 +177,30 @@ class Metrics:
             for name, value in samples:
                 store[name].add(value, exemplar)
 
+    def attach_live_counters(
+        self, read: Callable[[], Dict[str, float]]
+    ) -> None:
+        """`read()` gives counters by name as they stand; every read of
+        this registry shows them in place of a stored value."""
+        with self._lock:
+            self._live_counters.append(read)
+
+    def _counters_now(self) -> Dict[str, float]:
+        """The stored counters under the live ones (lock held)."""
+        counters = dict(self._counters)
+        for read in self._live_counters:
+            counters.update(read())
+        return counters
+
     def get_counter(self, name: str) -> float:
         """O(1) single-counter read (tests/operators polling one hot
         counter — e.g. the optimistic-replay `replay.*` family —
         shouldn't pay for a full dump() copy)."""
         with self._lock:
+            for read in self._live_counters:
+                value = read().get(name)
+                if value is not None:
+                    return value
             return self._counters.get(name, 0.0)
 
     def get_gauge(self, name: str) -> Optional[float]:
@@ -225,7 +247,7 @@ class Metrics:
     def dump(self) -> Dict:
         with self._lock:
             return {
-                "counters": dict(self._counters),
+                "counters": self._counters_now(),
                 "gauges": dict(self._gauges),
                 "samples": {
                     k: s.snapshot() for k, s in self._samples.items()
@@ -238,7 +260,7 @@ class Metrics:
         point-in-time debugging surface, not a time series)."""
         with self._lock:
             return {
-                "counters": dict(self._counters),
+                "counters": self._counters_now(),
                 "gauges": dict(self._gauges),
                 "samples": {
                     k: {
@@ -274,7 +296,7 @@ class Metrics:
             return base
 
         with self._lock:
-            for name, value in sorted(self._counters.items()):
+            for name, value in sorted(self._counters_now().items()):
                 base = claim(name)
                 if base is None:
                     continue
