@@ -31,11 +31,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import twofloat
 from .score import (
     MAX_SKIP,
     NO_NODE,
     ScoreList,
     earliest_best,
+    pair_hi,
     under_threshold,
 )
 
@@ -100,6 +102,12 @@ class SpreadInputs(NamedTuple):
     # scores with and updates ONLY slots where group == t.  None (the
     # single-group trace) means every slot applies to every pick.
     group: jnp.ndarray = None  # i32[S]
+    # float32 trace only: what narrowing the host's float64 `desired`
+    # and `weight` to float32 drops (twofloat.split64).  With them the
+    # boost is a pair of float32s and the float64 definition's to the
+    # pair; None (every float64 launch) leaves the boost one array.
+    desired_lo: jnp.ndarray = None  # f32[S, V+1]
+    weight_lo: jnp.ndarray = None  # f32[S]
 
 
 class TGInputs(NamedTuple):
@@ -126,6 +134,8 @@ class TGInputs(NamedTuple):
     ask_disk: jnp.ndarray  # f[P]
     desired_count: jnp.ndarray  # i32[P] group count for anti score
     limit: jnp.ndarray  # i32[P] walk visit limit per pick
+    # float32 trace only: the low half of `affinity` (twofloat.split64)
+    affinity_lo: jnp.ndarray = None  # f32[T, C]
 
 
 class PortInputs(NamedTuple):
@@ -178,10 +188,21 @@ def spread_contribution(
 
     Reproduces GetCombinedUseMap incl. the PopulateProposed
     cleared-decrement quirk and spread.py's boost order (empty use
-    map short-circuits BEFORE the missing-attribute penalty)."""
+    map short-circuits BEFORE the missing-attribute penalty).
+
+    On a float32 trace whose launch brought the low halves of the
+    float64 `desired` and `weight`, `desired_node`, `safe_desired` and
+    `weight` are pairs ``(hi, lo)`` (ops/twofloat.py) and so is the
+    sum handed back (`_spread_pairs`); the use counts are whole
+    numbers and one array either way."""
     clr_adj = clr - jnp.where((prop > 0) & (clr > 1), 1.0, 0.0)
     combined = jnp.maximum(0.0, existing + prop - clr_adj)
     used_node = jnp.einsum("scv,sv->sc", onehot, combined)
+    if isinstance(weight, tuple):
+        return _spread_pairs(
+            desired_node, penalty_node, safe_desired, existing, prop,
+            combined, used_node, weight, active, even,
+        )
     frac = (desired_node - (used_node + 1.0)) / safe_desired
     pct_contrib = frac * weight[:, None]
     pct_full = jnp.where(
@@ -224,6 +245,84 @@ def spread_contribution(
         contrib = pct_full
     contrib = jnp.where(active[:, None], contrib, 0.0)
     return jnp.sum(contrib, axis=0)
+
+
+def _spread_pairs(
+    desired_node, penalty_node, safe_desired, existing, prop,
+    combined, used_node, weight, active, even,
+):
+    """`spread_contribution` with the boost as a pair of float32s:
+    ``(desired - (used + 1)) / desired * weight`` with `desired` and
+    `weight` the float64 inputs to the pair, the difference, the
+    quotient and the product each to about 2^-46, and the stanzas
+    summed as pairs in the definition's order.  The even mode's boosts
+    are quotients of whole numbers and ride the same call of the
+    quotient (a launch shape's compile time is in its count of
+    operations); the masks and the -1.0 of the penalty slot are the
+    array path's."""
+    above = twofloat.add_f(desired_node, -(used_node + 1.0))
+    below = safe_desired
+    if even is not None:
+        V1 = combined.shape[-1]
+        value_slot = jnp.arange(V1) < (V1 - 1)
+        present = ((existing + prop) > 0) & value_slot
+        has_map = present.any(axis=-1)
+        big = jnp.asarray(jnp.inf, combined.dtype)
+        min_b = jnp.min(
+            jnp.where(present, combined, big), axis=-1, keepdims=True
+        )
+        max_b = jnp.max(
+            jnp.where(present, combined, -big), axis=-1, keepdims=True
+        )
+        behind = used_node != min_b
+        # the cases that are no quotient: -1 behind an unused value or
+        # where every value is used alike, +1 on an unused value beside
+        # used ones (spread.py even_spread_score_boost)
+        flat = jnp.where(
+            behind,
+            jnp.where(min_b == 0.0, -1.0, 0.0),
+            jnp.where(
+                min_b == max_b, -1.0, jnp.where(min_b == 0.0, 1.0, 0.0)
+            ),
+        )
+        even_above = jnp.where(
+            flat != 0.0, 0.0,
+            jnp.where(behind, min_b - used_node, max_b - min_b),
+        )
+        even_below = jnp.broadcast_to(
+            jnp.where(min_b > 0, min_b, 1.0), even_above.shape
+        )
+        # a stanza with no use map yet reads (inf, -inf) here: its
+        # lanes are dropped below and must not reach the quotient
+        on = has_map[:, None]
+        above = (
+            jnp.stack([above[0], jnp.where(on, even_above, 0.0)]),
+            jnp.stack([above[1], jnp.zeros_like(even_above)]),
+        )
+        below = (
+            jnp.stack([below[0], jnp.where(on, even_below, 1.0)]),
+            jnp.stack([below[1], jnp.zeros_like(even_below)]),
+        )
+    frac = twofloat.quotient(above, below)
+    if even is not None:
+        even_val = twofloat.add_f((frac[0][1], frac[1][1]), flat)
+        frac = (frac[0][0], frac[1][0])
+    pct = twofloat.mul(frac, (weight[0][:, None], weight[1][:, None]))
+    hi = jnp.where(penalty_node, -1.0, pct[0])
+    lo = jnp.where(penalty_node, 0.0, pct[1])
+    if even is not None:
+        # even boosts carry no weight; a stanza in even mode with no
+        # use map scores 0, the penalty slot -1 otherwise
+        even_hi = jnp.where(penalty_node, -1.0, even_val[0])
+        even_lo = jnp.where(penalty_node, 0.0, even_val[1])
+        hi = jnp.where(even[:, None], jnp.where(on, even_hi, 0.0), hi)
+        lo = jnp.where(even[:, None], jnp.where(on, even_lo, 0.0), lo)
+    hi = jnp.where(active[:, None], hi, 0.0)
+    lo = jnp.where(active[:, None], lo, 0.0)
+    total = (hi[0], lo[0])
+    for s in range(1, hi.shape[0]):
+        total = twofloat.add(total, (hi[s], lo[s]))
+    return total
 
 
 class StepDeltas(NamedTuple):
@@ -434,6 +533,11 @@ def _run_picks(
     feas_tp = jnp.take(tg.feasible, perm, axis=1)  # (T, C)
     penalty_p = take(inp.penalty)
     aff_tp = jnp.take(tg.affinity, perm, axis=1)  # (T, C)
+    aff_lo_tp = (
+        jnp.take(tg.affinity_lo, perm, axis=1)
+        if tg.affinity_lo is not None
+        else None
+    )
     ports_on = port_ask is not None
     if ports_on:
         ports_p0 = jnp.take(port_used, perm, axis=1)  # (Q, C)
@@ -456,12 +560,36 @@ def _run_picks(
         with jax.named_scope("spread"):
             codes_sp = jnp.take(spread.codes, perm, axis=1)  # (S, C)
             onehot_p = jax.nn.one_hot(codes_sp, V1, dtype=dtype)
-            desired_node = jnp.einsum(
-                "scv,sv->sc", onehot_p, spread.desired
+            # a float32 launch that brought the low halves of the
+            # float64 `desired` and `weight` scores the boost on pairs
+            # (spread_contribution)
+            pairs = spread.desired_lo is not None
+
+            def at_node(per_slot):
+                # a node's own slot, selected: exact whatever the
+                # backend's matmul precision is
+                return jnp.sum(
+                    jnp.where(onehot_p != 0, per_slot[:, None, :], 0.0),
+                    axis=-1,
+                )
+
+            desired_node = (
+                (at_node(spread.desired), at_node(spread.desired_lo))
+                if pairs
+                else jnp.einsum("scv,sv->sc", onehot_p, spread.desired)
             )
             penalty_node = codes_sp == (V1 - 1)
-            safe_desired = jnp.where(
-                desired_node != 0, desired_node, 1.0
+            has_desired = pair_hi(desired_node) != 0
+            safe_desired = (
+                (
+                    jnp.where(has_desired, desired_node[0], 1.0),
+                    jnp.where(has_desired, desired_node[1], 0.0),
+                )
+                if pairs
+                else jnp.where(has_desired, desired_node, 1.0)
+            )
+            spread_weight = (
+                (spread.weight, spread.weight_lo) if pairs else spread.weight
             )
             spread_existing = spread.used0.astype(dtype)  # (S, V+1)
 
@@ -585,7 +713,12 @@ def _run_picks(
             scores.penalty(penalty_vec)
             aff_k = aff_tp[t]
             has_aff = aff_k != 0.0
-            scores.append(jnp.where(has_aff, aff_k, 0.0), has_aff)
+            if aff_lo_tp is not None:
+                # float32 trace, low halves brought: the term is the
+                # float64 affinity to the pair (0 has a low half of 0)
+                scores.append((aff_k, aff_lo_tp[t]), has_aff)
+            else:
+                scores.append(jnp.where(has_aff, aff_k, 0.0), has_aff)
             if dev_aff is not None:
                 # device-affinity match fraction (rank.go:460): appended
                 # for EVERY scored node when the ask carries affinities
@@ -607,9 +740,11 @@ def _run_picks(
                 spread_total = spread_contribution(
                     onehot_p, desired_node, penalty_node, safe_desired,
                     spread_existing, spread_prop, spread_clr,
-                    spread.weight, slot_active, spread.even, dtype,
+                    spread_weight, slot_active, spread.even, dtype,
                 )
-                scores.append(spread_total, spread_total != 0.0)
+                scores.append(
+                    spread_total, pair_hi(spread_total) != 0.0
+                )
         with jax.named_scope("score"):
             final = scores.mean()
 
@@ -939,6 +1074,8 @@ def chained_plan_picks_cols(
     occ0=None,  # i32[E, C] pickless-group distinct_hosts occupancy
     dh_tg=None,  # bool[E, T] group-level distinct_hosts flags
     return_carry: bool = False,
+    affinity_lo=None,  # f32[E, T, C]: float32 trace only, the low
+                       # half of the host's float64 `affinity`
 ):
     """Serially-equivalent chained planner over shared node columns —
     the BatchWorker's production launch.  Semantics identical to
@@ -971,7 +1108,7 @@ def chained_plan_picks_cols(
         (dev_aff, dev_aff_on) if dev_aff is not None else None
     )
     for x in (coll0, affinity, spread, deltas, pre, port_ask,
-              dev_ask, dev_aff_pair, occ0, dh_tg):
+              dev_ask, dev_aff_pair, occ0, dh_tg, affinity_lo):
         pattern.append(x is not None)
         if x is not None:
             parts.append(x)
@@ -992,6 +1129,7 @@ def chained_plan_picks_cols(
         )
         oc = next(it) if pattern[8] else None
         dhg = next(it) if pattern[9] else None
+        aff_lo = next(it) if pattern[10] else None
         if p is not None:
             with jax.named_scope("usage_update"):
                 used = (
@@ -1015,6 +1153,7 @@ def chained_plan_picks_cols(
             ask_disk=b.ask_disk,
             desired_count=b.desired_count,
             limit=b.limit,
+            affinity_lo=aff_lo,
         )
         # the BatchInputs carrier only supplies perm/penalty/
         # distinct_hosts here — group-routed fields ride in tg_in

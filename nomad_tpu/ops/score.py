@@ -133,8 +133,11 @@ class ScoreList:
     exponentials to ``mean``: ``20 - sum`` exactly, each quotient and
     sum to about 2^-46, so that two candidates whose float64 scores
     differ order as float64 orders them where one float32 would tie
-    them.  A term that is not a pair yet (node affinity, spread boost,
-    device affinity, policy terms) enters ``append`` as ``(value, 0)``."""
+    them.  The node-affinity term and the spread boost of the chained
+    kernel (ops/batch.py) are pairs too wherever the launch brings the
+    low halves of their float64 inputs; a term that is not a pair
+    (device affinity, policy terms, the per-select kernels' affinity
+    and boost) enters ``append`` as ``(value, 0)``."""
 
     def __init__(
         self, cpu_after, cpu_cap, mem_after, mem_cap, collisions,
@@ -207,15 +210,18 @@ class ScoreList:
         self.count = self.count + penalized.astype(self.dtype)
 
     def append(self, term, present=None) -> None:
-        """Add ``term`` (0 where it does not append) to the sum and
-        ``present`` (a mask, or a 0/1 flag in the working dtype; by
-        default where ``term`` is not 0) to the count."""
-        if self.paired:
+        """Add ``term`` (0 where it does not append; on the float32
+        trace an array or a pair) to the sum and ``present`` (a mask,
+        or a 0/1 flag in the working dtype; by default where ``term``
+        is not 0) to the count."""
+        if isinstance(term, tuple):
+            self.sum = twofloat.add(self.sum, term)
+        elif self.paired:
             self.sum = twofloat.add_f(self.sum, term)
         else:
             self.sum = self.sum + term
         if present is None:
-            present = term != 0.0
+            present = pair_hi(term) != 0.0
         if present.dtype == jnp.bool_:
             present = present.astype(self.count.dtype)
         self.count = self.count + present

@@ -9,11 +9,14 @@ near-tied candidates.  So the float32 trace carries the free share and
 the exponential as an unevaluated sum ``hi + lo`` of two float32 arrays
 (about 48 significant bits) and rounds once at the end.  The rest of a
 score — the sum of the two exponentials, ``20 - sum``, ``/ 18``, the
-anti-affinity and penalty terms and the mean — is defined in float64
-too, so it stays a pair all the way to the walk's maximum
-(ops/score.py ``ScoreList``): the pair operations below the
-exponential (``exact_sum``, ``add``, ``add_f``, ``quotient``, ``clip``,
-``at_most``) are the ones that tail needs.
+anti-affinity and penalty terms, the node-affinity term, the spread
+boost and the mean — is defined in float64 too, so it stays a pair all
+the way to the walk's maximum (ops/score.py ``ScoreList``): the pair
+operations below the exponential (``exact_sum``, ``add``, ``add_f``,
+``mul``, ``quotient``, ``clip``, ``at_most``) are the ones that tail
+needs.  A float64 input that is no float32 (a spread's desired count
+of 1.8, a weight of 60/95) reaches the trace as the pair the host
+splits it into (``split64``).
 
 Only float32 ``+``, ``-`` and ``*`` carry the result, and they are
 correctly rounded on the CPU and on the TPU alike.  Every product whose
@@ -42,6 +45,16 @@ F32 = np.float32
 def _const(value: float) -> Tuple[np.float32, np.float32]:
     hi = F32(value)
     return hi, F32(value - float(hi))
+
+
+def split64(x) -> Tuple[np.ndarray, np.ndarray]:
+    """A float64 numpy array as the pair of float32 arrays nearest to
+    it (about 48 of its 53 significant bits), on the host: ``hi`` is
+    what narrowing the array to a float32 trace gives, ``lo`` what that
+    narrowing drops."""
+    x = np.asarray(x, np.float64)
+    hi = x.astype(F32)
+    return hi, (x - hi).astype(F32)
 
 
 def _two_sum(a, b) -> Pair:
@@ -84,7 +97,8 @@ def add_f(x: Pair, b) -> Pair:
     return _fast_two_sum(s, e + x[1])
 
 
-def _mul(x: Pair, y: Pair) -> Pair:
+def mul(x: Pair, y: Pair) -> Pair:
+    """``x * y`` to about 2^-46 of it."""
     p, e = _two_prod(x[0], y[0])
     return _fast_two_sum(p, e + (x[0] * y[1] + x[1] * y[0]))
 
@@ -99,18 +113,22 @@ def neg(x: Pair) -> Pair:
 
 
 def quotient(x: Pair, d) -> Pair:
-    """``x / d`` for a float32 ``d`` that holds a whole number other
-    than 0, to about 2^-47: the backend's quotient, then two
-    corrections by the quotient of the exact remainder.  A quotient
-    that a pair holds exactly comes out exact, and ``d == 1`` hands
-    ``x`` back."""
-    q = x[0] / d
+    """``x / d`` for a ``d`` other than 0, one float32 array (a whole
+    number, say) or a pair, to about 2^-46: the backend's quotient by
+    ``d``'s ``hi``, then two corrections by the quotient of the
+    remainder ``x - q * d``, which is taken to the pair.  A quotient
+    by one float32 that a pair holds exactly comes out exact, and
+    ``d == 1`` hands ``x`` back."""
+    d_hi, d_lo = d if isinstance(d, tuple) else (d, None)
+    q = x[0] / d_hi
     out = (q, jnp.zeros_like(q))
     rem = x
     for _ in range(2):
-        p, e = _two_prod(q, d)
+        p, e = _two_prod(q, d_hi)
+        if d_lo is not None:
+            e = e + q * d_lo
         rem = add(rem, (-p, -e))
-        q = rem[0] / d
+        q = rem[0] / d_hi
         out = add_f(out, q)
     return out
 
@@ -159,13 +177,13 @@ def pow10(x: Pair) -> Pair:
     n = jnp.clip(jnp.round(x[0] * _LOG2_10), -126.0, 127.0)
     # r = x - n * log10(2): 10^x = 2^n * 10^r, |r| <= log10(2) / 2
     r = add_f(add_f(add_f(x, -n * _L1), -n * _L2), -n * _L3)
-    t = _mul(r, _LN10)  # 10^r = e^t
+    t = mul(r, _LN10)  # 10^r = e^t
     tail = jnp.full_like(t[0], F32(_INV_FACT[_LAST_TERM]))
     for k in range(_LAST_TERM - 1, _PAIR_TERMS - 1, -1):
         tail = tail * t[0] + F32(_INV_FACT[k])
     p = (tail, jnp.zeros_like(tail))
     for k in range(_PAIR_TERMS - 1, -1, -1):
-        p = add(_mul(p, t), _const(_INV_FACT[k]))
+        p = add(mul(p, t), _const(_INV_FACT[k]))
     # times 2^n, exact: the exponent field written directly
     scale = jax.lax.bitcast_convert_type(
         (n.astype(jnp.int32) + 127) << 23, jnp.float32

@@ -73,6 +73,7 @@ from ..ops.batch import (
     split_pulls,
 )
 from ..ops.constraints import MaskCompiler
+from ..ops.twofloat import split64
 from ..sched.feasible import shuffle_permutation
 from ..sched.generic_sched import GenericScheduler
 from ..sched.rank import BinPackIterator, RankedNode
@@ -116,6 +117,7 @@ CHUNK_BUCKETS = (2, 4, 8)
 _PER_EVAL_KWARGS = (
     "wanted", "coll0", "affinity", "spread", "deltas", "pre",
     "port_ask", "dev_ask", "dev_aff", "dev_aff_on", "occ0", "dh_tg",
+    "affinity_lo",
 )
 # the cold-compile shield's counters, zero-registered at Server
 # construction: evals a cold launch shape sent down the host path, and
@@ -354,6 +356,10 @@ class _Assembled:
     # eval-axis width this arena's E was aligned to (one launch =
     # one `chunk`-wide slice); chosen per flush by _plan_chunk_width
     chunk: int = PIPELINE_CHUNK
+    # float32 columns only: what narrowing the float64 `affinity` to
+    # the trace's dtype drops (ops/twofloat.py split64; the spread's
+    # low halves ride in `spread` itself).  None at float64
+    affinity_lo: Optional[np.ndarray] = None
 
 
 class _AdmissionQueue:
@@ -4017,6 +4023,9 @@ class BatchWorker(Worker):
             pre=self._zero_pre(E),
             return_carry=True,
         )
+        if steady and args[0].dtype == np.float32:
+            # as _assemble ships an affinity to a float32 trace
+            kwargs["affinity_lo"] = np.zeros((E, T, C), np.float32)
         return args, kwargs
 
     def warm_shapes(
@@ -5180,6 +5189,29 @@ class BatchWorker(Worker):
         use_mesh = mesh_capable if mesh is None else (
             bool(mesh) and mesh_capable
         )
+        # the persistent delta-patched device mirror every launch
+        # reads — the SHARDED mirror for mesh arenas (a mid-chain
+        # admission arena reuses the chain head's mirror tuple
+        # instead of re-syncing: a re-sync would patch buffers
+        # the in-flight launches are reading)
+        dev_cols = (
+            shared_cols
+            if shared_cols is not None
+            else self._device_columns(table, sharded=use_mesh)
+        )
+        affinity_lo = None
+        if dev_cols[0].dtype == np.float32 and not use_mesh:
+            # a float32 trace narrows these float64 inputs; their low
+            # halves go with them, so that the node-affinity term and
+            # the spread boost are the float64 definition's to the
+            # pair (ops/batch.py; the mesh path scores on `hi` alone)
+            if affinity is not None:
+                affinity_lo = split64(affinity)[1]
+            if spread_stack is not None:
+                spread_stack = spread_stack._replace(
+                    desired_lo=split64(spread_stack.desired)[1],
+                    weight_lo=split64(spread_stack.weight)[1],
+                )
         return _Assembled(
             E_real=E_real,
             E=E,
@@ -5202,18 +5234,10 @@ class BatchWorker(Worker):
             dev_aff_on=dev_aff_on,
             occ0=occ0,
             dh_tg=dh_tg,
-            # the persistent delta-patched device mirror every launch
-            # reads — the SHARDED mirror for mesh arenas (a mid-chain
-            # admission arena reuses the chain head's mirror tuple
-            # instead of re-syncing: a re-sync would patch buffers
-            # the in-flight launches are reading)
-            dev_cols=(
-                shared_cols
-                if shared_cols is not None
-                else self._device_columns(table, sharded=use_mesh)
-            ),
+            dev_cols=dev_cols,
             use_mesh=use_mesh,
             chunk=chunk,
+            affinity_lo=affinity_lo,
         )
 
     # -- launch + fetch (pipeline stages 2 and 3) ----------------------

@@ -6,12 +6,15 @@ one of the benchmark's job shapes.  Prints one JSON line: how the
 chained kernel's placements compare with the sequential scheduler's
 (no JAX in it) and with ``benchmark/reference.py`` at float64.
 
-    python tests/_float32_parity_child.py <config> <nodes> <jobs> <seed> [planted]
+    python tests/_float32_parity_child.py <config> <nodes> <jobs> <seed> [planted|weighted]
 
 With ``planted`` the fleet is one near-tie of the configuration's score
 lattice (tests/_near_ties.py), the seed's choice of them: half the
 nodes a candidate, half its neighbour whose float64 score is a hair
-better and which one float32 a score cannot tell from it.
+better and which one float32 a score cannot tell from it.  With
+``weighted`` the job has a second node affinity (dc3, weight 60 beside
+dc2's 35), so that its affinity terms, 35/95 and 60/95, are no float32
+and reach the float32 trace as pairs.
 """
 import json
 import os
@@ -66,6 +69,11 @@ def main(argv):
     config["fleet"]["nodes"] = nodes
     config["fleet"]["resident_allocs"] = nodes * 10
     world = make_world(config, seed)
+    if argv[4:] == ["weighted"]:
+        config["job"]["affinities"].append({
+            "ltarget": "${node.datacenter}", "rtarget": "dc3", "operand": "=",
+            "weight": 60,
+        })
     if argv[4:] == ["planted"]:
         worse, better, _gap = near.near_ties(config["fleet"])
         res = config["job"]["task_groups"][0]["tasks"][0]["resources"]

@@ -1,8 +1,12 @@
 """Near-ties of a fleet's score lattice, for tests/test_float32_scoring.py
 and its child process: candidates whose float64 scores (structs/funcs.py,
-sched/rank.py) differ while one float32 a score — the tail the float32
-trace had before its scores were pairs, kept HERE as ``plain_float32``
-and nowhere in the program — ties them, or even swaps them.  numpy only.
+sched/rank.py, sched/spread.py) differ while one float32 a score — the
+tail the float32 trace had before its scores were pairs, kept HERE as
+``plain_float32`` and nowhere in the program — ties them, or even swaps
+them; and, for a job with a spread and a node affinity, candidates of
+different datacenters that one float32 a TERM (``float32_terms``: the
+boost and the affinity as the trace held them before they were pairs)
+puts in the wrong order.  numpy only.
 """
 import dataclasses
 
@@ -22,9 +26,14 @@ def exponentials(cpu_after, cpu_cap, mem_after, mem_cap):
     )
 
 
-def _mean_score(dtype, e_cpu, e_mem, collisions, desired_count, penalty):
+def _mean_score(
+    dtype, e_cpu, e_mem, collisions, desired_count, penalty,
+    affinity=0.0, boost=0.0,
+):
     """rank.go's score list and its mean over binpack fitness, job
-    anti-affinity and the reschedule penalty, every step in ``dtype``."""
+    anti-affinity, the reschedule penalty, node affinity and the spread
+    boost (each of the last two appended where it is not 0), every step
+    in ``dtype``."""
     t = dtype
     fit = np.clip(t(20.0) - (e_cpu.astype(t) + e_mem.astype(t)), t(0), t(18))
     total = fit / t(18.0)
@@ -35,14 +44,79 @@ def _mean_score(dtype, e_cpu, e_mem, collisions, desired_count, penalty):
     count = count + has.astype(t)
     total = total - np.asarray(penalty).astype(t)
     count = count + np.asarray(penalty).astype(t)
+    for term in (affinity, boost):
+        term = np.asarray(term).astype(t)
+        total = total + term
+        count = count + (term != 0).astype(t)
     return total / count
 
 
-def float64_score(e_cpu, e_mem, collisions=0, desired_count=10, penalty=False):
+def float64_score(
+    e_cpu, e_mem, collisions=0, desired_count=10, penalty=False,
+    affinity=0.0, boost=0.0,
+):
     """The definition: every step after the exponentials in float64."""
     return _mean_score(
-        np.float64, e_cpu, e_mem, collisions, desired_count, penalty
+        np.float64, e_cpu, e_mem, collisions, desired_count, penalty,
+        affinity, boost,
     )
+
+
+def spread_boost(dtype, desired, used, weight=1.0):
+    """sched/spread.py's boost of one stanza, ``((desired - used) /
+    desired) * weight`` with ``used`` counting the placement under way
+    (spread.go:123): ``desired`` and ``weight`` are the float64 numbers
+    the host builds (percent / 100 x count; weight / sum of weights),
+    brought to ``dtype`` as a launch narrows them, and every step after
+    that in ``dtype``."""
+    t = dtype
+    d = np.asarray(desired, np.float64).astype(t)
+    w = np.asarray(weight, np.float64).astype(t)
+    return ((d - (np.asarray(used).astype(t) + t(1.0))) / d) * w
+
+
+def float32_terms(
+    e_cpu, e_mem, collisions, desired_count, affinity, desired, used, weight
+):
+    """The control for a job with a spread and an affinity: the score
+    as the float32 trace held it while those two terms were ONE float32
+    each — the affinity narrowed, the boost computed in float32 from
+    narrowed operands — and the rest of the score a pair (float64 here,
+    which a pair follows to 2^-44)."""
+    return float64_score(
+        e_cpu, e_mem, collisions, desired_count,
+        affinity=np.asarray(affinity, np.float64).astype(F),
+        boost=spread_boost(F, desired, used, weight),
+    )
+
+
+def job_terms(config):
+    """What a configuration's job gives each datacenter of its fleet,
+    as the scheduler computes them in float64: (desired count of the
+    spread stanza — percent targets, the rest implicit —, the stanza's
+    weight over the sum of weights, the node affinity's matched weight
+    over the sum of weights).  One datacenter stanza, datacenter
+    affinities: the benchmark's spread job."""
+    job = config["job"]
+    count = float(job["task_groups"][0]["count"])
+    dcs = config["fleet"]["datacenters"]
+    (spread,) = job["spreads"]
+    targets = {
+        t["value"]: (float(t["percent"]) / 100.0) * count
+        for t in spread["targets"]
+    }
+    rest = count - sum(targets.values())
+    desired = np.asarray([targets.get(dc, rest) for dc in dcs], np.float64)
+    weight = float(spread["weight"]) / float(
+        sum(s["weight"] for s in job["spreads"])
+    )
+    total = sum(abs(float(a["weight"])) for a in job["affinities"])
+    affinity = np.asarray([
+        sum(float(a["weight"]) for a in job["affinities"] if a["rtarget"] == dc)
+        / total
+        for dc in dcs
+    ], np.float64)
+    return desired, weight, affinity
 
 
 def plain_float32(e_cpu, e_mem, collisions=0, desired_count=10, penalty=False):
@@ -107,6 +181,53 @@ def near_ties(fleet, collisions=0, desired_count=10):
     )
     if collisions:
         found &= rows[:-1, 4] != rows[1:, 4]
+    at = np.flatnonzero(found)
+    return rows[at], rows[at + 1], gap[at]
+
+
+def spread_near_ties(config, weight=None, affinity=None):
+    """(worse, better, gap) over the score lattice of a fleet whose job
+    has a spread and an affinity: two candidates of DIFFERENT
+    datacenters, in one state of the job's placements, whose float64
+    scores differ by ``gap`` and which ``float32_terms`` does not order
+    so.  A candidate is a row (cpu after, cpu capacity, memory after,
+    memory capacity, collisions = 0, datacenter, allocations of the job
+    in that datacenter so far).  ``weight`` and ``affinity`` put other
+    numbers in the job's place (a second stanza's share of the weights,
+    say)."""
+    desired, w, aff = job_terms(config)
+    if weight is not None:
+        w = weight
+    if affinity is not None:
+        aff = np.asarray(affinity, np.float64)
+    count = int(config["job"]["task_groups"][0]["count"])
+    _sums, where = lattice_sums(config["fleet"])
+    n = len(where)
+    dc, used = np.divmod(np.arange(len(desired) * count), count)
+    rows = np.concatenate([
+        np.tile(where, (len(dc), 1)), np.zeros((n * len(dc), 1), np.int64),
+        np.repeat(dc, n)[:, None], np.repeat(used, n)[:, None],
+    ], axis=1)
+    e_cpu, e_mem = exponentials(rows[:, 0], rows[:, 1], rows[:, 2], rows[:, 3])
+    want = float64_score(
+        e_cpu, e_mem, 0, count, affinity=aff[rows[:, 5]],
+        boost=spread_boost(np.float64, desired[rows[:, 5]], rows[:, 6], w),
+    )
+    control = float32_terms(
+        e_cpu, e_mem, 0, count, aff[rows[:, 5]], desired[rows[:, 5]],
+        rows[:, 6], w,
+    )
+    order = np.argsort(want, kind="stable")
+    want, control, rows = want[order], control[order], rows[order]
+    gap = np.diff(want)
+    found = (
+        (want[:-1] > 0) & (gap > want[1:] * 2.0**-40)
+        & (control[:-1] >= control[1:])
+        & (rows[:-1, 5] != rows[1:, 5])
+        # one state of the job: what the two datacenters hold together
+        # leaves room for the placement under way
+        & (rows[:-1, 6] + rows[1:, 6] < count)
+    )
     at = np.flatnonzero(found)
     return rows[at], rows[at + 1], gap[at]
 

@@ -127,3 +127,64 @@ def test_the_worker_counts_the_flagged_picks_and_replays_the_clean_pulls(
         assert counters.get("batch_worker.fallbacks", 0.0) == 0.0
     finally:
         server.stop()
+
+
+@pytest.mark.parametrize("flagged", [False, True], ids=["float64", "flagged"])
+def test_walk_counters_count_on_the_whole_fleet_branch_of_a_spread_world(
+    monkeypatch, flagged
+):
+    """A spread and an affinity lift the visit limit: every pick draws
+    the whole ring, and the counters count on that branch of the walk
+    as on the limited one — picks, pulls and, where a float32 trace's
+    answer carries the flag, the picks its `lo` half chose."""
+    from nomad_tpu.structs import Affinity, Spread, SpreadTarget
+
+    if flagged:
+        real = bw.chained_plan_picks_cols
+
+        def flagging(*args, **kwargs):
+            rows, pulls, carry = real(*args, **kwargs)
+            return rows, pulls.at[:, :2].add(PAIR_DECIDED), carry
+
+        monkeypatch.setattr(bw, "chained_plan_picks_cols", flagging)
+    n_nodes, count, jobs = 45, 6, 7
+    server = Server(num_schedulers=1, seed=38, batch_pipeline=True)
+    for i in range(n_nodes):
+        node = mock.node(id=f"spread-node-{i}", datacenter=f"dc{1 + i % 3}")
+        node.computed_class = compute_node_class(node)
+        server.store.upsert_node(node)
+    for i in range(jobs):
+        job = mock.job(id=f"spread-{i}")
+        job.datacenters = ["dc1", "dc2", "dc3"]
+        job.spreads = [Spread(
+            attribute="${node.datacenter}", weight=60,
+            targets=(SpreadTarget("dc1", 50), SpreadTarget("dc2", 30)),
+        )]
+        job.affinities = [
+            Affinity("${node.datacenter}", "dc2", "=", 35),
+            Affinity("${node.datacenter}", "dc3", "=", 60),
+        ]
+        tg = job.task_groups[0]
+        tg.count = count
+        tg.tasks[0].resources.cpu = 20
+        tg.tasks[0].resources.memory_mb = 16
+        server.register_job(job)
+    server.start()
+    try:
+        assert server.drain_to_idle(timeout=120)
+        counters = server.metrics.dump()["counters"]
+        assert server.workers[0].prescored == jobs
+        assert counters.get("batch_worker.fallbacks", 0.0) == 0.0
+        picks = counters["batch_worker.walk_picks"]
+        assert picks == jobs * count
+        assert counters["batch_worker.walk_pulls"] == picks * n_nodes
+        assert counters[bw.PAIR_COUNTER] == (2 * jobs if flagged else 0.0)
+        # every job placed its six, over all three datacenters
+        for i in range(jobs):
+            allocs = server.store.allocs_by_job("default", f"spread-{i}")
+            assert len(allocs) == count
+            assert len({
+                server.store.node_by_id(a.node_id).datacenter for a in allocs
+            }) == 3
+    finally:
+        server.stop()
