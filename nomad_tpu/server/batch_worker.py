@@ -62,6 +62,7 @@ LOG = logging.getLogger("nomad_tpu.server.batch_worker")
 
 import numpy as np
 
+from .. import collector
 from ..ops.batch import (
     ChainInputs,
     PreDeltas,
@@ -1710,6 +1711,8 @@ class BatchWorker(Worker):
                     self.schedulers, timeout=0.1
                 )
                 if ev is None:
+                    # idle: where a walk of the whole heap may run
+                    collector.reclaim_at_idle()
                     continue
                 self._note_dequeue(ev)
                 # storm detection at the gulp boundary: a backlog of

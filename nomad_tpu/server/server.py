@@ -25,6 +25,7 @@ from typing import Dict, List, Optional
 
 LOG = logging.getLogger("nomad_tpu.server")
 
+from .. import collector
 from ..state.store import StateStore
 from ..structs import (
     Allocation,
@@ -207,6 +208,10 @@ class Server:
         # call a draw.  The registry reads them whenever it is read,
         # from construction on
         self.metrics.attach_live_counters(id_counts)
+        # the same for the collector policy a started server holds
+        # (nomad_tpu/collector.py): freezes and idle reclaims
+        self.metrics.attach_live_counters(collector.counts)
+        self._holds_collector = False
         # placement explainability: zero-register the placement.*
         # counter/gauge families so dashboards see the whole reason
         # vocabulary from process start (absence-of-series must mean
@@ -538,6 +543,11 @@ class Server:
     def start(self) -> None:
         """Single-process mode: this server is always the leader."""
         self._running = True
+        # while any server serves, the process's cycle collector
+        # freezes what outlives a full collection
+        if not self._holds_collector:
+            self._holds_collector = True
+            collector.hold()
         # history snapshots run for the whole server lifetime, not
         # just leadership — a follower's metrics are history too
         self.metrics_history.start()
@@ -546,6 +556,9 @@ class Server:
     def stop(self) -> None:
         self._running = False
         self.revoke_leadership()
+        if self._holds_collector:
+            self._holds_collector = False
+            collector.release()
         self.metrics_history.stop()
         self._heartbeat_deadlines.clear()
         # an overload excursion that never walked back to NORMAL must

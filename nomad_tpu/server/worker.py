@@ -14,6 +14,7 @@ from __future__ import annotations
 import threading
 from typing import List, Optional, Tuple
 
+from .. import collector
 from ..explain import EXPLAIN
 from ..raft import NotLeaderError
 from ..sched import new_scheduler
@@ -112,6 +113,7 @@ class Worker:
                 self.schedulers, timeout=0.1
             )
             if ev is None:
+                collector.reclaim_at_idle()
                 continue
             try:
                 self.process_eval(ev, token)
